@@ -30,6 +30,11 @@ The ``scale_speedup`` column (throughput vs the 1-device fleet) is
 reported for the trajectory diff (_diff.py matches it by name) but NOT
 gated: forced host devices share the same physical cores, so CPU
 scaling is a smoke signal, not a claim.
+
+This is a CPU rehearsal of the placement layer's structure and
+exactness, NOT a chip measurement: every fleet runs in a CPU-forced
+child process, and its timings are host-CPU timings.  The chip check of
+the same path is ``chip_smoke.py --chips 4`` (one process, real chips).
 """
 import json
 import pathlib

@@ -86,6 +86,8 @@ def main(argv=None):
                      f"{sorted(benches)} (aliases: {sorted(aliases)})")
     if not selected and not args.all:
         ap.error("pass --all to run every benchmark, or --only <names>")
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     json_dir = None
     if args.json_dir:
         json_dir = pathlib.Path(args.json_dir)

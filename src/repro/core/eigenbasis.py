@@ -9,9 +9,10 @@ This module is the single entry point and the batched engine (DESIGN.md §7):
 
   * ``fit`` runs Algorithm 1 for a whole stack of B matrices inside one
     jitted program — the solver cores are pure ``lax`` control flow, so
-    ``jit(vmap(core))`` runs B greedy factorizations in lockstep, and a
-    device mesh shards the matrix batch across the data axes
-    (runtime/sharding.py + launch/mesh.py).
+    ``jit(vmap(core))`` runs B greedy factorizations in lockstep on the
+    CPU (each device's matrices in turn on an accelerator:
+    ``_over_batch``), and a device mesh shards the matrix batch across
+    the data axes (runtime/sharding.py + launch/mesh.py).
   * ``apply`` / ``project`` route through the batched staged tables
     ((B, S, P); core/staging.py) into the fused Pallas kernels
     (kernels/butterfly.py, kernels/shear.py) with the vmapped ref.py
@@ -48,10 +49,48 @@ GENERAL = "general"
 # Cached jitted fit programs (one compile per (kind, g, hyperparam) combo)
 # ---------------------------------------------------------------------------
 
+def _over_batch(one, shards: int = 1):
+    """``one`` over a leading batch axis of every argument.
+
+    On the CPU the B factorizations run in lockstep (``vmap``).  On an
+    accelerator each device's matrices run one after another
+    (``lax.map``): the greedy and polish loops update rows and columns at
+    data-dependent indices, which ``vmap`` turns into gathers and
+    scatters over the whole stack, and a TPU runs those far slower than
+    the in-place slice updates of one matrix.  ``shards`` is the number
+    of devices the batch is split over: the batch is viewed as (shards,
+    B / shards) and vmapped over its device axis (one matrix slice per
+    device, so nothing is gathered), where a plain ``lax.map`` over the
+    split axis would gather the whole batch onto every device."""
+    if jax.default_backend() == "cpu":
+        return jax.vmap(one)
+
+    def per_device(*args):
+        return jax.lax.map(lambda a: one(*a), args)
+
+    def run(*args):
+        split = [a.reshape(shards, -1, *a.shape[1:]) for a in args]
+        out = jax.vmap(per_device)(*split)
+        return jax.tree.map(lambda o: o.reshape(-1, *o.shape[2:]), out)
+
+    # a size-1 device axis would still cost index arithmetic in every
+    # loop step
+    return per_device if shards == 1 else run
+
+
+def _batch_shards(mats) -> int:
+    """How many devices the leading (batch) axis of ``mats`` is split
+    over (1 for host arrays and single-device arrays)."""
+    sharding = getattr(mats, "sharding", None)
+    if sharding is None:
+        return 1
+    return mats.shape[0] // sharding.shard_shape(mats.shape)[0]
+
+
 @functools.lru_cache(maxsize=None)
 def _sym_fit_program(g: int, n_iter: int, update_spectrum: bool,
                      eps: float, score: str, batched: bool,
-                     masked: bool = False):
+                     masked: bool = False, shards: int = 1):
     if masked:
         def one(s_mat, sbar0, size):
             return gt._approx_sym_core(
@@ -63,12 +102,13 @@ def _sym_fit_program(g: int, n_iter: int, update_spectrum: bool,
                 s_mat, sbar0, g, n_iter, update_spectrum,
                 jnp.asarray(eps, s_mat.dtype), score)
 
-    return jax.jit(jax.vmap(one) if batched else one)
+    return jax.jit(_over_batch(one, shards) if batched else one)
 
 
 @functools.lru_cache(maxsize=None)
 def _gen_fit_program(m: int, n_iter: int, update_spectrum: bool,
-                     eps: float, batched: bool, masked: bool = False):
+                     eps: float, batched: bool, masked: bool = False,
+                     shards: int = 1):
     if masked:
         def one(c_mat, cbar0, size):
             return tt._approx_gen_core(
@@ -80,13 +120,13 @@ def _gen_fit_program(m: int, n_iter: int, update_spectrum: bool,
                 c_mat, cbar0, m, n_iter, update_spectrum,
                 jnp.asarray(eps, c_mat.dtype))
 
-    return jax.jit(jax.vmap(one) if batched else one)
+    return jax.jit(_over_batch(one, shards) if batched else one)
 
 
 @functools.lru_cache(maxsize=None)
 def _sym_extend_program(g_extra: int, n_iter: int, update_spectrum: bool,
                         eps: float, score: str, batched: bool,
-                        masked: bool = False):
+                        masked: bool = False, shards: int = 1):
     """Warm-start extension program, cached like the fit programs: one
     compile per (g_extra, hyperparam) combo serves every batch."""
     if masked:
@@ -102,12 +142,13 @@ def _sym_extend_program(g_extra: int, n_iter: int, update_spectrum: bool,
                 n_iter, update_spectrum, jnp.asarray(eps, s_mat.dtype),
                 score)
 
-    return jax.jit(jax.vmap(one) if batched else one)
+    return jax.jit(_over_batch(one, shards) if batched else one)
 
 
 @functools.lru_cache(maxsize=None)
 def _gen_extend_program(m_extra: int, n_iter: int, update_spectrum: bool,
-                        eps: float, batched: bool, masked: bool = False):
+                        eps: float, batched: bool, masked: bool = False,
+                        shards: int = 1):
     if masked:
         def one(c_mat, fk, fi, fj, fa, cbar, size):
             return tt._extend_gen_core(
@@ -119,7 +160,7 @@ def _gen_extend_program(m_extra: int, n_iter: int, update_spectrum: bool,
                 c_mat, TFactors(fk, fi, fj, fa), cbar, m_extra, n_iter,
                 update_spectrum, jnp.asarray(eps, c_mat.dtype))
 
-    return jax.jit(jax.vmap(one) if batched else one)
+    return jax.jit(_over_batch(one, shards) if batched else one)
 
 
 def _is_symmetric(mats: jnp.ndarray) -> bool:
@@ -246,10 +287,10 @@ class ApproxEigenbasis:
             stage_pad: Optional[tuple] = None) -> "ApproxEigenbasis":
         """Factor one matrix (n, n) or a batch (B, n, n) — Algorithm 1.
 
-        A batch runs inside ONE jit: the B greedy factorizations advance in
-        lockstep (vmapped Theorem-1/3 init + Theorem-2/4 polish sweeps +
-        Lemma-1/2 spectrum refits), which is the embarrassing per-matrix
-        parallelism of the problem.  With ``mesh`` the batch is device_put
+        A batch runs inside ONE jit: the B greedy factorizations
+        (Theorem-1/3 init + Theorem-2/4 polish sweeps + Lemma-1/2
+        spectrum refits) advance in lockstep on the CPU and one after
+        another per device on an accelerator (``_over_batch``).  With ``mesh`` the batch is device_put
         against the mesh's data axes first, so the same program runs SPMD
         across devices (DESIGN.md §7).
 
@@ -335,7 +376,7 @@ class ApproxEigenbasis:
                      else gt.default_sbar(mats, sizes))
             fit_fn = _sym_fit_program(num_transforms, n_iter,
                                       update_spectrum, float(eps), score,
-                                      batched, masked)
+                                      batched, masked, _batch_shards(mats))
             factors, sbar, obj, hist, iters = fit_fn(mats, sbar0, *size_arg)
             fwd, bwd = (pack_g_batch_pair(factors, n, pad=stage_pad)
                         if batched else pack_g_pair(factors, n=n))
@@ -351,7 +392,7 @@ class ApproxEigenbasis:
                      else tt.default_cbar(mats, sizes))
             fit_fn = _gen_fit_program(num_transforms, n_iter,
                                       update_spectrum, float(eps), batched,
-                                      masked)
+                                      masked, _batch_shards(mats))
             factors, cbar, obj, hist, iters = fit_fn(mats, cbar0, *size_arg)
             fwd, bwd = (pack_t_batch_pair(factors, n, pad=stage_pad)
                         if batched else pack_t_pair(factors, n))
@@ -445,7 +486,7 @@ class ApproxEigenbasis:
             info["score"] = score  # chained extends keep the criterion
             fit_fn = _sym_extend_program(extra, n_iter, update_spectrum,
                                          float(eps), score, self.batched,
-                                         masked)
+                                         masked, _batch_shards(mats))
             factors, sbar, obj, hist, iters = fit_fn(
                 mats, *self.factors, self.spectrum, *size_arg)
             fwd, bwd = (pack_g_batch_pair(factors, n, cuts=cuts,
@@ -454,7 +495,8 @@ class ApproxEigenbasis:
                         else pack_g_pair(factors, cuts=cuts, n=n))
         else:
             fit_fn = _gen_extend_program(extra, n_iter, update_spectrum,
-                                         float(eps), self.batched, masked)
+                                         float(eps), self.batched, masked,
+                                         _batch_shards(mats))
             factors, sbar, obj, hist, iters = fit_fn(
                 mats, *self.factors, self.spectrum, *size_arg)
             fwd, bwd = (pack_t_batch_pair(factors, n, cuts=cuts,

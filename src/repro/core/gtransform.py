@@ -82,17 +82,21 @@ def g_to_dense(factors: GFactors, n: int, dtype=jnp.float32) -> jnp.ndarray:
 # Dense 2x2 row/column mixing helpers (dynamic indices, jit-safe)
 # ---------------------------------------------------------------------------
 
+# The rows (columns) a 2x2 mix reads are materialized before it writes:
+# fused into the writes instead, they keep the pre-update matrix alive,
+# and XLA:TPU then copies the whole matrix in and out of every loop
+# iteration that mixes it.
+
+
 def _mix_rows(m, i, j, w00, w01, w10, w11):
-    ri = m[i]
-    rj = m[j]
+    ri, rj = lax.optimization_barrier((m[i], m[j]))
     m = m.at[i].set(w00 * ri + w01 * rj)
     m = m.at[j].set(w10 * ri + w11 * rj)
     return m
 
 
 def _mix_cols(m, i, j, w00, w01, w10, w11):
-    ci = m[:, i]
-    cj = m[:, j]
+    ci, cj = lax.optimization_barrier((m[:, i], m[:, j]))
     m = m.at[:, i].set(w00 * ci + w01 * cj)
     m = m.at[:, j].set(w10 * ci + w11 * cj)
     return m
@@ -212,33 +216,33 @@ def g_init(s_mat: jnp.ndarray, sbar: jnp.ndarray, g: int,
     Returns factors (application order) and the final working matrix
     ``W = Ubar^T S Ubar`` (whose diagonal is the Lemma-1 spectrum).
     """
-    n = s_mat.shape[0]
     dtype = s_mat.dtype
     sbar = sbar.astype(dtype)
     factors0 = gfactors_identity(g, dtype)
     gains0 = _gain_matrix(s_mat, sbar, score, valid)
 
     def body(t, carry):
-        s_work, gains, fi, fj, fc, fs, fsg = carry
-        flat = jnp.argmax(gains)
-        p = flat // n
-        q = flat % n
-        i = jnp.minimum(p, q).astype(jnp.int32)
-        j = jnp.maximum(p, q).astype(jnp.int32)
+        s_work, diag_s, gains, rmax, rarg, fi, fj, fc, fs, fsg = carry
+        # first maximum in row-major order, as argmax over the whole matrix
+        p = jnp.argmax(rmax).astype(jnp.int32)
+        q = rarg[p]
+        i = jnp.minimum(p, q)
+        j = jnp.maximum(p, q)
         # gamma mode pairs d1 with the larger current diagonal slot
         # (continuity); paper mode pairs by the sbar rearrangement.
-        ki = sbar[i] if score == "paper" else s_work[i, i]
-        kj = sbar[j] if score == "paper" else s_work[j, j]
+        ki = sbar[i] if score == "paper" else diag_s[i]
+        kj = sbar[j] if score == "paper" else diag_s[j]
         c, s, sigma = _procrustes_2x2(
-            s_work[i, i], s_work[j, j], s_work[i, j], ki, kj)
+            diag_s[i], diag_s[j], s_work[i, j], ki, kj)
         s_work = _conjugate_gt(s_work, i, j, c, s, sigma)
+        diag_s = diag_s.at[i].set(s_work[i, i]).at[j].set(s_work[j, j])
         # refresh the O(n) affected scores (rows/cols i and j)
-        diag_s = jnp.diagonal(s_work)
         gi = _pair_gains_rows(diag_s, s_work[i], sbar, i, score, valid)
         gains = gains.at[i].set(gi).at[:, i].set(gi)
         gj = _pair_gains_rows(diag_s, s_work[j], sbar, j, score, valid)
         gains = gains.at[j].set(gj).at[:, j].set(gj)
         gains = gains.at[j, i].set(gj[i]).at[i, j].set(gj[i])
+        rmax, rarg = _refresh_row_max(gains, rmax, rarg, i, j, gi, gj)
         # store in application order: discovery t corresponds to slot g-1-t
         slot = g - 1 - t
         fi = fi.at[slot].set(i)
@@ -246,12 +250,46 @@ def g_init(s_mat: jnp.ndarray, sbar: jnp.ndarray, g: int,
         fc = fc.at[slot].set(c)
         fs = fs.at[slot].set(s)
         fsg = fsg.at[slot].set(sigma)
-        return s_work, gains, fi, fj, fc, fs, fsg
+        return s_work, diag_s, gains, rmax, rarg, fi, fj, fc, fs, fsg
 
-    init = (s_mat, gains0, factors0.i, factors0.j,
-            factors0.c, factors0.s, factors0.sigma)
-    s_work, _, fi, fj, fc, fs, fsg = lax.fori_loop(0, g, body, init)
+    init = (s_mat, jnp.diagonal(s_mat), gains0, jnp.max(gains0, axis=1),
+            jnp.argmax(gains0, axis=1).astype(jnp.int32), factors0.i,
+            factors0.j, factors0.c, factors0.s, factors0.sigma)
+    s_work, *_, fi, fj, fc, fs, fsg = lax.fori_loop(0, g, body, init)
     return GFactors(fi, fj, fc, fs, fsg), s_work
+
+
+def _refresh_row_max(gains, rmax, rarg, i, j, gi, gj):
+    """Row maxima and their first columns after rows/columns i < j of the
+    (symmetric) gain matrix changed, without a pass over the matrix.
+
+    Rows i and j are re-read.  Any other row r changed only in columns i
+    and j (to gi[r], gj[r]); its old maximum still stands unless it sat in
+    one of those columns and fell, and then the row is re-read too (a few
+    rows per step).  Ties keep the first column, so the greedy picks the
+    same pair as a row-major argmax over the whole matrix."""
+    rows = jnp.arange(rmax.shape[0])
+    stale = (((rarg == i) & (gi < rmax)) | ((rarg == j) & (gj < rmax))
+             | (rows == i) | (rows == j))
+
+    def better(v, a, best_v, best_a):
+        take = (v > best_v) | ((v == best_v) & (a < best_a))
+        return jnp.where(take, v, best_v), jnp.where(take, a, best_a)
+
+    rmax, rarg = better(gi, i, rmax, rarg)
+    rmax, rarg = better(gj, j, rmax, rarg)
+
+    def reread(carry):
+        stale, rmax, rarg = carry
+        r = jnp.argmax(stale)
+        row = gains[r]
+        rmax = rmax.at[r].set(jnp.max(row))
+        rarg = rarg.at[r].set(jnp.argmax(row).astype(rarg.dtype))
+        return stale.at[r].set(False), rmax, rarg
+
+    _, rmax, rarg = lax.while_loop(lambda c: jnp.any(c[0]), reread,
+                                   (stale, rmax, rarg))
+    return rmax, rarg
 
 
 # ---------------------------------------------------------------------------
@@ -333,21 +371,18 @@ def _polish_block(a_ii, a_jj, a_ij, b_ii, b_jj, b_ij, m11, m12, m21, m22,
     return c, s, sigma
 
 
-def g_polish(s_mat: jnp.ndarray, factors: GFactors, sbar: jnp.ndarray
-             ) -> GFactors:
+def g_polish(s_mat: jnp.ndarray, factors: GFactors, sbar: jnp.ndarray,
+             w: Optional[jnp.ndarray] = None) -> GFactors:
     """One Gauss-Seidel polishing sweep over all g transforms (Theorem 2
-    restricted to the stored indices — the paper's experimental setting)."""
+    restricted to the stored indices — the paper's experimental setting).
+    ``w``: W = Ubar^T S Ubar for ``factors``, if the caller has it."""
     g = factors.g
     dtype = s_mat.dtype
     sbar = sbar.astype(dtype)
 
     # W = Ubar^T S Ubar; A_0 = G_0 W G_0^T
-    def conj_down(t, m):
-        k = g - 1 - t
-        return _conjugate_gt(m, factors.i[k], factors.j[k],
-                             factors.c[k], factors.s[k], factors.sigma[k])
-
-    w = lax.fori_loop(0, g, conj_down, s_mat)
+    if w is None:
+        w = g_conjugated(s_mat, factors)
     a0 = _conjugate_g(w, factors.i[0], factors.j[0],
                       factors.c[0], factors.s[0], factors.sigma[0])
     b0 = jnp.zeros_like(s_mat) + jnp.diag(sbar)
@@ -372,13 +407,10 @@ def g_polish(s_mat: jnp.ndarray, factors: GFactors, sbar: jnp.ndarray
         fsg = fsg.at[k].set(sg)
         # advance: B_{k+1} = G_k B_k G_k^T (new values); A_{k+1} = G_{k+1} A_k G_{k+1}^T
         b_mat = _conjugate_g(b_mat, i, j, c, s, sg)
+        # (after the last component this conjugates once more, unread)
         kn = jnp.minimum(k + 1, g - 1)
-        a_mat = lax.cond(
-            k + 1 < g,
-            lambda m: _conjugate_g(m, factors.i[kn], factors.j[kn],
-                                   factors.c[kn], factors.s[kn],
-                                   factors.sigma[kn]),
-            lambda m: m, a_mat)
+        a_mat = _conjugate_g(a_mat, factors.i[kn], factors.j[kn],
+                             factors.c[kn], factors.s[kn], factors.sigma[kn])
         return a_mat, b_mat, fc, fs, fsg
 
     _, _, fc, fs, fsg = lax.fori_loop(
@@ -410,7 +442,11 @@ def lemma1_spectrum(s_mat: jnp.ndarray, factors: GFactors) -> jnp.ndarray:
 def g_objective(s_mat: jnp.ndarray, factors: GFactors, sbar: jnp.ndarray
                 ) -> jnp.ndarray:
     """||S - Ubar diag(sbar) Ubar^T||_F^2 (== ||W - diag(sbar)||_F^2)."""
-    w = g_conjugated(s_mat, factors)
+    return _off_spectrum(g_conjugated(s_mat, factors), sbar)
+
+
+def _off_spectrum(w, sbar):
+    """||W - diag(sbar)||_F^2."""
     d = w - jnp.diag(sbar.astype(w.dtype))
     return jnp.sum(d * d)
 
@@ -418,26 +454,43 @@ def g_objective(s_mat: jnp.ndarray, factors: GFactors, sbar: jnp.ndarray
 def _sym_iterate(s_mat, factors, sbar, n_iter, update_spectrum, eps):
     """Algorithm-1 refinement loop: polish + Lemma-1 sweeps until the
     objective change drops below ``eps`` (shared by the from-scratch fit
-    and the warm-start extension)."""
-    obj0 = g_objective(s_mat, factors, sbar)
+    and the warm-start extension).  Each sweep conjugates S through the
+    chain once: that W gives the Lemma-1 spectrum, the objective and the
+    next polish's starting point."""
+    w0 = g_conjugated(s_mat, factors)
+    obj0 = _off_spectrum(w0, sbar)
 
     def iter_body(carry):
-        it, factors, sbar, obj_prev, obj, hist = carry
-        f2 = g_polish(s_mat, factors, sbar)
-        sb2 = jnp.where(update_spectrum, lemma1_spectrum(s_mat, f2), sbar)
-        obj2 = g_objective(s_mat, f2, sb2)
+        it, factors, w, sbar, obj_prev, obj, hist = carry
+        f2 = g_polish(s_mat, factors, sbar, w)
+        w2 = g_conjugated(s_mat, f2)
+        sb2 = jnp.where(update_spectrum, jnp.diagonal(w2), sbar)
+        obj2 = _off_spectrum(w2, sb2)
         hist = hist.at[it + 1].set(obj2)
-        return it + 1, f2, sb2, obj, obj2, hist
+        return it + 1, f2, w2, sb2, obj, obj2, hist
 
     def cond(carry):
-        it, _, _, obj_prev, obj, _ = carry
+        it, *_, obj_prev, obj, _ = carry
         return jnp.logical_and(it < n_iter,
                                jnp.abs(obj_prev - obj) >= eps)
 
     hist0 = jnp.full((n_iter + 1,), jnp.nan, s_mat.dtype).at[0].set(obj0)
-    state = (0, factors, sbar, obj0 + 2 * eps + 1.0, obj0, hist0)
-    it, factors, sbar, _, obj, hist = lax.while_loop(cond, iter_body, state)
+    state = (0, factors, w0, sbar, obj0 + 2 * eps + 1.0, obj0, hist0)
+    it, factors, _, sbar, _, obj, hist = lax.while_loop(cond, iter_body,
+                                                        state)
     return factors, sbar, obj, hist, it
+
+
+def full_f32_matmuls(fn):
+    """Trace ``fn`` with f32 matmuls at full precision.  A TPU otherwise
+    runs an f32 dot as a single bf16 pass (about three significant
+    digits), which the greedy's incremental score updates cannot absorb:
+    fits would stall and objectives drift away from the CPU's."""
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+    return traced
 
 
 def _valid_coords(s_mat, size):
@@ -448,6 +501,7 @@ def _valid_coords(s_mat, size):
     return jnp.arange(s_mat.shape[-1]) < size
 
 
+@full_f32_matmuls
 def _approx_sym_core(s_mat, sbar0, g, n_iter, update_spectrum, eps, score,
                      size=None):
     """Traceable Algorithm-1 body (init + polish/spectrum sweeps).
@@ -467,6 +521,7 @@ def _approx_sym_core(s_mat, sbar0, g, n_iter, update_spectrum, eps, score,
     return _sym_iterate(s_mat, factors, sbar, n_iter, update_spectrum, eps)
 
 
+@full_f32_matmuls
 def _extend_sym_core(s_mat, factors0, sbar0, g_extra, n_iter,
                      update_spectrum, eps, score, size=None):
     """Warm-start extension: append ``g_extra`` Theorem-1 components

@@ -29,9 +29,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from .types import SCALE, SHEAR, TFactors, tfactors_identity
-from .gtransform import _masked_default_spectrum, _valid_coords
+from .gtransform import (_masked_default_spectrum, _valid_coords,
+                         full_f32_matmuls)
 from .polyutil import (QUARTIC_POINTS, fit_quartic, minimize_quartic,
-                       real_cubic_roots)
+                       real_cubic_roots, real_quartic_roots)
 
 # Transform-parameter bounds.  The optimal local 'a' can be enormous when
 # the quartic's leading coefficients are tiny; huge shears/scales are
@@ -177,17 +178,8 @@ def _scale_scores(b_mat, e_mat, v_mat, h_mat, nrow, mcol):
     p = fit_quartic(vals)                                   # (n, 5)
     # minimize phi = P/a^2:  Q(a) = a P' - 2P = -2 p0 - p1 a + p3 a^3 + 2 p4 a^4
     q0, q1, q3, q4 = -2.0 * p[..., 0], -p[..., 1], p[..., 2] * 0 + p[..., 3], 2.0 * p[..., 4]
-    n = rho.shape[0]
-    comp = jnp.zeros((n, 4, 4), b_mat.dtype)
-    lead = jnp.where(jnp.abs(q4) > 1e-20, q4, 1.0)
-    comp = comp.at[:, 1, 0].set(1.0).at[:, 2, 1].set(1.0).at[:, 3, 2].set(1.0)
-    comp = comp.at[:, 0, 3].set(-q0 / lead)
-    comp = comp.at[:, 1, 3].set(-q1 / lead)
-    comp = comp.at[:, 2, 3].set(0.0)
-    comp = comp.at[:, 3, 3].set(-q3 / lead)
-    roots = jnp.linalg.eigvals(comp.astype(jnp.float32))     # (n, 4) complex
-    real_ok = jnp.abs(roots.imag) < 1e-3 * (1.0 + jnp.abs(roots.real))
-    cand = jnp.where(real_ok, roots.real, 1.0).astype(b_mat.dtype)
+    roots, real_ok = real_quartic_roots(q4, q3, jnp.zeros_like(q3), q1, q0)
+    cand = jnp.where(real_ok, roots, 1.0).astype(b_mat.dtype)
     # also try cubic fallback roots (q4 ~ 0) and a plain grid refresh
     fb = real_cubic_roots(q3, jnp.zeros_like(q3), q1, q0)
     cand = jnp.concatenate([cand, fb, jnp.ones_like(cand[:, :1])], axis=-1)
@@ -560,6 +552,7 @@ def _gen_iterate(c_mat, factors, cbar, n_iter, update_spectrum, eps):
     return factors, cbar, obj, hist, it
 
 
+@full_f32_matmuls
 def _approx_gen_core(c_mat, cbar0, m, n_iter, update_spectrum, eps,
                      size=None):
     """Traceable Algorithm-1 body for the general case (jit-free so the
@@ -574,6 +567,7 @@ def _approx_gen_core(c_mat, cbar0, m, n_iter, update_spectrum, eps,
     return _gen_iterate(c_mat, factors, cbar, n_iter, update_spectrum, eps)
 
 
+@full_f32_matmuls
 def _extend_gen_core(c_mat, factors0, cbar0, m_extra, n_iter,
                      update_spectrum, eps, size=None):
     """Warm-start extension for the general case (DESIGN.md §9): continue

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.gtransform import full_f32_matmuls
 from repro.core.staging import table_arrays as _tables
 from repro.kernels.plan import ApplyPlan
 
@@ -62,7 +63,7 @@ def _residual_program(plan, num_probes: int):
         den = jnp.maximum(jnp.sum(laps * laps, axis=(-2, -1)), _EPS)
         return est / den
 
-    return jax.jit(program)
+    return jax.jit(full_f32_matmuls(program))
 
 
 def estimate_rel_residual(basis, laps, *, num_probes: int = 8,

@@ -42,6 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.gtransform import full_f32_matmuls
+
 
 
 class Action(enum.Enum):
@@ -235,7 +237,7 @@ def _prefix_spectrum_program(batched: bool, n: int,
         ut = table_op(fwd_t, eye)
         return jnp.einsum("...ij,...jk,...ik->...i", ut, laps, ut)
 
-    return jax.jit(program)
+    return jax.jit(full_f32_matmuls(program))
 
 
 def lemma1_refresh(basis, laps) -> jnp.ndarray:

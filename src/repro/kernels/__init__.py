@@ -1,4 +1,5 @@
-"""Pallas TPU kernels (validated in interpret mode) + XLA reference path.
+"""Pallas TPU kernels (compiled on a TPU, interpreted on the CPU) + the
+XLA reference path.
 
 Dispatch is declarative: ``plan.ApplyPlan`` names a staged-table
 computation and compiles it to one cached program (DESIGN.md §13);

@@ -2,10 +2,10 @@
 
 Two tuned axes (DESIGN.md §13):
 
-  * ``block_b`` — signal rows per Pallas grid step (the static
-    ``block_b`` every kernel entry point takes).  Tables are replicated
-    whole into VMEM per grid step, so the only real dial is how many
-    rows ride along with one table residency.
+  * ``block_b`` — signals per Pallas grid step (the static ``block_b``
+    every kernel entry point takes; the lanes of the transposed signal
+    tile).  The stage tables stream through SMEM once per tile, so the
+    dial is how many signals share one pass over the tables.
   * stage chunking — the cut-ladder granularity the packers schedule
     against (``core/staging.py::default_cut_ladder``): more chunks mean
     finer anytime tiers but deeper schedules, so the best granularity is
@@ -19,9 +19,10 @@ Choices persist in ONE JSON cache so they survive the process:
                  "chunks/sym/n64": {"num_chunks": 4, "source": "prior",
                                     "depth_overhead": {...}}}}
 
-Plan keys are ``<family>/<mode>/<batched|single>/n<width>`` — backend-
-free on purpose: only the Pallas path consults ``block_b``, and the
-same table geometry should tune once.  The cache lives at
+Plan keys are ``<device kind>/<family>/<mode>/<batched|single>/n<width>``:
+a tile measured on one device kind (the CPU's interpreter, say) is never
+applied on another.  They carry no backend: only the Pallas path
+consults ``block_b``.  The cache lives at
 ``$REPRO_AUTOTUNE_CACHE`` (or ``~/.cache/repro/autotune.json``); CI
 points it into the bench artifact dir so tile choices ride along with
 the benchmark JSON and ``benchmarks/_diff.py`` can warn when a choice
@@ -96,8 +97,13 @@ def save_cache(cache: dict, path=None) -> pathlib.Path:
     return p
 
 
+def device_tag() -> str:
+    """The default device's kind as a key segment ("cpu", "TPU_v5_lite")."""
+    return jax.devices()[0].device_kind.replace(" ", "_")
+
+
 def plan_key(plan) -> str:
-    return (f"{plan.family}/{plan.mode}/"
+    return (f"{device_tag()}/{plan.family}/{plan.mode}/"
             f"{'batched' if plan.batched else 'single'}/n{plan.n}")
 
 

@@ -86,10 +86,11 @@ class ApplyPlan:
     ``mode``: "apply" | "operator" | "bank".  ``n``: table width (the
     bucket width for ragged fleets).  ``num_stages``: anytime ladder cut
     (both operator legs are cut consistently; "apply" mode also takes
-    ``keep`` — see ``leg_orientation``).  ``block_b``: Pallas tile rows
-    (None = the persisted autotune choice, falling back to
+    ``keep`` — see ``leg_orientation``).  ``block_b``: Pallas tile
+    signals (None = the persisted autotune choice, falling back to
     ``DEFAULT_BLOCK_B``).  ``precision``/``fused``: see module
-    docstring."""
+    docstring.  Pallas plans run compiled on a TPU and interpreted on
+    the CPU (kernels/butterfly.py::resolve_interpret)."""
 
     family: str
     mode: str
@@ -101,7 +102,6 @@ class ApplyPlan:
     precision: str = "f32"
     fused: bool = True
     block_b: Optional[int] = None
-    interpret: bool = True
     #: optional mesh placement (runtime/sharding.py::BucketPlacement):
     #: ``prepare`` pads the batch axis to the per-device quantum and pins
     #: the tables onto the bucket's devices as sharded jit arguments.
@@ -274,8 +274,8 @@ class ApplyPlan:
                    ("general", False): _sh.shear_apply,
                    ("general", True): _sh.batched_shear_apply}
             fn = fns[self.family, self.batched]
-            kw = dict(block_b=self._resolved_block_b(),
-                      interpret=self.interpret, num_stages=cut, keep=keep)
+            kw = dict(block_b=self._resolved_block_b(), num_stages=cut,
+                      keep=keep)
             if self.batched:
                 return lambda t, x: fn(
                     self._staged(t), x.reshape(x.shape[0], -1, n),
@@ -296,8 +296,7 @@ class ApplyPlan:
                    ("general", False): _sh.gen_operator_apply,
                    ("general", True): _sh.batched_gen_operator_apply}
             fn = fns[self.family, self.batched]
-            kw = dict(block_b=self._resolved_block_b(),
-                      interpret=self.interpret, num_stages=cut)
+            kw = dict(block_b=self._resolved_block_b(), num_stages=cut)
             if self.batched:
                 return lambda ft, bt, d, x: fn(
                     self._staged(ft), self._staged(bt), d,
@@ -320,8 +319,7 @@ class ApplyPlan:
                ("general", False): _sp.gen_filter_bank_apply,
                ("general", True): _sp.batched_gen_filter_bank_apply}
         fn = fns[self.family, self.batched]
-        kw = dict(block_b=self._resolved_block_b(),
-                  interpret=self.interpret, num_stages=cut)
+        kw = dict(block_b=self._resolved_block_b(), num_stages=cut)
 
         if self.batched:
             def bank_op(ft, bt, g, x):

@@ -1,195 +1,65 @@
 """Pallas TPU kernel: staged T-transform (scaling/shear) application.
 
-Same VMEM tiling strategy as butterfly.py; the per-pair action is the
-unified  y_i = alpha x_i + beta x_j  (2 flops/pair — the paper's efficiency
-argument for T- over G-transforms carries straight to the VPU).  The fused
-general-operator kernel applies  Tbar diag(d) Tbar^{-1}  in one round trip
+Same launch as butterfly.py (``chain_call``: coordinates on sublanes,
+signals on lanes, tables streamed through SMEM); the per-pair action is
+the unified  y_i = alpha x_i + beta x_j  — 2 flops per lane and one row
+store, where a G pair needs 6 and two (the paper's efficiency argument
+for T- over G-transforms carries straight to the VPU).  The fused
+general operator applies  Tbar diag(d) Tbar^{-1}  in one launch
 (directed-graph FGFT projection).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
 
-from repro.core.staging import StagedT, truncate_staged
-from .butterfly import _batched_table_spec
+from repro.core.staging import StagedT, table_arrays, truncate_staged
+from .butterfly import (DEFAULT_BLOCK_B, _STATIC, batch_of_one,
+                        chain_call)
 
-DEFAULT_BLOCK_B = 128
-
-
-def _stage_body(x, ii, jj, al, be):
-    xi = jnp.take(x, ii, axis=1)
-    xj = jnp.take(x, jj, axis=1)
-    yi = al[None, :] * xi + be[None, :] * xj
-    return x.at[:, ii].set(yi)
+#: identity entry (index slots take the width n): alpha=1, beta=0
+T_NOOP = (1.0, 0.0)
 
 
-def _shear_kernel(ii_ref, jj_ref, a_ref, b_ref, x_ref, o_ref):
-    x = x_ref[...]
-    dt = x.dtype
-
-    def body(st, xc):
-        return _stage_body(xc, ii_ref[st], jj_ref[st],
-                           a_ref[st].astype(dt), b_ref[st].astype(dt))
-
-    o_ref[...] = lax.fori_loop(0, ii_ref.shape[0], body, x)
+def _t_pair(buf, rnd, i, j, al, be):
+    buf[pl.ds(i, 1), :] = rnd(rnd(al * buf[pl.ds(i, 1), :])
+                              + rnd(be * buf[pl.ds(j, 1), :]))
 
 
-def _fused_gen_kernel(iii_ref, ijj_ref, ia_ref, ib_ref,
-                      fii_ref, fjj_ref, fa_ref, fb_ref,
-                      d_ref, x_ref, o_ref):
-    x = x_ref[...]
-    dt = x.dtype
-
-    def inv_body(st, xc):
-        return _stage_body(xc, iii_ref[st], ijj_ref[st],
-                           ia_ref[st].astype(dt), ib_ref[st].astype(dt))
-
-    x = lax.fori_loop(0, iii_ref.shape[0], inv_body, x)
-    x = x * d_ref[...].astype(dt)[None, :]
-
-    def fwd_body(st, xc):
-        return _stage_body(xc, fii_ref[st], fjj_ref[st],
-                           fa_ref[st].astype(dt), fb_ref[st].astype(dt))
-
-    o_ref[...] = lax.fori_loop(0, fii_ref.shape[0], fwd_body, x)
-
-
-def _full_spec(arr):
-    return pl.BlockSpec(arr.shape, lambda b: (0,) * arr.ndim)
-
-
-@functools.partial(jax.jit, static_argnames=("block_b", "interpret",
-                                             "num_stages", "keep"))
-def shear_apply(staged: StagedT, x: jnp.ndarray,
-                block_b: int = DEFAULT_BLOCK_B,
-                interpret: bool = True,
-                num_stages: int | None = None,
-                keep: str = "head") -> jnp.ndarray:
-    """y = Tbar @ x for batched x of shape (B, n).
-
-    One dummy column absorbs padding entries (index n no-ops).  Static
-    ``num_stages`` cuts the stage tables at a prefix boundary
-    (DESIGN.md §9)."""
-    staged = truncate_staged(staged, num_stages, keep)
-    b, n = x.shape
-    bb = min(block_b, b)
-    grid = (pl.cdiv(b, bb),)
-    xp = jnp.pad(x, ((0, 0), (0, 1)))
-    tables = (staged.idx_i, staged.idx_j, staged.alpha, staged.beta)
-    out = pl.pallas_call(
-        _shear_kernel,
-        grid=grid,
-        in_specs=[_full_spec(t) for t in tables]
-        + [pl.BlockSpec((bb, n + 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((bb, n + 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, n + 1), x.dtype),
-        interpret=interpret,
-    )(*tables, xp)
-    return out[:, :n]
-
-
-@functools.partial(jax.jit, static_argnames=("block_b", "interpret",
-                                             "num_stages"))
-def gen_operator_apply(fwd: StagedT, inv: StagedT, diag: jnp.ndarray,
-                       x: jnp.ndarray, block_b: int = DEFAULT_BLOCK_B,
-                       interpret: bool = True,
-                       num_stages: int | None = None) -> jnp.ndarray:
-    """y = Tbar diag(d) Tbar^{-1} x, fused.
-
-    Static ``num_stages`` truncates both legs to the same component
-    prefix (inv tail / fwd head; DESIGN.md §9)."""
-    inv = truncate_staged(inv, num_stages, "tail")
-    fwd = truncate_staged(fwd, num_stages, "head")
-    b, n = x.shape
-    bb = min(block_b, b)
-    grid = (pl.cdiv(b, bb),)
-    xp = jnp.pad(x, ((0, 0), (0, 1)))
-    dp = jnp.pad(diag, (0, 1), constant_values=1.0)
-    tables = (inv.idx_i, inv.idx_j, inv.alpha, inv.beta,
-              fwd.idx_i, fwd.idx_j, fwd.alpha, fwd.beta, dp)
-    out = pl.pallas_call(
-        _fused_gen_kernel,
-        grid=grid,
-        in_specs=[_full_spec(t) for t in tables]
-        + [pl.BlockSpec((bb, n + 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((bb, n + 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, n + 1), x.dtype),
-        interpret=interpret,
-    )(*tables, xp)
-    return out[:, :n]
-
-
-def _batched_shear_kernel(ii_ref, jj_ref, a_ref, b_ref, x_ref, o_ref):
-    """Plain batched apply: one grid cell = (matrix b, signal tile i)."""
-    x = x_ref[0]
-    dt = x.dtype
-
-    def body(st, xc):
-        return _stage_body(xc, ii_ref[0, st], jj_ref[0, st],
-                           a_ref[0, st].astype(dt), b_ref[0, st].astype(dt))
-
-    o_ref[0] = lax.fori_loop(0, ii_ref.shape[1], body, x)
-
-
-@functools.partial(jax.jit, static_argnames=("block_b", "interpret",
-                                             "num_stages", "keep"))
+@functools.partial(jax.jit, static_argnames=_STATIC + ("keep",))
 def batched_shear_apply(staged: StagedT, x: jnp.ndarray,
                         block_b: int = DEFAULT_BLOCK_B,
-                        interpret: bool = True,
+                        interpret: Optional[bool] = None,
                         num_stages: int | None = None,
                         keep: str = "head") -> jnp.ndarray:
-    """y[b] = Tbar_b x[b]: tables (B, S, P), x (B, R, n) -> (B, R, n)."""
+    """y[b] = Tbar_b x[b]: tables (B, S, P), x (B, R, n) -> (B, R, n).
+    Static ``num_stages`` cuts the tables at a prefix boundary
+    (DESIGN.md §9)."""
     staged = truncate_staged(staged, num_stages, keep)
-    b, r, n = x.shape
-    bb = min(block_b, r)
-    grid = (b, pl.cdiv(r, bb))
-    xp = jnp.pad(x, ((0, 0), (0, 0), (0, 1)))
-    tables = (staged.idx_i, staged.idx_j, staged.alpha, staged.beta)
-    out = pl.pallas_call(
-        _batched_shear_kernel,
-        grid=grid,
-        in_specs=[_batched_table_spec(t) for t in tables]
-        + [pl.BlockSpec((1, bb, n + 1), lambda bm, i: (bm, i, 0))],
-        out_specs=pl.BlockSpec((1, bb, n + 1), lambda bm, i: (bm, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, r, n + 1), x.dtype),
-        interpret=interpret,
-    )(*tables, xp)
-    return out[..., :n]
+    return chain_call(_t_pair, T_NOOP, None, table_arrays(staged), None,
+                      x, block_b, interpret)[:, 0]
 
 
-def _batched_fused_gen_kernel(iii_ref, ijj_ref, ia_ref, ib_ref,
-                              fii_ref, fjj_ref, fa_ref, fb_ref,
-                              d_ref, x_ref, o_ref):
-    """One grid cell = (matrix b, signal tile i); mirrors the batched
-    butterfly kernel (DESIGN.md §7)."""
-    x = x_ref[0]
-    dt = x.dtype
-
-    def inv_body(st, xc):
-        return _stage_body(xc, iii_ref[0, st], ijj_ref[0, st],
-                           ia_ref[0, st].astype(dt), ib_ref[0, st].astype(dt))
-
-    x = lax.fori_loop(0, iii_ref.shape[1], inv_body, x)
-    x = x * d_ref[0].astype(dt)[None, :]
-
-    def fwd_body(st, xc):
-        return _stage_body(xc, fii_ref[0, st], fjj_ref[0, st],
-                           fa_ref[0, st].astype(dt), fb_ref[0, st].astype(dt))
-
-    o_ref[0] = lax.fori_loop(0, fii_ref.shape[1], fwd_body, x)
+@functools.partial(jax.jit, static_argnames=_STATIC + ("keep",))
+def shear_apply(staged: StagedT, x: jnp.ndarray,
+                block_b: int = DEFAULT_BLOCK_B,
+                interpret: Optional[bool] = None,
+                num_stages: int | None = None,
+                keep: str = "head") -> jnp.ndarray:
+    """y = Tbar @ x for x of shape (R, n)."""
+    return batched_shear_apply(batch_of_one(staged), x[None], block_b,
+                               interpret, num_stages, keep)[0]
 
 
-@functools.partial(jax.jit, static_argnames=("block_b", "interpret",
-                                             "num_stages"))
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def batched_gen_operator_apply(fwd: StagedT, inv: StagedT,
                                diag: jnp.ndarray, x: jnp.ndarray,
                                block_b: int = DEFAULT_BLOCK_B,
-                               interpret: bool = True,
+                               interpret: Optional[bool] = None,
                                num_stages: int | None = None
                                ) -> jnp.ndarray:
     """y[b] = Tbar_b diag(d_b) Tbar_b^{-1} x[b] for a batch of directed
@@ -197,20 +67,16 @@ def batched_gen_operator_apply(fwd: StagedT, inv: StagedT,
     ``num_stages`` cuts both legs (inv tail / fwd head)."""
     inv = truncate_staged(inv, num_stages, "tail")
     fwd = truncate_staged(fwd, num_stages, "head")
-    b, r, n = x.shape
-    bb = min(block_b, r)
-    grid = (b, pl.cdiv(r, bb))
-    xp = jnp.pad(x, ((0, 0), (0, 0), (0, 1)))
-    dp = jnp.pad(diag, ((0, 0), (0, 1)), constant_values=1.0)
-    tables = (inv.idx_i, inv.idx_j, inv.alpha, inv.beta,
-              fwd.idx_i, fwd.idx_j, fwd.alpha, fwd.beta, dp)
-    out = pl.pallas_call(
-        _batched_fused_gen_kernel,
-        grid=grid,
-        in_specs=[_batched_table_spec(t) for t in tables]
-        + [pl.BlockSpec((1, bb, n + 1), lambda bm, i: (bm, i, 0))],
-        out_specs=pl.BlockSpec((1, bb, n + 1), lambda bm, i: (bm, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, r, n + 1), x.dtype),
-        interpret=interpret,
-    )(*tables, xp)
-    return out[..., :n]
+    return chain_call(_t_pair, T_NOOP, table_arrays(inv), table_arrays(fwd),
+                      diag[:, None], x, block_b, interpret)[:, 0]
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def gen_operator_apply(fwd: StagedT, inv: StagedT, diag: jnp.ndarray,
+                       x: jnp.ndarray, block_b: int = DEFAULT_BLOCK_B,
+                       interpret: Optional[bool] = None,
+                       num_stages: int | None = None) -> jnp.ndarray:
+    """y = Tbar diag(d) Tbar^{-1} x, fused."""
+    return batched_gen_operator_apply(
+        batch_of_one(fwd), batch_of_one(inv), diag[None], x[None], block_b,
+        interpret, num_stages)[0]

@@ -4,17 +4,13 @@ The spectral subsystem (repro/spectral/; DESIGN.md §8) filters graph
 signals through a *bank* of F responses at once.  Composed naively that is
 three kernel launches per filter — analysis, diagonal scale, synthesis —
 and F redundant analysis passes.  This kernel fuses the whole bank into ONE
-launch per tile: the analysis transform runs once, its coefficients stay
-resident in VMEM, and each filter applies as diagonal-scale → synthesis on
-the cached coefficients.  HBM traffic drops from 2F reads + F writes of the
-signal tile to 1 read + F writes, and the analysis flops are paid once
-instead of F times.
-
-Grid layout follows butterfly.py/shear.py (DESIGN.md §4, §7): single-matrix
-kernels tile the signal rows, batched kernels prepend a matrix-batch grid
-axis so cell (b, i) stages matrix b's (1, S, P) tables into VMEM.  The bank
-axis F is a static python loop inside the kernel (banks are small: a
-handful of filters or Hammond wavelet scales).
+launch per tile (butterfly.py::chain_call): the analysis transform runs
+once into a VMEM scratch, and each filter seeds its output tile from those
+coefficients times its gains and runs the synthesis chain there.  HBM
+traffic drops from 2F reads + F writes of the signal tile to 1 read + F
+writes, and the analysis flops are paid once instead of F times.  The
+filter index is part of the grid's last axis, so VMEM holds one output
+tile whatever F is.
 
 Validated in interpret mode against kernels/ref.py::*_filter_bank_apply
 (tests/test_spectral.py).
@@ -22,186 +18,73 @@ Validated in interpret mode against kernels/ref.py::*_filter_bank_apply
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from jax.experimental import pallas as pl
 
-from repro.core.staging import StagedG, StagedT, truncate_staged
-from .butterfly import _batched_table_spec, _full_spec
-from .butterfly import _stage_body as _g_stage
-from .shear import _stage_body as _t_stage
-
-DEFAULT_BLOCK_B = 128
+from repro.core.staging import StagedG, StagedT, table_arrays, truncate_staged
+from .butterfly import (DEFAULT_BLOCK_B, G_NOOP, _STATIC, _g_pair,
+                        batch_of_one, chain_call)
+from .shear import T_NOOP, _t_pair
 
 
-def _g_chain(x, ii_ref, jj_ref, c_ref, s_ref, sg_ref, prefix=()):
-    """Run a full staged G-chain on x; ``prefix`` indexes a batched table."""
-    dt = x.dtype
-
-    def body(st, xc):
-        ix = prefix + (st,)
-        return _g_stage(xc, ii_ref[ix], jj_ref[ix], c_ref[ix].astype(dt),
-                        s_ref[ix].astype(dt), sg_ref[ix].astype(dt))
-
-    return lax.fori_loop(0, ii_ref.shape[len(prefix)], body, x)
-
-
-def _t_chain(x, ii_ref, jj_ref, a_ref, b_ref, prefix=()):
-    dt = x.dtype
-
-    def body(st, xc):
-        ix = prefix + (st,)
-        return _t_stage(xc, ii_ref[ix], jj_ref[ix], a_ref[ix].astype(dt),
-                        b_ref[ix].astype(dt))
-
-    return lax.fori_loop(0, ii_ref.shape[len(prefix)], body, x)
-
-
-def _bank_sym_kernel(aii, ajj, ac, as_, asg, fii, fjj, fc, fs, fsg,
-                     d_ref, x_ref, o_ref):
-    """Analysis once; per-filter scale+synthesis off the cached
-    coefficients.  d_ref: (F, n+1) gains; o_ref: (F, bb, n+1)."""
-    coeff = _g_chain(x_ref[...], aii, ajj, ac, as_, asg)
-    for f in range(d_ref.shape[0]):
-        y = coeff * d_ref[f].astype(coeff.dtype)[None, :]
-        o_ref[f] = _g_chain(y, fii, fjj, fc, fs, fsg)
-
-
-def _bank_gen_kernel(iii, ijj, ia, ib, fii, fjj, fa, fb, d_ref, x_ref,
-                     o_ref):
-    coeff = _t_chain(x_ref[...], iii, ijj, ia, ib)
-    for f in range(d_ref.shape[0]):
-        y = coeff * d_ref[f].astype(coeff.dtype)[None, :]
-        o_ref[f] = _t_chain(y, fii, fjj, fa, fb)
-
-
-def _batched_bank_sym_kernel(aii, ajj, ac, as_, asg, fii, fjj, fc, fs, fsg,
-                             d_ref, x_ref, o_ref):
-    """One grid cell = (matrix b, signal tile i); tables (1, S, P), gains
-    (1, F, n+1), x (1, bb, n+1), out (1, F, bb, n+1)."""
-    coeff = _g_chain(x_ref[0], aii, ajj, ac, as_, asg, prefix=(0,))
-    for f in range(d_ref.shape[1]):
-        y = coeff * d_ref[0, f].astype(coeff.dtype)[None, :]
-        o_ref[0, f] = _g_chain(y, fii, fjj, fc, fs, fsg, prefix=(0,))
-
-
-def _batched_bank_gen_kernel(iii, ijj, ia, ib, fii, fjj, fa, fb, d_ref,
-                             x_ref, o_ref):
-    coeff = _t_chain(x_ref[0], iii, ijj, ia, ib, prefix=(0,))
-    for f in range(d_ref.shape[1]):
-        y = coeff * d_ref[0, f].astype(coeff.dtype)[None, :]
-        o_ref[0, f] = _t_chain(y, fii, fjj, fa, fb, prefix=(0,))
-
-
-def _g_tables(fwd: StagedG, adj: StagedG, num_stages=None):
+def _g_legs(fwd: StagedG, adj: StagedG, num_stages=None):
     """Analysis (adj, head-cut) + synthesis (fwd, tail-cut) tables
     truncated to the same component prefix (DESIGN.md §9)."""
-    adj = truncate_staged(adj, num_stages, "head")
-    fwd = truncate_staged(fwd, num_stages, "tail")
-    return (adj.idx_i, adj.idx_j, adj.c, adj.s, adj.sigma,
-            fwd.idx_i, fwd.idx_j, fwd.c, fwd.s, fwd.sigma)
+    return (table_arrays(truncate_staged(adj, num_stages, "head")),
+            table_arrays(truncate_staged(fwd, num_stages, "tail")))
 
 
-def _t_tables(fwd: StagedT, inv: StagedT, num_stages=None):
-    inv = truncate_staged(inv, num_stages, "tail")
-    fwd = truncate_staged(fwd, num_stages, "head")
-    return (inv.idx_i, inv.idx_j, inv.alpha, inv.beta,
-            fwd.idx_i, fwd.idx_j, fwd.alpha, fwd.beta)
+def _t_legs(fwd: StagedT, inv: StagedT, num_stages=None):
+    return (table_arrays(truncate_staged(inv, num_stages, "tail")),
+            table_arrays(truncate_staged(fwd, num_stages, "head")))
 
 
-def _bank_call(kernel, tables, gains, x, block_b, interpret):
-    """Shared single-matrix launch: x (R, n), gains (F, n) -> (F, R, n)."""
-    r, n = x.shape
-    f = gains.shape[0]
-    bb = min(block_b, r)
-    grid = (pl.cdiv(r, bb),)
-    xp = jnp.pad(x, ((0, 0), (0, 1)))
-    dp = jnp.pad(gains, ((0, 0), (0, 1)), constant_values=1.0)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[_full_spec(t) for t in tables]
-        + [_full_spec(dp), pl.BlockSpec((bb, n + 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((f, bb, n + 1), lambda i: (0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((f, r, n + 1), x.dtype),
-        interpret=interpret,
-    )(*tables, dp, xp)
-    return out[..., :n]
-
-
-def _batched_bank_call(kernel, tables, gains, x, block_b, interpret):
-    """Batched launch: x (B, R, n), gains (B, F, n) -> (B, F, R, n)."""
-    b, r, n = x.shape
-    f = gains.shape[1]
-    bb = min(block_b, r)
-    grid = (b, pl.cdiv(r, bb))
-    xp = jnp.pad(x, ((0, 0), (0, 0), (0, 1)))
-    dp = jnp.pad(gains, ((0, 0), (0, 0), (0, 1)), constant_values=1.0)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[_batched_table_spec(t) for t in tables]
-        + [_batched_table_spec(dp),
-           pl.BlockSpec((1, bb, n + 1), lambda bm, i: (bm, i, 0))],
-        out_specs=pl.BlockSpec((1, f, bb, n + 1),
-                               lambda bm, i: (bm, 0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, f, r, n + 1), x.dtype),
-        interpret=interpret,
-    )(*tables, dp, xp)
-    return out[..., :n]
-
-
-@functools.partial(jax.jit, static_argnames=("block_b", "interpret",
-                                             "num_stages"))
-def sym_filter_bank_apply(fwd: StagedG, adj: StagedG, gains: jnp.ndarray,
-                          x: jnp.ndarray, block_b: int = DEFAULT_BLOCK_B,
-                          interpret: bool = True,
-                          num_stages: int | None = None) -> jnp.ndarray:
-    """y[f] = Ubar diag(gains_f) Ubar^T x, all F filters in one launch.
-
-    ``gains``: (F, n), ``x``: (R, n) -> (F, R, n).  Static ``num_stages``
-    cuts both transform legs to the same component prefix."""
-    return _bank_call(_bank_sym_kernel, _g_tables(fwd, adj, num_stages),
-                      gains, x, block_b, interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("block_b", "interpret",
-                                             "num_stages"))
-def gen_filter_bank_apply(fwd: StagedT, inv: StagedT, gains: jnp.ndarray,
-                          x: jnp.ndarray, block_b: int = DEFAULT_BLOCK_B,
-                          interpret: bool = True,
-                          num_stages: int | None = None) -> jnp.ndarray:
-    """y[f] = Tbar diag(gains_f) Tbar^{-1} x — the directed bank."""
-    return _bank_call(_bank_gen_kernel, _t_tables(fwd, inv, num_stages),
-                      gains, x, block_b, interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("block_b", "interpret",
-                                             "num_stages"))
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def batched_sym_filter_bank_apply(fwd: StagedG, adj: StagedG,
                                   gains: jnp.ndarray, x: jnp.ndarray,
                                   block_b: int = DEFAULT_BLOCK_B,
-                                  interpret: bool = True,
+                                  interpret: Optional[bool] = None,
                                   num_stages: int | None = None
                                   ) -> jnp.ndarray:
     """Per-matrix banks: tables (B, S, P), gains (B, F, n), x (B, R, n)
-    -> (B, F, R, n).  Grid (B, ⌈R/block_b⌉) as in butterfly.py."""
-    return _batched_bank_call(_batched_bank_sym_kernel,
-                              _g_tables(fwd, adj, num_stages), gains, x,
-                              block_b, interpret)
+    -> (B, F, R, n), all F filters in one launch.  Static ``num_stages``
+    cuts both transform legs to the same component prefix."""
+    return chain_call(_g_pair, G_NOOP, *_g_legs(fwd, adj, num_stages),
+                      gains, x, block_b, interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("block_b", "interpret",
-                                             "num_stages"))
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def batched_gen_filter_bank_apply(fwd: StagedT, inv: StagedT,
                                   gains: jnp.ndarray, x: jnp.ndarray,
                                   block_b: int = DEFAULT_BLOCK_B,
-                                  interpret: bool = True,
+                                  interpret: Optional[bool] = None,
                                   num_stages: int | None = None
                                   ) -> jnp.ndarray:
     """Directed per-matrix banks: gains (B, F, n), x (B, R, n)."""
-    return _batched_bank_call(_batched_bank_gen_kernel,
-                              _t_tables(fwd, inv, num_stages), gains, x,
-                              block_b, interpret)
+    return chain_call(_t_pair, T_NOOP, *_t_legs(fwd, inv, num_stages),
+                      gains, x, block_b, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def sym_filter_bank_apply(fwd: StagedG, adj: StagedG, gains: jnp.ndarray,
+                          x: jnp.ndarray, block_b: int = DEFAULT_BLOCK_B,
+                          interpret: Optional[bool] = None,
+                          num_stages: int | None = None) -> jnp.ndarray:
+    """y[f] = Ubar diag(gains_f) Ubar^T x: gains (F, n), x (R, n) ->
+    (F, R, n)."""
+    return batched_sym_filter_bank_apply(
+        batch_of_one(fwd), batch_of_one(adj), gains[None], x[None],
+        block_b, interpret, num_stages)[0]
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def gen_filter_bank_apply(fwd: StagedT, inv: StagedT, gains: jnp.ndarray,
+                          x: jnp.ndarray, block_b: int = DEFAULT_BLOCK_B,
+                          interpret: Optional[bool] = None,
+                          num_stages: int | None = None) -> jnp.ndarray:
+    """y[f] = Tbar diag(gains_f) Tbar^{-1} x — the directed bank."""
+    return batched_gen_filter_bank_apply(
+        batch_of_one(fwd), batch_of_one(inv), gains[None], x[None],
+        block_b, interpret, num_stages)[0]

@@ -8,6 +8,16 @@ init and only then calls it.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the engines annotate
+    shardings and let the compiler propagate them (``make_mesh`` itself
+    defaults to ``Explicit`` axes, under which those programs do not
+    type-check)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,7 +25,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     for the 512-chip two-pod configuration."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_local_mesh(model_axis: int = 1):
@@ -26,4 +36,4 @@ def make_local_mesh(model_axis: int = 1):
             f"make_local_mesh: {n} visible device(s) cannot be factored "
             f"into a model axis of {model_axis} (need model_axis >= 1 and "
             f"{n} % model_axis == 0)")
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return auto_mesh((n // model_axis, model_axis), ("data", "model"))

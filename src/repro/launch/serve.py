@@ -59,7 +59,7 @@ import numpy as np
 
 from repro import obs
 from repro.configs import ARCH_NAMES, get_config
-from repro.launch.mesh import make_local_mesh
+from repro.launch.mesh import auto_mesh, make_local_mesh
 from repro.models import transformer as tfm
 
 DEFAULT_TIERS = {"full": 1.0, "balanced": 0.5, "draft": 0.25}
@@ -1109,11 +1109,15 @@ class RaggedFGFTServeEngine:
             alpha = num_transforms / (w_max * np.log2(w_max))
             return max(int(round(alpha * w * np.log2(w))), 1)
 
+        #: per-bucket onboarding seconds (fit, pack, tier spectra) of
+        #: the fits this router ran; empty for a restored router
+        self.onboard_seconds: Dict[int, float] = {}
         if _engines is not None:               # load() restores prefit
             self.engines = _engines
             return
         self.engines: Dict[int, FGFTServeEngine] = {}
         for w, members in sorted(self.bucket_of.items()):
+            t0 = time.perf_counter()
             stack, sizes = pad_ragged([laps[p] for p in members], width=w)
             self.engines[w] = FGFTServeEngine(
                 stack, scaled_g(w), n_iter=n_iter, backend=backend,
@@ -1123,6 +1127,9 @@ class RaggedFGFTServeEngine:
                 fused=fused, block_b=block_b,
                 placement=(None if self.placement is None
                            else self.placement[w]))
+            jax.block_until_ready([t["spectrum"] for t in
+                                   self.engines[w].tiers.values()])
+            self.onboard_seconds[w] = time.perf_counter() - t0
 
     def __len__(self) -> int:
         return len(self.sizes)
@@ -1327,7 +1334,7 @@ class RaggedFGFTServeEngine:
             # THIS process has (shard-aware restore reassembles full
             # arrays, so any mesh shape works)
             if mesh is None:
-                mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+                mesh = auto_mesh((len(jax.devices()),), ("data",))
             placement = "auto"
         fp = _resolve_fleet_placement(placement, mesh, bucket_of)
         engines: Dict[int, FGFTServeEngine] = {}
@@ -1686,6 +1693,8 @@ def _export_obs(args):
 
 def main(argv=None):
     args = parse_args(argv)
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     try:
         return _serve_main(args)
     finally:

@@ -989,6 +989,10 @@ def serve_fgft_async(args) -> dict:
             churn_stop.set()
             churner.join()
         stats = service.stats()
+    failed = stats["errors"], stats["maintain"]["errors"]
+    if any(failed):
+        raise RuntimeError(f"[svc] {failed[0]} request(s) and {failed[1]} "
+                           f"maintenance tick(s) failed; no rate reported")
     qps = len(results) / elapsed
     print(f"[svc] {len(results)} requests in {elapsed:.2f}s -> "
           f"{qps:.1f} qps sustained "

@@ -18,28 +18,6 @@ from repro.optim import adamw, compress
 from . import sharding as shd
 
 
-def _shard_map(f, *, axis_names, in_specs, out_specs, mesh=None,
-               fallback_mesh=None, check_vma=False):
-    """jax.shard_map with a fallback for jax < 0.6 (this container ships
-    0.4.x, where only jax.experimental.shard_map exists and partial-manual
-    is spelled ``auto=`` instead of ``axis_names=``).
-
-    ``mesh=None`` inherits the context mesh on jax >= 0.6 (nested use);
-    the pre-0.6 API cannot, so nested callers also supply
-    ``fallback_mesh`` (the physical mesh), used only on the old path."""
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(axis_names=axis_names, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=check_vma)
-        if mesh is not None:
-            kwargs["mesh"] = mesh
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-    mesh = mesh if mesh is not None else fallback_mesh
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma, auto=auto)
-
-
 class TrainState(NamedTuple):
     params: Any
     opt: adamw.AdamWState
@@ -243,11 +221,11 @@ def make_pod_compressed_train_step(
 
         # mesh omitted: inherits the context mesh, whose pod axis is
         # already Manual from the enclosing shard_map
-        return _shard_map(
+        return jax.shard_map(
             local, axis_names={"data", "model"},
             in_specs=(leaf_specs, leaf_specs, P()),
             out_specs=(leaf_specs, leaf_specs),
-            fallback_mesh=mesh, check_vma=False)(grads, ef, step)
+            check_vma=False)(grads, ef, step)
 
     def inner(params, batch, ef, step):
         inner_axes = tuple(a for a in ("data",) if a in mesh.axis_names)
@@ -262,7 +240,7 @@ def make_pod_compressed_train_step(
         ef = jax.tree.map(lambda e: e[None], ef)
         return loss, metrics, grads, ef
 
-    smap = _shard_map(
+    smap = jax.shard_map(
         inner, mesh=mesh, axis_names={"pod"},
         in_specs=(p_specs, b_specs, ef_pod_specs, P()),
         out_specs=(P(), {"loss": P(), "ppl_proxy": P()},

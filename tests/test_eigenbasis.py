@@ -1,6 +1,7 @@
 """Batched eigenspace engine (core/eigenbasis.py): batched-vs-loop
 equivalence, save/load round-trips, and batched Pallas-vs-ref parity."""
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -34,6 +35,28 @@ def test_batched_sym_fit_matches_single_runs():
         _, _, info = approximate_symmetric(mats[i], g=g, n_iter=2)
         rel_single = float(info["objective"]) / norms[i]
         np.testing.assert_allclose(rel_batched[i], rel_single, atol=1e-5)
+
+
+@pytest.mark.parametrize("shards", [1, 2])
+def test_accelerator_batch_form_matches_lockstep(monkeypatch, shards):
+    """On an accelerator a batched fit runs each device's matrices in
+    turn (eigenbasis.py::_over_batch); it must return what the CPU's
+    lockstep vmap returns, split over one or two device shards."""
+    from repro.core import eigenbasis as eb
+    mats = _sym_batch(4, 16, seed=3)
+    sbar0 = jnp.zeros((4, 16), jnp.float32)
+    args = (48, 2, True, 1e-3, "gamma", True, False)
+    want = eb._sym_fit_program(*args)(mats, sbar0)
+    monkeypatch.setattr(eb.jax, "default_backend", lambda: "tpu")
+    got = eb._over_batch(
+        lambda s, sb: eb.gt._approx_sym_core(
+            s, sb, 48, 2, True, jnp.asarray(1e-3, s.dtype), "gamma"),
+        shards)(mats, sbar0)
+    for w, g in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        assert w.shape == g.shape
+    np.testing.assert_allclose(np.asarray(got[2]), np.asarray(want[2]),
+                               rtol=1e-4)
+    np.testing.assert_array_equal(np.asarray(got[0].i), np.asarray(want[0].i))
 
 
 @pytest.mark.slow

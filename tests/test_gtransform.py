@@ -6,7 +6,8 @@ import jax.numpy as jnp
 
 from repro.core import (approximate_symmetric, g_init, g_polish, g_objective,
                         g_to_dense, gapply, lemma1_spectrum)
-from repro.core.gtransform import _gain_matrix, _procrustes_2x2
+from repro.core.gtransform import (_gain_matrix, _procrustes_2x2,
+                                  _refresh_row_max)
 from repro.core.types import GFactors, gfactors_identity
 
 
@@ -79,6 +80,35 @@ def test_theorem1_score_matches_bruteforce():
             # objective drop = 2 * gain
             np.testing.assert_allclose(base - obj, 2 * gains[i, j],
                                        rtol=1e-3, atol=1e-3)
+
+
+def test_row_maxima_pick_the_whole_matrix_argmax():
+    """The greedy's row maxima, refreshed after each step's row/column
+    updates, pick the pair a row-major argmax over the whole gain matrix
+    picks, ties and all-(-inf) padding rows included."""
+    rng = np.random.default_rng(0)
+    n, pad = 16, 3
+    g = rng.integers(0, 4, (n, n)).astype(np.float32)
+    g = np.maximum(g, g.T)
+    g[-pad:] = g[:, -pad:] = -np.inf
+    np.fill_diagonal(g, -np.inf)
+    gains = jnp.asarray(g)
+    rmax = jnp.max(gains, axis=1)
+    rarg = jnp.argmax(gains, axis=1).astype(jnp.int32)
+    for _ in range(60):
+        i, j = (jnp.int32(v) for v in sorted(rng.choice(n - pad, 2, False)))
+        gi, gj = (np.where(np.arange(n) < n - pad,
+                           rng.integers(0, 4, n), -np.inf).astype(np.float32)
+                  for _ in range(2))
+        gi[i], gj[j] = -np.inf, -np.inf
+        gains = (gains.at[i].set(gi).at[:, i].set(gi).at[j].set(gj)
+                 .at[:, j].set(gj).at[j, i].set(gj[i]).at[i, j].set(gj[i]))
+        rmax, rarg = _refresh_row_max(gains, rmax, rarg, i, j,
+                                      jnp.asarray(gi), jnp.asarray(gj))
+        np.testing.assert_array_equal(rmax, jnp.max(gains, axis=1))
+        np.testing.assert_array_equal(rarg, jnp.argmax(gains, axis=1))
+        p = int(jnp.argmax(rmax))
+        assert p * n + int(rarg[p]) == int(jnp.argmax(gains))
 
 
 def test_equal_sbar_entries_give_zero_gain():

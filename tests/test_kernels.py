@@ -188,3 +188,15 @@ def test_plan_prefix_backend_parity_and_bank():
     b_x = plan("bank", "xla").bank(fwd, adj, gains, x[0])
     b_p = plan("bank", "pallas").bank(fwd, adj, gains, x[0])
     np.testing.assert_allclose(np.asarray(b_x), np.asarray(b_p), atol=1e-5)
+
+
+def test_interpret_mode_only_on_the_cpu(monkeypatch):
+    """Kernels interpret exactly when the backend is the CPU; asking for
+    the interpreter anywhere else is an error, never a silent fallback."""
+    assert bf.resolve_interpret(None) is True
+    assert bf.resolve_interpret(True) is True
+    monkeypatch.setattr(bf.jax, "default_backend", lambda: "tpu")
+    assert bf.resolve_interpret(None) is False
+    assert bf.resolve_interpret(False) is False
+    with pytest.raises(ValueError, match="interpret"):
+        bf.resolve_interpret(True)

@@ -13,9 +13,10 @@ _SCRIPT = """
     from repro.configs import get_config
     from repro.runtime import steps as steps_lib
     from repro.runtime import hlo_analysis as hlo
+    from repro.launch.mesh import auto_mesh
 
     cfg = get_config("qwen2-1.5b", smoke=True)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = auto_mesh((2, 2, 2), ("pod", "data", "model"))
     bundle = steps_lib.make_pod_compressed_train_step(
         cfg, mesh, seq_len=32, global_batch=4, compress_ratio=0.25)
     with mesh:
@@ -41,11 +42,6 @@ _SCRIPT = """
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not hasattr(__import__("jax"), "shard_map"),
-    reason="nested partial-manual shard_map needs jax>=0.6: the 0.4.x XLA "
-           "aborts with 'Check failed: sharding.IsManualSubgroup()' "
-           "(runtime/steps.py shims the API, but not the compiler)")
 def test_pod_compressed_step_runs_and_reduces_cross_pod():
     res = run_in_mesh_subprocess(_SCRIPT, devices=8)
     # losses finite and step executes repeatedly (EF buffers thread through)

@@ -330,6 +330,21 @@ def test_autotune_cache_roundtrip(tmp_path):
                                       path=path) == 2
 
 
+def test_autotune_keys_carry_the_device_kind(tmp_path):
+    """A tile recorded for another device kind is never applied here."""
+    path = tmp_path / "autotune.json"
+    plan = ApplyPlan(family="sym", mode="operator", n=32, batched=True)
+    key = autotune.plan_key(plan)
+    assert key == f"{autotune.device_tag()}/sym/operator/batched/n32"
+    assert " " not in key
+    other = "cpu" if autotune.device_tag() != "cpu" else "TPU_v5_lite"
+    autotune.record(key.replace(autotune.device_tag(), other, 1),
+                    path=path, source="measured", block_b=256)
+    assert autotune.cached_block_b(plan, path) is None
+    autotune.record(key, path=path, source="measured", block_b=64)
+    assert autotune.cached_block_b(plan, path) == 64
+
+
 def test_autotune_corrupt_cache_is_fresh(tmp_path):
     path = tmp_path / "autotune.json"
     path.write_text("{not json")
