@@ -4,7 +4,7 @@ ragged batching vs a per-graph loop (DESIGN.md §10).
 A production fleet arrives with MANY distinct Laplacian sizes; the batched
 engine's (B, n, n) stack cannot hold it directly.  The router
 (launch/serve.py::RaggedFGFTServeEngine) zero-pads each graph into its
-power-of-two bucket, fits every bucket in ONE masked jit(vmap), and
+power-of-two bucket, fits every bucket in ONE masked jit, and
 dispatches each serving step as one fused batched operator per bucket.
 This benchmark gates the two claims that make that design honest:
 

@@ -9,10 +9,9 @@ This module is the single entry point and the batched engine (DESIGN.md §7):
 
   * ``fit`` runs Algorithm 1 for a whole stack of B matrices inside one
     jitted program — the solver cores are pure ``lax`` control flow, so
-    ``jit(vmap(core))`` runs B greedy factorizations in lockstep on the
-    CPU (each device's matrices in turn on an accelerator:
-    ``_over_batch``), and a device mesh shards the matrix batch across
-    the data axes (runtime/sharding.py + launch/mesh.py).
+    one program runs each device's matrices in turn (``_over_batch``),
+    and a device mesh shards the matrix batch across the data axes
+    (runtime/sharding.py + launch/mesh.py).
   * ``apply`` / ``project`` route through the batched staged tables
     ((B, S, P); core/staging.py) into the fused Pallas kernels
     (kernels/butterfly.py, kernels/shear.py) with the vmapped ref.py
@@ -52,19 +51,15 @@ GENERAL = "general"
 def _over_batch(one, shards: int = 1):
     """``one`` over a leading batch axis of every argument.
 
-    On the CPU the B factorizations run in lockstep (``vmap``).  On an
-    accelerator each device's matrices run one after another
-    (``lax.map``): the greedy and polish loops update rows and columns at
-    data-dependent indices, which ``vmap`` turns into gathers and
+    Each device's matrices run one after another (``lax.map``): the
+    greedy and polish loops update rows and columns at data-dependent
+    indices, which a lockstep ``vmap`` would turn into gathers and
     scatters over the whole stack, and a TPU runs those far slower than
     the in-place slice updates of one matrix.  ``shards`` is the number
     of devices the batch is split over: the batch is viewed as (shards,
     B / shards) and vmapped over its device axis (one matrix slice per
     device, so nothing is gathered), where a plain ``lax.map`` over the
     split axis would gather the whole batch onto every device."""
-    if jax.default_backend() == "cpu":
-        return jax.vmap(one)
-
     def per_device(*args):
         return jax.lax.map(lambda a: one(*a), args)
 
@@ -289,8 +284,8 @@ class ApproxEigenbasis:
 
         A batch runs inside ONE jit: the B greedy factorizations
         (Theorem-1/3 init + Theorem-2/4 polish sweeps + Lemma-1/2
-        spectrum refits) advance in lockstep on the CPU and one after
-        another per device on an accelerator (``_over_batch``).  With ``mesh`` the batch is device_put
+        spectrum refits) run one after another on each device
+        (``_over_batch``).  With ``mesh`` the batch is device_put
         against the mesh's data axes first, so the same program runs SPMD
         across devices (DESIGN.md §7).
 
@@ -442,7 +437,7 @@ class ApproxEigenbasis:
         ``mats``: the same (n, n) / (B, n, n) stack this basis was fitted
         to (the basis stores factors, not matrices; a ragged fit extends
         against the same zero-padded bucket stack and keeps its masking).
-        Batched fits extend under one jit(vmap) program, cached like the
+        Batched fits extend under one jitted program, cached like the
         fit programs.  The extended tables' cut ladder includes the
         ORIGINAL g, so the pre-extension basis remains selectable as a
         serving tier.  ``score`` defaults to the score the fit resolved

@@ -507,9 +507,9 @@ def _approx_sym_core(s_mat, sbar0, g, n_iter, update_spectrum, eps, score,
     """Traceable Algorithm-1 body (init + polish/spectrum sweeps).
 
     Kept jit-free so callers can compose it: ``approximate_symmetric`` jits
-    it directly; the batched engine (core/eigenbasis.py) wraps it in
-    ``jit(vmap(...))`` to run Algorithm 1 for a whole stack of matrices in
-    one program (DESIGN.md §7).  ``size`` (scalar, may be traced/vmapped)
+    it directly; the batched engine (core/eigenbasis.py) runs
+    Algorithm 1 for a whole stack of matrices in one program with it
+    (DESIGN.md §7).  ``size`` (scalar, may be traced/vmapped)
     masks the greedy to the leading ``size`` coordinates so a ragged
     matrix zero-padded into the bucket fits exactly as its own-size fit
     would: padding rows/cols are zero, so every polish/Lemma-1 sweep is

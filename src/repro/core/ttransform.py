@@ -556,7 +556,7 @@ def _gen_iterate(c_mat, factors, cbar, n_iter, update_spectrum, eps):
 def _approx_gen_core(c_mat, cbar0, m, n_iter, update_spectrum, eps,
                      size=None):
     """Traceable Algorithm-1 body for the general case (jit-free so the
-    batched engine can wrap it in ``jit(vmap(...))``; DESIGN.md §7).
+    batched engine can run it over a batch in one jit; DESIGN.md §7).
     ``size`` (scalar, may be traced/vmapped) masks the Theorem-3 greedy to
     the leading ``size`` coordinates of a zero-padded ragged matrix; the
     polish and Lemma-2 refits then stay confined to the valid block by

@@ -25,7 +25,7 @@ repeating an action that demonstrably did not take.  The full state
 machine is drawn in DESIGN.md §11.
 
 Every refit path runs as a cached compiled program: fit/extend reuse the
-``lru_cache``d ``jit(vmap)`` programs of core/eigenbasis.py, the Lemma-1
+``lru_cache``d jitted programs of core/eigenbasis.py, the Lemma-1
 refresh and per-tier prefix refreshes live here (``_lemma1_program`` /
 ``_prefix_spectrum_program``) — steady-state updates trigger zero
 recompilation.

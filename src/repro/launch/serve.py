@@ -20,7 +20,7 @@ the fused analysis->scale->synthesis path, DESIGN.md §8):
       --graphs 8 --graph-n 64 --filter-steps 20
 
 CPU smoke (heterogeneous fleet — graphs of mixed sizes routed through
-power-of-two buckets, one masked jit(vmap) fit + one jitted dispatch per
+power-of-two buckets, one masked jitted fit + one jitted dispatch per
 bucket per step, DESIGN.md §10):
   python -m repro.launch.serve --fgft --ragged --graphs 9 \
       --graph-sizes 24,48,64 --filter-steps 20
@@ -160,7 +160,7 @@ def parse_args(argv=None):
                     help="serve a HETEROGENEOUS fleet: graphs of mixed "
                          "sizes (--graph-sizes) are grouped into "
                          "power-of-two buckets, each bucket fitted in one "
-                         "masked jit(vmap) and served through its own "
+                         "masked jit and served through its own "
                          "jitted tier programs (DESIGN.md §10)")
     ap.add_argument("--graph-sizes", default="24,48,64",
                     help="comma-separated graph sizes cycled over "
@@ -321,7 +321,8 @@ class FGFTServeEngine:
                  sizes=None, dynamic: bool = False, policy=None,
                  basis=None, drift_baseline=None,
                  precision: str = "f32", fused: bool = True,
-                 block_b: Optional[int] = None, placement=None):
+                 block_b: Optional[int] = None, placement=None,
+                 tier_spectra: Optional[Dict[tuple, Any]] = None):
         # deferred import: repro.core builds jnp constants at import time,
         # and launch modules must not touch jax state before mesh setup
         from repro.core import ApproxEigenbasis
@@ -404,7 +405,7 @@ class FGFTServeEngine:
                 basis = self._repin(basis)
             else:
                 self._stage_pad = tuple(int(q) for q in pinned)
-        self._install(basis, laps)
+        self._install(basis, laps, tier_spectra)
         # tracked Laplacians: the update/refit substrate in dynamic mode,
         # and what save() persists so load() can rebuild tier spectra
         # without refitting (small next to the staged tables)
@@ -491,12 +492,15 @@ class FGFTServeEngine:
                     self._live.basis, jnp.asarray(self._laps_host)))
         return jax.block_until_ready(y)
 
-    def _install(self, basis, laps):
+    def _install(self, basis, laps, tier_spectra=None):
         """Build a COMPLETE serving version (per-tier refit spectra,
         cached program bindings, filter-bank gains) and swap it in with a
         single attribute store.  ``laps``: the Laplacians the tier
         spectra refit against — the fit stack at construction, the
-        updated stack on a dynamic swap."""
+        updated stack on a dynamic swap.  ``tier_spectra`` ({(num_stages,
+        num_transforms): spectrum}) supplies cut-tier spectra computed
+        earlier against the same basis and ``laps`` (a checkpoint's);
+        cuts it lacks are refit here."""
         from repro.kernels.plan import ApplyPlan
 
         def _plan(mode, num_stages=None):
@@ -524,6 +528,8 @@ class FGFTServeEngine:
             cut = None if n_stages >= full_stages else n_stages
             if cut is None or basis.kind != "sym":
                 spec = basis.spectrum
+            elif tier_spectra and (cut, n_comp) in tier_spectra:
+                spec = jnp.asarray(tier_spectra[(cut, n_comp)], jnp.float32)
             else:
                 from repro.dynamic.refit import prefix_spectrum
                 spec = prefix_spectrum(basis, laps, cut)
@@ -856,6 +862,21 @@ class FGFTServeEngine:
                                  f"engine's own keys: {sorted(overlap)}")
             extra_meta.update(extra_metadata)
         extra_state = {"laps": jnp.asarray(self._laps_host)}
+        # the cut tiers' served spectra: a restored engine serves these
+        # instead of refitting them, so its answers do not depend on how
+        # the reader's devices would batch the Lemma-1 refit (placed
+        # serving is bitwise the saving engine's; DESIGN.md §14)
+        nb = live.basis.spectrum.shape[0] if live.basis.batched else None
+        cuts = []
+        for t in live.tiers.values():
+            cut = [int(t["num_stages"]), int(t["num_transforms"])]
+            if (live.basis.kind == "sym"
+                    and cut[0] < live.basis.fwd.num_stages
+                    and cut not in cuts):
+                cuts.append(cut)
+                extra_state["tier_spectrum_{}_{}".format(*cut)] = (
+                    jnp.asarray(np.asarray(t["spectrum"])[:nb]))
+        extra_meta["serve"]["tier_spectrum_cuts"] = sorted(cuts)
         if self.dynamic:
             extra_meta["dynamic"] = {
                 "versions": self.versions.tolist(),
@@ -908,6 +929,9 @@ class FGFTServeEngine:
         dyn_meta = meta.get("dynamic")
         if dynamic is None:
             dynamic = dyn_meta is not None
+        # saved cut-tier spectra were refit against the saved Laplacians:
+        # caller-supplied ``laps`` refit them instead
+        tier_spectra = None
         if laps is None:
             shape = ((int(basis.spectrum.shape[0]), basis.n, basis.n)
                      if basis.batched else (basis.n, basis.n))
@@ -921,6 +945,14 @@ class FGFTServeEngine:
                     "by plain ApproxEigenbasis.save, not engine.save); "
                     "pass laps= explicitly") from exc
             laps = state["laps"]
+            names = {(int(s), int(c)): f"tier_spectrum_{s}_{c}"
+                     for s, c in serve_meta.get("tier_spectrum_cuts", [])}
+            if names:
+                like = {name: jnp.zeros(basis.spectrum.shape, jnp.float32)
+                        for name in names.values()}
+                state, _, _ = restore_checkpoint(directory, like, step=step)
+                tier_spectra = {key: state[name]
+                                for key, name in names.items()}
         engine = cls(laps, n_iter=serve_meta.get("n_iter", 3),
                      backend=backend, mesh=mesh,
                      filters=filters if filters is not None
@@ -933,7 +965,8 @@ class FGFTServeEngine:
                      else serve_meta.get("precision", "f32"),
                      fused=fused if fused is not None
                      else serve_meta.get("fused", True),
-                     block_b=block_b, placement=placement)
+                     block_b=block_b, placement=placement,
+                     tier_spectra=tier_spectra)
         from dataclasses import replace as _replace
         engine._live = _replace(
             engine._live, version=int(basis.info.get("version", 0)))
@@ -1057,7 +1090,7 @@ class RaggedFGFTServeEngine:
     A production fleet arrives with many Laplacian sizes; one (B, n, n)
     stack cannot hold it.  The router groups graphs into power-of-two
     buckets (``bucket_width``), zero-pads each graph into its bucket and
-    fits every bucket in ONE masked jit(vmap) (``ApproxEigenbasis.fit``
+    fits every bucket in ONE masked jit (``ApproxEigenbasis.fit``
     with ``sizes``), so per-graph accuracy matches each graph's own-size
     fit while the fleet still compiles O(log sizes) programs instead of
     O(graphs).  Fitted per-bucket engines (and their jitted tier programs)

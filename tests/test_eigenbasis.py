@@ -38,20 +38,20 @@ def test_batched_sym_fit_matches_single_runs():
 
 
 @pytest.mark.parametrize("shards", [1, 2])
-def test_accelerator_batch_form_matches_lockstep(monkeypatch, shards):
-    """On an accelerator a batched fit runs each device's matrices in
-    turn (eigenbasis.py::_over_batch); it must return what the CPU's
-    lockstep vmap returns, split over one or two device shards."""
+def test_accelerator_batch_form_matches_lockstep(shards):
+    """A batched fit runs each device's matrices in turn (eigenbasis.py::
+    _over_batch); it must return what a lockstep vmap of the same core
+    returns, split over one or two device shards."""
     from repro.core import eigenbasis as eb
     mats = _sym_batch(4, 16, seed=3)
     sbar0 = jnp.zeros((4, 16), jnp.float32)
-    args = (48, 2, True, 1e-3, "gamma", True, False)
-    want = eb._sym_fit_program(*args)(mats, sbar0)
-    monkeypatch.setattr(eb.jax, "default_backend", lambda: "tpu")
-    got = eb._over_batch(
-        lambda s, sb: eb.gt._approx_sym_core(
-            s, sb, 48, 2, True, jnp.asarray(1e-3, s.dtype), "gamma"),
-        shards)(mats, sbar0)
+
+    def one(s, sb):
+        return eb.gt._approx_sym_core(s, sb, 48, 2, True,
+                                      jnp.asarray(1e-3, s.dtype), "gamma")
+
+    want = jax.jit(jax.vmap(one))(mats, sbar0)
+    got = jax.jit(eb._over_batch(one, shards))(mats, sbar0)
     for w, g in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
         assert w.shape == g.shape
     np.testing.assert_allclose(np.asarray(got[2]), np.asarray(want[2]),

@@ -205,6 +205,31 @@ def test_router_placed_save_load_bit_identical(placed_router, tmp_path):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_load_serves_saved_tier_spectra(tmp_path, monkeypatch):
+    """A restored engine serves the cut-tier spectra it was saved with
+    (a reader whose devices batch the Lemma-1 refit differently must not
+    serve other bits); caller-supplied Laplacians refit them instead."""
+    from repro.dynamic import refit
+    laps = np.stack([laplacian(community_graph(16, seed=s))
+                     for s in range(3)])
+    eng = FGFTServeEngine(jnp.asarray(laps), 96, n_iter=1,
+                          tiers={"draft": 0.25, "half": 0.5, "full": 1.0})
+    eng.save(tmp_path, step=1)
+    calls = []
+    real = refit.prefix_spectrum
+    monkeypatch.setattr(refit, "prefix_spectrum",
+                        lambda *a: calls.append(a[2]) or real(*a))
+    loaded = FGFTServeEngine.load(tmp_path)
+    assert calls == []
+    for name in ("draft", "half", "full"):
+        np.testing.assert_array_equal(
+            np.asarray(loaded.tiers[name]["spectrum"]),
+            np.asarray(eng.tiers[name]["spectrum"]))
+    FGFTServeEngine.load(tmp_path, laps=jnp.asarray(laps))
+    assert sorted(calls) == sorted(
+        t["num_stages"] for name, t in eng.tiers.items() if name != "full")
+
+
 def test_corrupt_placement_manifest_raises(placed_router, tmp_path):
     router, _ = placed_router
     router.save(tmp_path, step=1)

@@ -65,21 +65,8 @@ def _g_log_g(n: int) -> int:
     return int(2 * n * np.log2(n))
 
 
-@pytest.fixture
-def tpu_fit_programs(monkeypatch):
-    """Batched fits run each matrix in turn on a TPU (eigenbasis.py::
-    _over_batch) and in lockstep on the CPU, the default backend here:
-    build the TPU's form."""
-    monkeypatch.setattr(eb.jax, "default_backend", lambda: "tpu")
-    eb._sym_fit_program.cache_clear()
-    eb._gen_fit_program.cache_clear()
-    yield
-    eb._sym_fit_program.cache_clear()
-    eb._gen_fit_program.cache_clear()
-
-
 @pytest.mark.parametrize("family,n", [("sym", 2048), ("general", 1024)])
-def test_router_fit_program_compiles(one_chip, tpu_fit_programs, family, n):
+def test_router_fit_program_compiles(one_chip, family, n):
     """The masked batched fit a one-graph bucket runs (n_iter=3, the
     router's default g = 2 n log2 n)."""
     g = _g_log_g(n)
@@ -94,7 +81,7 @@ def test_router_fit_program_compiles(one_chip, tpu_fit_programs, family, n):
     assert mem.temp_size_in_bytes < 16e9
 
 
-def test_fit_split_over_four_chips_gathers_nothing(topo, tpu_fit_programs):
+def test_fit_split_over_four_chips_gathers_nothing(topo):
     """A batch sharded over a 2x2 mesh fits each chip's matrices on that
     chip: no all-gather, permute or all-to-all in the compiled program
     (the one all-reduce is the convergence test of the sweep loop)."""
