@@ -60,6 +60,11 @@ from . import ref as _ref
 from . import shear as _sh
 from . import spectral as _sp
 
+# the serving path's spans (plan compiles here, the front door's
+# dispatch stages in launch/service.py) also land in the JAX profile,
+# on the device trace's clock, when a profiled slice is running
+obs.configure(annotation=jax.profiler.TraceAnnotation)
+
 PLAN_FAMILIES = ("sym", "general")
 PLAN_MODES = ("apply", "operator", "bank")
 PLAN_BACKENDS = ("xla", "pallas")
@@ -201,6 +206,14 @@ class ApplyPlan:
         if _compile.cache_info().misses == before:
             _PLAN_HITS.inc(**self._obs_labels())
         return prog
+
+    @property
+    def program_name(self) -> str:
+        """Stable name of the compiled program (its XLA module is
+        ``jit_<name>``), so a profile attributes device time to the
+        plan: family, mode, width and ladder cut."""
+        cut = "" if self.num_stages is None else f"_k{self.num_stages}"
+        return f"plan_{self.family}_{self.mode}_n{self.n}{cut}"
 
     def _obs_labels(self) -> dict:
         return {"family": self.family, "mode": self.mode,
@@ -390,7 +403,12 @@ def _compile(plan: ApplyPlan):
                   "precision": plan.precision}):
         if plan.mode != "apply" and not plan.fused:
             return plan._three_pass()
-        return jax.jit(plan.table_op())
+        op = plan.table_op()
+
+        def program(*args):
+            return op(*args)
+        program.__name__ = program.__qualname__ = plan.program_name
+        return jax.jit(program)
 
 
 @functools.lru_cache(maxsize=None)

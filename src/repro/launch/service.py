@@ -73,6 +73,14 @@ _OBS_SHED = obs.counter("service_shed_total",
 _OBS_DISPATCHES = obs.counter("service_dispatches_total",
                               "coalesced fused dispatches",
                               ("service", "tier"))
+_OBS_SIGNAL_ELEMENTS = obs.counter(
+    "service_signal_elements_total",
+    "signal elements (rows x graph size) the dispatches carried",
+    ("service", "tier"))
+_OBS_BLOCK_ELEMENTS = obs.counter(
+    "service_block_elements_total",
+    "block elements (batch x padded rows x bucket width) the dispatches "
+    "walked", ("service", "tier"))
 _OBS_QUEUE_DEPTH = obs.gauge("service_queue_depth",
                              "queue depth sampled at the last dispatch",
                              ("service",))
@@ -260,6 +268,21 @@ class _Request:
 
 
 @dataclass(frozen=True)
+class _Layout:
+    """One dispatch's block: request -> (batch row, row offset), the
+    block's (b, r_pad, n) shape and its signal vs walked elements (the
+    bank's filter count multiplies both, so it is left out)."""
+
+    offsets: List[Tuple[int, int]]
+    batched: bool
+    b: int
+    r_pad: int
+    n: int
+    signal_elements: int
+    block_elements: int
+
+
+@dataclass(frozen=True)
 class _Route:
     """Where one graph's requests dispatch: which engine, which batch row,
     its bucket key (None for a uniform fleet) and true size."""
@@ -349,6 +372,8 @@ class AsyncFGFTService:
         self._depth_peak = 0
         self._dispatches = 0
         self._coalesced = 0
+        self._signal_elements = 0
+        self._block_elements = 0
         self._occ_max = 0
         self._maintain_ticks = 0
         self._maintain_errors = 0
@@ -516,27 +541,83 @@ class AsyncFGFTService:
                 for stage in ("queue", "batch", "execute", "total")}
         return cached
 
+    def _layout(self, batch: List[_Request]) -> "_Layout":
+        """Where each request of ``batch`` lands in the dispatch block
+        and how much of the block is signal: same-graph requests stack
+        along the row axis, each graph fills its own batch row, rows are
+        quantized to ``r_pad``."""
+        route0 = self._routes[batch[0].graph_id]
+        offsets = []                            # request -> its row slice
+        used: Dict[int, int] = {}               # batch row -> rows filled
+        signal = 0
+        for req in batch:
+            route = self._routes[req.graph_id]
+            off = used.get(route.row, 0)
+            offsets.append((route.row, off))
+            used[route.row] = off + req.signal.shape[0]
+            signal += req.signal.size           # rows x the graph's n
+        r_pad = quantize_rows(max(used.values()), self.row_quantum)
+        eng = route0.engine
+        n = eng.basis.n
+        b = int(np.shape(eng.basis.spectrum)[0]) if route0.batched else 1
+        return _Layout(offsets=offsets, batched=route0.batched, b=b,
+                       r_pad=r_pad, n=n, signal_elements=signal,
+                       block_elements=b * r_pad * n)
+
     def _run_batch(self, batch: List[_Request],
                    t_collect: Optional[float] = None):
+        """One coalesced dispatch inside a ``serve.dispatch`` span whose
+        children time the dispatcher's stages (DESIGN.md §15): build,
+        put, launch, device, pull (``_fused_dispatch``) and reply."""
         t0 = self._clock()
         if t_collect is None:
             t_collect = t0
+        tracer = obs.default_tracer()
         try:
-            results = self._fused_dispatch(batch)
+            lay = self._layout(batch)
         except Exception as exc:  # noqa: BLE001 — fail the batch, not the service
-            with self._cond:
-                self._errors += len(batch)
-            for req in batch:
-                req.future.set_exception(exc)
+            self._fail(batch, exc)
             return
+        label = "bank" if batch[0].tier == BANK else batch[0].tier
+        args = None
+        if tracer.enabled:
+            args = {"tier": label, "w": lay.n, "b": lay.b,
+                    "r_pad": lay.r_pad, "requests": len(batch),
+                    "rows": sum(req.signal.shape[0] for req in batch),
+                    "signal_elements": lay.signal_elements,
+                    "block_elements": lay.block_elements}
+        with tracer.span("serve.dispatch", cat="serve", args=args):
+            try:
+                y, version = self._fused_dispatch(batch, lay)
+            except Exception as exc:  # noqa: BLE001 — fail the batch, not the service
+                self._fail(batch, exc)
+                return
+            with tracer.span("serve.reply", cat="serve"):
+                self._reply(batch, lay, y, version, label, t_collect, t0)
+
+    def _fail(self, batch: List[_Request], exc: Exception):
+        with self._cond:
+            self._errors += len(batch)
+        for req in batch:
+            req.future.set_exception(exc)
+
+    def _reply(self, batch: List[_Request], lay: "_Layout", y, version,
+               label: str, t_collect: float, t0: float):
+        """Crop each request's answer out of the host block ``y``, do
+        the batch's bookkeeping and resolve its futures."""
+        results = []
+        for req, (row, off) in zip(batch, lay.offsets):
+            r, size = req.signal.shape
+            yb = y[row] if lay.batched else y
+            results.append(yb[..., off:off + r, :size])
         t1 = self._clock()
-        tier = batch[0].tier
-        label = "bank" if tier == BANK else tier
         with self._cond:
             self._dispatches += 1
             self._coalesced += len(batch)
             self._occ_max = max(self._occ_max, len(batch))
             self._served += len(batch)
+            self._signal_elements += lay.signal_elements
+            self._block_elements += lay.block_elements
             depth_now = len(self._queue)
         tracer = obs.default_tracer()
         if obs.recording_enabled():
@@ -548,9 +629,16 @@ class AsyncFGFTService:
             # fig15 QPS gate caught
             dchild = self._obs_dispatch.get(label)
             if dchild is None:
-                dchild = self._obs_dispatch[label] = \
-                    _OBS_DISPATCHES.labels(service=self.name, tier=label)
-            dchild.inc()
+                dchild = self._obs_dispatch[label] = (
+                    _OBS_DISPATCHES.labels(service=self.name, tier=label),
+                    _OBS_SIGNAL_ELEMENTS.labels(service=self.name,
+                                                tier=label),
+                    _OBS_BLOCK_ELEMENTS.labels(service=self.name,
+                                               tier=label))
+            dispatches, signal, block = dchild
+            dispatches.inc()
+            signal.inc(lay.signal_elements)
+            block.inc(lay.block_elements)
             self._obs_depth.set(depth_now)
             stage_obs = self._stage_children(label)
             stage_obs["batch"].observe_many(t0 - t_collect, len(batch))
@@ -560,7 +648,7 @@ class AsyncFGFTService:
             stage_obs["total"].observe_seq(
                 [t1 - req.t_submit for req in batch])
         tid = threading.get_ident()
-        for req, (y, version) in zip(batch, results):
+        for req, yr in zip(batch, results):
             queue_s = t0 - req.t_submit
             self.latency.record(f"{label}/queue", queue_s)
             self.latency.record(f"{label}/service", t1 - t0)
@@ -585,56 +673,47 @@ class AsyncFGFTService:
                      {"graph": req.graph_id, "tier": label,
                       "version": version, "batch_size": len(batch)})))
             req.future.set_result(ServeResult(
-                y=y, graph_id=req.graph_id, tier=label, version=version,
+                y=yr, graph_id=req.graph_id, tier=label, version=version,
                 queue_s=queue_s, service_s=t1 - t0,
                 total_s=t1 - req.t_submit, batch_size=len(batch),
                 trace_id=req.trace_id))
 
-    def _fused_dispatch(self, batch: List[_Request]):
+    def _fused_dispatch(self, batch: List[_Request], lay: "_Layout"):
         """ONE fused engine dispatch answering every request in ``batch``
-        (all share a dispatch group): same-graph requests stack along the
-        row axis, each graph fills its own batch row, rows are quantized,
-        and the result is cropped back per request.  Rows are independent
-        under every kernel in the stack (they broadcast over the leading
-        axes), so the coalesced answer matches the per-request loop —
-        bitwise for the G family (tests/test_service.py)."""
+        (all share a dispatch group), laid out by ``lay``; returns the
+        whole answer block on the host and its serving version.  Rows
+        are independent under every kernel in the stack (they broadcast
+        over the leading axes), so the coalesced answer matches the
+        per-request loop — bitwise for the G family
+        (tests/test_service.py)."""
         import jax.numpy as jnp
-        route0 = self._routes[batch[0].graph_id]
-        eng, tier = route0.engine, batch[0].tier
-        offsets = []                            # request -> its row slice
-        used: Dict[int, int] = {}               # batch row -> rows filled
-        for req in batch:
-            row = self._routes[req.graph_id].row
-            off = used.get(row, 0)
-            offsets.append((row, off))
-            used[row] = off + req.signal.shape[0]
-        r_pad = quantize_rows(max(used.values()), self.row_quantum)
-        n = eng.basis.n
-        if route0.batched:
-            b = int(np.asarray(eng.basis.spectrum).shape[0])
-            block = np.zeros((b, r_pad, n), np.float32)
-        else:
-            block = np.zeros((r_pad, n), np.float32)
-        for req, (row, off) in zip(batch, offsets):
-            r, size = req.signal.shape
-            dst = block[row] if route0.batched else block
-            dst[off:off + r, :size] = req.signal
-        x = jnp.asarray(block)
-        if tier == BANK:
-            y, version = eng.step_bank_versioned(x)
-        else:
-            y, version = eng.step_versioned(x, self._h, tier=tier)
-        y = np.asarray(y)                       # device sync: work is done
-        results = []
-        for req, (row, off) in zip(batch, offsets):
-            r, size = req.signal.shape
+        eng = self._routes[batch[0].graph_id].engine
+        tier = batch[0].tier
+        tracer = obs.default_tracer()
+        with tracer.span("serve.build", cat="serve"):
+            shape = (lay.r_pad, lay.n)
+            block = np.zeros((lay.b,) + shape if lay.batched else shape,
+                             np.float32)
+            for req, (row, off) in zip(batch, lay.offsets):
+                r, size = req.signal.shape
+                dst = block[row] if lay.batched else block
+                dst[off:off + r, :size] = req.signal
+        with tracer.span("serve.put", cat="serve"):
+            x = jnp.asarray(block)
+        with tracer.span("serve.launch", cat="serve"):
             if tier == BANK:
-                yb = y[row] if route0.batched else y
-                results.append((yb[:, off:off + r, :size], version))
+                y, version = eng.step_bank_versioned(x)
             else:
-                yt = y[row] if route0.batched else y
-                results.append((yt[off:off + r, :size], version))
-        return results
+                y, version = eng.step_versioned(x, self._h, tier=tier)
+        with tracer.span("serve.device", cat="serve"):
+            # queue the answer's copy to the host behind the step first,
+            # as a bare pull would, so that waiting for the device on its
+            # own adds no round trip
+            y.copy_to_host_async()
+            y.block_until_ready()
+        with tracer.span("serve.pull", cat="serve"):
+            y = np.asarray(y)
+        return y, version
 
     # -- background maintenance (dynamic engines; DESIGN.md §11) -----------
 
@@ -721,6 +800,7 @@ class AsyncFGFTService:
             self._errors = 0
             self._depth_peak = len(self._queue)
             self._dispatches = self._coalesced = self._occ_max = 0
+            self._signal_elements = self._block_elements = 0
             self._maintain_ticks = self._maintain_errors = 0
             self._swaps = 0
         self.latency = LatencyRecorder(max_samples=self.latency.max_samples)
@@ -739,6 +819,10 @@ class AsyncFGFTService:
                           "peak": self._depth_peak,
                           "max": self.max_queue},
                 "dispatches": self._dispatches,
+                # block fill: signal_elements / block_elements is the
+                # share of the walked (b, r_pad, w) blocks that is signal
+                "signal_elements": self._signal_elements,
+                "block_elements": self._block_elements,
                 "batch": {
                     "cap": self.max_batch,
                     "occupancy_mean": (self._coalesced / self._dispatches

@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import json
 from pathlib import Path
-from typing import Callable, Optional
+from typing import Any, Callable, Optional
 
 from repro.obs.metrics import (Counter, Gauge, Histogram,
                                MetricsRegistry, bucket_counts, counter,
@@ -46,15 +46,20 @@ METRICS_DIR_ENV = "REPRO_METRICS_DIR"
 
 
 def configure(enabled: Optional[bool] = None,
-              trace_clock: Optional[Callable[[], float]] = None) -> None:
+              trace_clock: Optional[Callable[[], float]] = None,
+              annotation: Optional[Callable[..., Any]] = None) -> None:
     """One switch for the whole layer: ``enabled`` toggles metric
     recording AND the default tracer; ``trace_clock`` swaps the default
-    tracer's clock (tests inject a fake)."""
+    tracer's clock (tests inject a fake); ``annotation`` installs the
+    default tracer's profiler mirror (``kernels/plan.py`` installs
+    ``jax.profiler.TraceAnnotation``; this package never imports jax)."""
     if enabled is not None:
         set_enabled(enabled)
         default_tracer().enabled = bool(enabled)
     if trace_clock is not None:
         default_tracer().clock = trace_clock
+    if annotation is not None:
+        default_tracer().annotation = annotation
 
 
 def export_metrics(directory, merge: bool = True) -> dict:

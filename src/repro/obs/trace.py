@@ -20,6 +20,16 @@ back.  Two properties carry the whole design:
     export; ``spans()`` returns copies so callers can never mutate the
     ring through a snapshot.
 
+``span()`` is also mirrored into an injected profiler annotation
+(``Tracer.annotation``: a factory ``annotation(name, **args)`` returning
+a context manager, ``jax.profiler.TraceAnnotation`` once the serving
+path installs it through ``obs.configure(annotation=...)``), so a span
+opened inside a profiled slice lands in the JAX profile on the
+profiler's clock with its args as event stats.  This module never
+imports jax; with no factory (the default) nothing is mirrored.
+Retrospective spans (``add_span``/``add_spans``) stay in the ring only:
+their endpoints come from another clock and often another thread.
+
 Exports: ``export_chrome_trace`` writes the Chrome trace-event JSON
 (``{"traceEvents": [...]}``, timestamps in µs) that chrome://tracing
 and Perfetto load directly; ``export_jsonl`` writes one span per line
@@ -32,15 +42,15 @@ it through queue → coalesce → dispatch → reply so the id on a
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import json
 import os
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
 from pathlib import Path
-from typing import Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 __all__ = ["Tracer", "default_tracer", "new_trace_id"]
 
@@ -59,14 +69,20 @@ class Tracer:
     """Bounded ring buffer of spans with an injectable clock."""
 
     def __init__(self, clock: Callable[[], float] = time.monotonic,
-                 capacity: int = DEFAULT_CAPACITY, enabled: bool = True):
+                 capacity: int = DEFAULT_CAPACITY, enabled: bool = True,
+                 annotation: Optional[Callable[..., Any]] = None):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.clock = clock
         self.capacity = capacity
         self.enabled = enabled
+        #: profiler mirror of ``span()``: ``annotation(name, **args)``
+        #: returns a context manager entered around the span (None: the
+        #: ring only)
+        self.annotation = annotation
         self._lock = threading.Lock()
         self._ring: deque = deque(maxlen=capacity)
+        self._disabled_span = contextlib.nullcontext(self)
 
     # -- recording ----------------------------------------------------
     def now(self) -> float:
@@ -121,22 +137,16 @@ class Tracer:
         with self._lock:
             self._ring.append(rec)
 
-    @contextmanager
     def span(self, name: str, *, cat: str = "",
              trace_id: Optional[int] = None,
              args: Optional[Dict[str, object]] = None):
-        """Time a block on the tracer's own clock.  Disabled tracers
-        skip the clock reads entirely (the fig15 QPS gate measures the
-        disabled path)."""
+        """Time a block on the tracer's own clock and mirror it into the
+        profiler annotation, if one is installed; ``with`` yields the
+        tracer.  A disabled tracer costs one branch: no clock read, no
+        annotation (the fig15 QPS gate measures the disabled path)."""
         if not self.enabled:
-            yield self
-            return
-        t0 = self.clock()
-        try:
-            yield self
-        finally:
-            self.add_span(name, t0, self.clock(), cat=cat,
-                          trace_id=trace_id, args=args)
+            return self._disabled_span
+        return _Span(self, name, cat, trace_id, args)
 
     # -- queries ------------------------------------------------------
     def spans(self, cat: Optional[str] = None,
@@ -196,6 +206,37 @@ class Tracer:
             for r in self.spans():
                 fh.write(json.dumps(r) + "\n")
         return path
+
+
+class _Span:
+    """One ``Tracer.span()``: the ring record plus its profiler mirror,
+    closed (and recorded) on the way out of an exception too."""
+
+    __slots__ = ("tracer", "name", "cat", "trace_id", "args", "t0",
+                 "mirror")
+
+    def __init__(self, tracer: Tracer, name: str, cat: str,
+                 trace_id: Optional[int],
+                 args: Optional[Dict[str, object]]):
+        self.tracer, self.name, self.cat = tracer, name, cat
+        self.trace_id, self.args = trace_id, args
+        self.mirror = None
+
+    def __enter__(self) -> Tracer:
+        tracer = self.tracer
+        if tracer.annotation is not None:
+            self.mirror = tracer.annotation(self.name, **(self.args or {}))
+            self.mirror.__enter__()
+        self.t0 = tracer.clock()
+        return tracer
+
+    def __exit__(self, *exc) -> bool:
+        tracer = self.tracer
+        tracer.add_span(self.name, self.t0, tracer.clock(), cat=self.cat,
+                        trace_id=self.trace_id, args=self.args)
+        if self.mirror is not None:
+            self.mirror.__exit__(*exc)
+        return False
 
 
 _DEFAULT = Tracer()

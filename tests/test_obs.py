@@ -387,6 +387,90 @@ def test_tracer_span_contextmanager_and_event_use_own_clock():
     assert e["ph"] == "i" and e["ts"] == 2.0 and e["dur"] == 0.0
 
 
+class FakeAnnotation:
+    """An injected profiler-annotation factory that logs what the
+    tracer asks of it: (enter, name, args) and (exit, name, exc type)."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **args):
+        log = self.log
+
+        class Mirror:
+            def __enter__(self):
+                log.append(("enter", name, args))
+
+            def __exit__(self, kind, value, tb):
+                log.append(("exit", name, kind))
+                return False
+
+        return Mirror()
+
+
+def test_tracer_span_mirrors_into_injected_annotation():
+    fake = FakeAnnotation()
+    tr = Tracer(clock=FakeClock(step=1.0), annotation=fake)
+    with tr.span("outer", cat="serve", args={"w": 8, "tier": "full"}):
+        with tr.span("inner"):
+            pass
+    assert fake.log == [("enter", "outer", {"w": 8, "tier": "full"}),
+                        ("enter", "inner", {}),
+                        ("exit", "inner", None),
+                        ("exit", "outer", None)]
+    # an exception closes both the mirror and the ring record
+    with pytest.raises(KeyError):
+        with tr.span("boom"):
+            raise KeyError("x")
+    assert fake.log[-2:] == [("enter", "boom", {}),
+                             ("exit", "boom", KeyError)]
+    assert [s["name"] for s in tr.spans()] == ["inner", "outer", "boom"]
+    (outer,) = tr.spans(name="outer")
+    assert outer["args"] == {"w": 8, "tier": "full"}
+    assert outer["cat"] == "serve"
+
+
+def test_tracer_span_disabled_or_without_factory_mirrors_nothing():
+    reads = []
+
+    def clock():
+        reads.append(1)
+        return 0.0
+
+    fake = FakeAnnotation()
+    off = Tracer(clock=clock, annotation=fake, enabled=False)
+    with off.span("x", args={"k": 1}) as got:
+        assert got is off
+    assert fake.log == [] and reads == [] and len(off) == 0
+    plain = Tracer(clock=FakeClock(step=1.0))
+    assert plain.annotation is None
+    with plain.span("y") as got:
+        assert got is plain
+    assert [s["name"] for s in plain.spans()] == ["y"]
+    # retrospective spans never reach the mirror
+    on = Tracer(annotation=fake)
+    on.add_span("r", 0.0, 1.0)
+    on.add_spans([("r2", 1.0, 2.0, "", None, None, None)])
+    assert fake.log == [] and len(on) == 2
+
+
+def test_obs_imports_no_jax():
+    import os
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    code = ("import sys, repro.obs; "
+            "print(sorted(m for m in sys.modules if m == 'jax' "
+            "or m.startswith(('jax.', 'repro.')) and "
+            "not m.startswith('repro.obs')))")
+    out = subprocess.run([sys.executable, "-c", code],
+                         env={**os.environ, "PYTHONPATH": src},
+                         capture_output=True, text=True, timeout=60)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "[]"
+
+
 def test_trace_exports_round_trip(tmp_path):
     tr = Tracer(clock=FakeClock(step=1.0))
     tr.add_span("req", 1.0, 3.5, cat="serve", trace_id=9,
@@ -460,6 +544,55 @@ def test_service_spans_telescope_exactly(sym_engine):
         assert tot["args"]["tier"] == res.tier == "full"
         assert tot["args"]["batch_size"] == res.batch_size
         assert q["args"] == bt["args"] == ex["args"] == {}
+
+
+SERVE_STAGES = ["serve.build", "serve.put", "serve.launch",
+                "serve.device", "serve.pull", "serve.reply"]
+
+
+def test_service_dispatch_emits_stage_spans_in_order(sym_engine):
+    from repro.launch.service import AsyncFGFTService
+    tracer = obs.default_tracer()
+    fake = FakeAnnotation()
+    saved = tracer.annotation
+    tracer.annotation = fake
+    try:
+        svc = AsyncFGFTService(sym_engine, clock=FakeClock(step=1.0),
+                               auto_start=False, max_batch=4,
+                               name="obs-stages")
+        rng = np.random.default_rng(3)
+        futs = [svc.submit(g, rng.standard_normal((r, 16)).astype(
+            np.float32)) for g, r in ((0, 2), (2, 3), (0, 1))]
+        tracer.clear()
+        assert svc.drain_once() == 3
+        for f in futs:
+            f.result(timeout=0)
+        svc.close()
+    finally:
+        tracer.annotation = saved
+    serve = [s for s in tracer.spans() if s["name"].startswith("serve.")]
+    (disp,) = [s for s in serve if s["name"] == "serve.dispatch"]
+    kids = sorted((s for s in serve if s["name"] != "serve.dispatch"),
+                  key=lambda s: s["ts"])
+    assert [s["name"] for s in kids] == SERVE_STAGES
+    assert all(s["cat"] == "serve" for s in serve)
+    # the children tile the dispatch in order, inside it
+    for a, b in zip(kids, kids[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"]
+    assert disp["ts"] <= kids[0]["ts"]
+    assert kids[-1]["ts"] + kids[-1]["dur"] <= disp["ts"] + disp["dur"]
+    # rows 2 + 1 stack on graph 0, 3 land on graph 2: r_pad = 8
+    assert disp["args"] == {
+        "tier": "full", "w": 16, "b": 3, "r_pad": 8, "requests": 3,
+        "rows": 6, "signal_elements": 6 * 16,
+        "block_elements": 3 * 8 * 16}
+    # the profiler mirror saw the same nesting, one span per stage
+    names = [(op, name) for op, name, _ in fake.log]
+    assert names == ([("enter", "serve.dispatch")]
+                     + [(op, n) for n in SERVE_STAGES
+                        for op in ("enter", "exit")]
+                     + [("exit", "serve.dispatch")])
+    assert fake.log[0][2] == disp["args"]
 
 
 def test_service_stats_embed_obs_snapshot(sym_engine):

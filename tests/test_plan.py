@@ -289,6 +289,23 @@ def test_plan_cache_identity_and_canonicalization():
     assert plan_cache_size() == size
 
 
+@pytest.mark.parametrize("plan, name", [
+    (ApplyPlan(family="sym", mode="operator", n=16), "plan_sym_operator_n16"),
+    (ApplyPlan(family="general", mode="bank", n=16, num_stages=3),
+     "plan_general_bank_n16_k3"),
+])
+def test_plan_program_has_a_stable_module_name(plan, name):
+    # the profile's XLA Modules line names a serving program by its plan
+    fwd, bwd, spec = _pair(plan.family, 16, 32)
+    assert plan.program_name == name
+    gains = (jnp.ones((2, 16), jnp.float32) if plan.mode == "bank"
+             else 1.0 / (1.0 + spec))
+    x = jnp.ones((3, 16), jnp.float32)
+    text = plan.program().lower(plan.prepare(fwd), plan.prepare(bwd),
+                                gains, x).as_text()
+    assert f"module @jit_{name} " in text
+
+
 def test_plan_validation():
     with pytest.raises(ValueError):
         ApplyPlan(family="nope", mode="apply", n=8)

@@ -184,6 +184,18 @@ def ragged_engine():
                                  n_iter=1, tiers={"full": 1.0})
 
 
+@pytest.fixture(scope="module")
+def ragged3_engine():
+    def s(n, seed):
+        x = np.random.default_rng(seed).standard_normal((n, n)).astype(
+            np.float32)
+        return x + x.T
+
+    # sizes 5/6/7 -> one bucket of width 8 holding all three graphs
+    return RaggedFGFTServeEngine([s(5, 0), s(6, 1), s(7, 2)], 12,
+                                 n_iter=1, tiers={"full": 1.0})
+
+
 def signals_for(engine, gid, rows, seed):
     route_n = (engine.sizes[gid] if isinstance(engine, RaggedFGFTServeEngine)
                else engine.basis.n)
@@ -347,7 +359,8 @@ def test_dispatch_error_fails_batch_not_service(sym_engine, monkeypatch):
     boom = svc.submit(0, x)
     monkeypatch.setattr(
         svc, "_fused_dispatch",
-        lambda batch: (_ for _ in ()).throw(RuntimeError("device lost")))
+        lambda batch, lay: (_ for _ in ()).throw(
+            RuntimeError("device lost")))
     svc.drain_once()
     with pytest.raises(RuntimeError, match="device lost"):
         boom.result(timeout=0)
@@ -459,6 +472,40 @@ def test_equivalence_ragged_buckets(ragged_engine):
             if gid in (0, 2)] == [3, 3, 3]
     assert [r.batch_size for r, (gid, *_) in zip(got, reqs)
             if gid == 1] == [2, 2]
+
+
+@pytest.mark.parametrize("requests, signal, block", [
+    # one request: 3 rows of n = 5 in a (3 graphs, 8 rows, w = 8) block
+    ([(0, 3)], 3 * 5, 3 * 8 * 8),
+    # two graphs of the 3-graph bucket, each on its own batch row
+    ([(0, 3), (2, 3)], 3 * 5 + 3 * 7, 3 * 8 * 8),
+    # two requests on one graph stack to 9 rows, quantized to 16
+    ([(1, 3), (1, 6)], 9 * 6, 3 * 16 * 8),
+], ids=["one-request", "two-graphs", "one-graph-stacked"])
+def test_fill_counters_match_block_arithmetic(ragged3_engine, requests,
+                                              signal, block):
+    from repro import obs
+    name = f"fill-{len(requests)}-{requests[-1][0]}"
+    svc = AsyncFGFTService(ragged3_engine, clock=FakeClock(),
+                           auto_start=False, max_batch=8, name=name)
+    futs = [svc.submit(gid, signals_for(ragged3_engine, gid, rows, gid))
+            for gid, rows in requests]
+    assert drain_all(svc) == [len(requests)]
+    for f in futs:
+        f.result(timeout=0)
+    st = svc.stats()
+    svc.close()
+    assert st["signal_elements"] == signal
+    assert st["block_elements"] == block
+    snap = obs.default_registry().collect()
+    for key, want in (("service_signal_elements_total", signal),
+                      ("service_block_elements_total", block)):
+        mine = [s for s in snap[key]["series"]
+                if s["labels"] == {"service": name, "tier": "full"}]
+        assert [s["value"] for s in mine] == [want]
+    svc.reset_stats()
+    assert svc.stats()["signal_elements"] == 0
+    assert svc.stats()["block_elements"] == 0
 
 
 # ---------------------------------------------------------------------------
