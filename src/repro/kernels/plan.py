@@ -21,6 +21,13 @@ tuples, i.e. the device arrays without the host ``cuts``/``n`` tail —
   * mode "operator":  ``program(fwd_tables, bwd_tables, diag, x)``
   * mode "bank":      ``program(fwd_tables, bwd_tables, gains, x)``
 
+A ``row`` plan (batched tables, no placement) takes one more argument,
+the batch row, last: ``program(..., x, row)``.  It walks that one
+graph's (R, n) block against the whole (B, S, P) tables and the (B, ...)
+spectrum or gains, each indexed at ``row`` inside the program, so one
+compiled program serves every graph of a bucket and the tables are
+never copied per graph.
+
 Precision policy (DESIGN.md §13): ``precision="bf16"`` stores the value
 tables in bfloat16 (``prepare`` casts them; indices stay int32) while
 ACCUMULATING in f32 — the compiled program upcasts the signal to f32
@@ -114,6 +121,9 @@ class ApplyPlan:
     #: swap that keeps shapes AND placement recompiles nothing (the jit
     #: argument layout is unchanged).
     placement: Optional[BucketPlacement] = None
+    #: walk one graph of the batched tables, its row an argument (see
+    #: the module docstring): the serving front door's per-graph blocks
+    row: bool = False
 
     def __post_init__(self):
         if self.family not in PLAN_FAMILIES:
@@ -140,6 +150,9 @@ class ApplyPlan:
             raise ValueError("placement requires batched=True (the batch "
                              "axis is what partitions over the bucket's "
                              "devices)")
+        if self.row and (not self.batched or self.placement is not None):
+            raise ValueError("row requires batched=True and no placement "
+                             "(a row index must not cross devices)")
         if self.mode != "apply" and self.keep != "head":
             # operator/bank legs derive their own orientation; canonical
             # keep="head" keeps equivalent plans on one cache entry
@@ -211,9 +224,10 @@ class ApplyPlan:
     def program_name(self) -> str:
         """Stable name of the compiled program (its XLA module is
         ``jit_<name>``), so a profile attributes device time to the
-        plan: family, mode, width and ladder cut."""
+        plan: family, mode, width, ladder cut and row selection."""
         cut = "" if self.num_stages is None else f"_k{self.num_stages}"
-        return f"plan_{self.family}_{self.mode}_n{self.n}{cut}"
+        row = "_row" if self.row else ""
+        return f"plan_{self.family}_{self.mode}_n{self.n}{cut}{row}"
 
     def _obs_labels(self) -> dict:
         return {"family": self.family, "mode": self.mode,
@@ -225,18 +239,9 @@ class ApplyPlan:
         scorer wraps the operator leg this way) without nesting a second
         dispatch cache."""
         op = self._dispatch()
-        if self.precision == "f32":
-            return op
-
-        def accumulate_f32(*args):
-            # bf16 policy: tables are stored bf16 but the staged walk
-            # runs on an f32 signal (the kernels cast entries to the
-            # signal dtype), so accumulation never drops below f32
-            x = args[-1]
-            y = op(*args[:-1], x.astype(jnp.float32))
-            return y.astype(x.dtype)
-
-        return accumulate_f32
+        if self.precision != "f32":
+            op = _accumulate_f32(op)
+        return _select_row(op) if self.row else op
 
     # -- one-shot conveniences (prepare + program + call) ------------------
 
@@ -274,22 +279,25 @@ class ApplyPlan:
         kernel entry points, reshape conventions and cut orientations
         are wired; every engine and apply path inherits it)."""
         cut, keep, n = self.num_stages, self.keep, self.n
+        # a row plan walks one graph: the single-matrix entry points on
+        # the row's tables (``_select_row`` indexes them)
+        batched = self.batched and not self.row
         if self.mode == "apply":
             if self.backend == "xla":
                 fns = {("sym", False): _ref.staged_g_apply,
                        ("sym", True): _ref.batched_g_apply,
                        ("general", False): _ref.staged_t_apply,
                        ("general", True): _ref.batched_t_apply}
-                fn = fns[self.family, self.batched]
+                fn = fns[self.family, batched]
                 return lambda t, x: fn(self._staged(t), x, cut, keep)
             fns = {("sym", False): _bf.butterfly_apply,
                    ("sym", True): _bf.batched_butterfly_apply,
                    ("general", False): _sh.shear_apply,
                    ("general", True): _sh.batched_shear_apply}
-            fn = fns[self.family, self.batched]
+            fn = fns[self.family, batched]
             kw = dict(block_b=self._resolved_block_b(), num_stages=cut,
                       keep=keep)
-            if self.batched:
+            if batched:
                 return lambda t, x: fn(
                     self._staged(t), x.reshape(x.shape[0], -1, n),
                     **kw).reshape(x.shape)
@@ -301,16 +309,16 @@ class ApplyPlan:
                        ("sym", True): _ref.batched_sym_operator_apply,
                        ("general", False): _ref.gen_operator_apply,
                        ("general", True): _ref.batched_gen_operator_apply}
-                fn = fns[self.family, self.batched]
+                fn = fns[self.family, batched]
                 return lambda ft, bt, d, x: fn(
                     self._staged(ft), self._staged(bt), d, x, cut)
             fns = {("sym", False): _bf.sym_operator_apply,
                    ("sym", True): _bf.batched_sym_operator_apply,
                    ("general", False): _sh.gen_operator_apply,
                    ("general", True): _sh.batched_gen_operator_apply}
-            fn = fns[self.family, self.batched]
+            fn = fns[self.family, batched]
             kw = dict(block_b=self._resolved_block_b(), num_stages=cut)
-            if self.batched:
+            if batched:
                 return lambda ft, bt, d, x: fn(
                     self._staged(ft), self._staged(bt), d,
                     x.reshape(x.shape[0], -1, n), **kw).reshape(x.shape)
@@ -324,17 +332,17 @@ class ApplyPlan:
                    ("sym", True): _ref.batched_sym_filter_bank_apply,
                    ("general", False): _ref.gen_filter_bank_apply,
                    ("general", True): _ref.batched_gen_filter_bank_apply}
-            fn = fns[self.family, self.batched]
+            fn = fns[self.family, batched]
             return lambda ft, bt, g, x: fn(
                 self._staged(ft), self._staged(bt), g, x, cut)
         fns = {("sym", False): _sp.sym_filter_bank_apply,
                ("sym", True): _sp.batched_sym_filter_bank_apply,
                ("general", False): _sp.gen_filter_bank_apply,
                ("general", True): _sp.batched_gen_filter_bank_apply}
-        fn = fns[self.family, self.batched]
+        fn = fns[self.family, batched]
         kw = dict(block_b=self._resolved_block_b(), num_stages=cut)
 
-        if self.batched:
+        if batched:
             def bank_op(ft, bt, g, x):
                 out = fn(self._staged(ft), self._staged(bt), g,
                          x.reshape(x.shape[0], -1, n), **kw)
@@ -352,26 +360,55 @@ class ApplyPlan:
         synthesis as separate dispatches through cached "apply" plans (a
         bank re-runs its analysis per filter) — the exact pre-fusion
         execution shape, kept callable so fused-vs-three-pass parity and
-        speedup stay measurable through one API (fig13)."""
+        speedup stay measurable through one API (fig13).  A row plan's
+        legs are row "apply" plans, and its diagonal or gains are the
+        row's."""
         a_keep, s_keep = leg_orientation(self.family)
         analysis = replace(self, mode="apply", keep=a_keep,
                            fused=True).program()
         synthesis = replace(self, mode="apply", keep=s_keep,
                             fused=True).program()
-        scale = _scale_program(self.batched)
+        batched = self.batched and not self.row
+        scale = _scale_program(batched)
         if self.mode == "operator":
-            def three_pass(fwd_t, bwd_t, d, x):
-                return synthesis(fwd_t, scale(d, analysis(bwd_t, x)))
+            def three_pass(fwd_t, bwd_t, d, x, *row):
+                d = d[row[0]] if row else d
+                return synthesis(fwd_t, scale(d, analysis(bwd_t, x, *row)),
+                                 *row)
             return three_pass
 
-        def three_pass_bank(fwd_t, bwd_t, gains, x):
-            num_filters = gains.shape[1 if self.batched else 0]
-            outs = [synthesis(fwd_t, scale(gains[:, f] if self.batched
+        def three_pass_bank(fwd_t, bwd_t, gains, x, *row):
+            gains = gains[row[0]] if row else gains
+            num_filters = gains.shape[1 if batched else 0]
+            outs = [synthesis(fwd_t, scale(gains[:, f] if batched
                                            else gains[f],
-                                           analysis(bwd_t, x)))
+                                           analysis(bwd_t, x, *row)), *row)
                     for f in range(num_filters)]
-            return jnp.stack(outs, axis=1 if self.batched else 0)
+            return jnp.stack(outs, axis=1 if batched else 0)
         return three_pass_bank
+
+
+def _accumulate_f32(op):
+    """bf16 policy: tables are stored bf16 but the staged walk runs on
+    an f32 signal (the kernels cast entries to the signal dtype), so
+    accumulation never drops below f32.  ``x`` is the last argument."""
+    def accumulate_f32(*args):
+        x = args[-1]
+        y = op(*args[:-1], x.astype(jnp.float32))
+        return y.astype(x.dtype)
+    return accumulate_f32
+
+
+def _select_row(op):
+    """``op`` over one graph's operands -> ``(*operands, x, row)`` over
+    the batched ones: every table leaf and the diagonal or gains are
+    indexed at ``row`` inside the program."""
+    def row_op(*args):
+        *operands, x, row = args
+        pick = functools.partial(jax.lax.dynamic_index_in_dim, index=row,
+                                 axis=0, keepdims=False)
+        return op(*jax.tree.map(pick, operands), x)
+    return row_op
 
 
 #: per-plan cache telemetry (DESIGN.md §15): misses increment INSIDE

@@ -115,6 +115,14 @@ class _LiveVersion:
     bank_gains: Any
     bank_fn: Any
     version: int
+    #: per-graph programs (``ApplyPlan.row``): tier -> program, and the
+    #: bank's; empty / None where the engine serves no row steps
+    row_fns: Dict[str, Any]
+    bank_row_fn: Any
+    #: tier -> (h, gains h(spectrum) with pad columns zeroed), the last
+    #: response each tier served: a dispatch's per-graph launches, and
+    #: the dispatches after it, filter the spectrum once
+    gains: Dict[str, tuple]
 
 
 def parse_tiers(spec: str) -> Dict[str, float]:
@@ -352,6 +360,11 @@ class FGFTServeEngine:
             # stall every other bucket's hot path)
             mesh = placement.mesh()
         self.mesh = mesh
+        #: whether ``step_versioned(..., row=g)`` walks graph g alone: the
+        #: batch sits on one device (unplaced, a mesh of at most one
+        #: device), so a row index never crosses devices
+        self.row_steps = placement is None and (mesh is None
+                                                or mesh.size == 1)
         self._filters = filters
         self._tier_spec = dict(tiers or {"full": 1.0})
         self._n_iter = n_iter
@@ -406,6 +419,10 @@ class FGFTServeEngine:
             else:
                 self._stage_pad = tuple(int(q) for q in pinned)
         self._install(basis, laps, tier_spectra)
+        # the row programs' index argument, one device scalar per graph
+        self._row_ids = (tuple(jnp.asarray(i, jnp.int32) for i in
+                               range(np.shape(basis.spectrum)[0]))
+                         if self.row_steps and basis.batched else ())
         # tracked Laplacians: the update/refit substrate in dynamic mode,
         # and what save() persists so load() can rebuild tier spectra
         # without refitting (small next to the staged tables)
@@ -477,13 +494,20 @@ class FGFTServeEngine:
 
     def warmup(self, signals: jnp.ndarray):
         """Compile the full serving + maintenance program suite up front
-        (tier programs, bank, drift scorer, Lemma-1 refresh), so the
-        first real update round runs at steady-state cost."""
+        (tier programs, bank, their per-graph programs on ``row_steps``
+        engines at the block's row count, drift scorer, Lemma-1
+        refresh), so the first real update round runs at steady-state
+        cost."""
         for name in self._live.tiers:
             y = self.step(signals, tier=name)
             self.stats["steps"][name] -= 1      # warmup doesn't count
+            if self._row_ids:
+                y = self.step_versioned(signals[0], tier=name, row=0)[0]
+                self.stats["steps"][name] -= 1
         if self._live.bank is not None:
             y = self.step_bank(signals)
+            if self._row_ids:
+                y = self.step_bank_versioned(signals[0], row=0)[0]
         if self.dynamic:
             self.drift()
             if self._kind == "sym":
@@ -503,13 +527,13 @@ class FGFTServeEngine:
         cuts it lacks are refit here."""
         from repro.kernels.plan import ApplyPlan
 
-        def _plan(mode, num_stages=None):
+        def _plan(mode, num_stages=None, row=False):
             return ApplyPlan(family=basis.kind, mode=mode, n=basis.n,
                              batched=basis.batched, backend=self.backend,
                              num_stages=num_stages,
                              precision=self._precision,
                              fused=self._fused, block_b=self._block_b,
-                             placement=self.placement)
+                             placement=self.placement, row=row)
 
         def _place(arr):
             # per-graph operands (tier spectra, bank gains) pad with zero
@@ -521,8 +545,10 @@ class FGFTServeEngine:
             return self.placement.place(arr)
 
         full_stages = int(basis.fwd.num_stages)
+        rows = self.row_steps and basis.batched
         tiers: Dict[str, dict] = {}
         fns: Dict[str, Any] = {}
+        row_fns: Dict[str, Any] = {}
         for name, frac in self._tier_spec.items():
             n_stages, n_comp = basis.select_tier(fraction=frac)
             cut = None if n_stages >= full_stages else n_stages
@@ -537,7 +563,9 @@ class FGFTServeEngine:
                            "num_transforms": n_comp,
                            "spectrum": _place(spec)}
             fns[name] = _plan("operator", cut).program()
-        bank = bank_gains = bank_fn = None
+            if rows:
+                row_fns[name] = _plan("operator", cut, row=True).program()
+        bank = bank_gains = bank_fn = bank_row_fn = None
         if self._filters:
             from repro.spectral import SpectralFilterBank, named_responses
             # gains are recomputed from the (possibly refreshed) spectrum
@@ -545,6 +573,8 @@ class FGFTServeEngine:
             bank = SpectralFilterBank(basis, named_responses(self._filters))
             bank_gains = _place(bank.gains())
             bank_fn = _plan("bank").program()
+            if rows:
+                bank_row_fn = _plan("bank", row=True).program()
         version = 0 if self._live is None else self._live.version + 1
         # placed engines build their table arguments through the plan's
         # prepare (batch-padded + NamedSharding-pinned); unplaced engines
@@ -558,7 +588,8 @@ class FGFTServeEngine:
         self._live = _LiveVersion(
             basis=basis, fwd=fwd_t, bwd=bwd_t, tiers=tiers,
             fns=fns, bank=bank, bank_gains=bank_gains, bank_fn=bank_fn,
-            version=version)
+            version=version, row_fns=row_fns, bank_row_fn=bank_row_fn,
+            gains={})
         _OBS_VERSION.set(version, family=basis.kind)
         if version > 0:
             _OBS_SWAPS.inc(family=basis.kind)
@@ -592,21 +623,49 @@ class FGFTServeEngine:
 
     # -- serving hot path --------------------------------------------------
 
-    def _step_on(self, live: _LiveVersion, signals: jnp.ndarray, h,
-                 tier: Optional[str]) -> jnp.ndarray:
-        """Tier dispatch against ONE live-version snapshot: tables, tier
-        spectra and program binding all come from ``live``, so a
-        concurrent ``maintain()`` swap can never mix versions inside a
-        single response (the async front-end relies on this)."""
-        tier = tier if tier is not None else self.default_tier
-        t = live.tiers[tier]
-        d = t["spectrum"] if h is None else h(t["spectrum"])
-        if h is not None and self._pad_valid is not None:
+    def _row_id(self, row: int):
+        """Batch row ``row`` as the row programs' index argument."""
+        if not self._row_ids:
+            raise ValueError("row steps need an unplaced batched engine "
+                             "on one device")
+        if not 0 <= row < len(self._row_ids):
+            raise ValueError(f"row {row} not in a batch of "
+                             f"{len(self._row_ids)}")
+        return self._row_ids[row]
+
+    def _gains(self, live: _LiveVersion, tier: str, h):
+        """``h`` of the tier's spectrum in ``live``, pad columns zeroed.
+        ``h`` is a pure map of the frequencies, so the gains are kept on
+        ``live`` for the last ``h`` the tier saw and computed again only
+        for another ``h`` or another version."""
+        hit = live.gains.get(tier)
+        if hit is not None and hit[0] is h:
+            return hit[1]
+        d = h(live.tiers[tier]["spectrum"])
+        if self._pad_valid is not None:
             # h(0) need not be 0 (heat/Tikhonov map 0 -> 1): unmasked
             # gains would leak pad columns of x into the output
             d = jnp.where(self._pad_valid, d, 0.0)
+        live.gains[tier] = (h, d)
+        return d
+
+    def _step_on(self, live: _LiveVersion, signals: jnp.ndarray, h,
+                 tier: Optional[str], row: Optional[int] = None
+                 ) -> jnp.ndarray:
+        """Tier dispatch against ONE live-version snapshot: tables, tier
+        spectra and program binding all come from ``live``, so a
+        concurrent ``maintain()`` swap can never mix versions inside a
+        single response (the async front-end relies on this).  With
+        ``row``, ``signals`` is graph ``row``'s (R, n) block and only
+        that graph is walked."""
+        rid = None if row is None else self._row_id(row)
+        tier = tier if tier is not None else self.default_tier
+        d = (live.tiers[tier]["spectrum"] if h is None
+             else self._gains(live, tier, h))
         self.stats["steps"][tier] += 1
         _OBS_STEPS.inc(tier=tier)
+        if rid is not None:
+            return live.row_fns[tier](live.fwd, live.bwd, d, signals, rid)
         if self.placement is not None:
             # callers hand true-B blocks; pad rows are zero signals on
             # identity pad tables, so the padded rows compute zeros that
@@ -625,26 +684,34 @@ class FGFTServeEngine:
         return self._step_on(self._live, signals, h, tier)
 
     def step_versioned(self, signals: jnp.ndarray, h=None,
-                       tier: Optional[str] = None) -> tuple:
+                       tier: Optional[str] = None,
+                       row: Optional[int] = None) -> tuple:
         """``step`` that also returns the serving version that produced
         the answer, both read from a SINGLE atomic ``_live`` snapshot
         (DESIGN.md §12: per-response version accounting for the async
-        service)."""
+        service).  ``row=g`` filters graph g's (R, n) block alone
+        (``row_steps`` engines)."""
         live = self._live
-        return self._step_on(live, signals, h, tier), live.version
+        return self._step_on(live, signals, h, tier, row), live.version
 
     def step_bank(self, signals: jnp.ndarray) -> jnp.ndarray:
         """All F bank responses on every graph: (B, R, n) ->
         (B, F, R, n), one fused dispatch (full tier; DESIGN.md §8)."""
         return self.step_bank_versioned(signals)[0]
 
-    def step_bank_versioned(self, signals: jnp.ndarray) -> tuple:
+    def step_bank_versioned(self, signals: jnp.ndarray,
+                            row: Optional[int] = None) -> tuple:
         """``step_bank`` plus the serving version, from one atomic
-        ``_live`` snapshot (DESIGN.md §12)."""
+        ``_live`` snapshot (DESIGN.md §12); ``row=g``: graph g's (R, n)
+        block -> (F, R, n)."""
         live = self._live
         if live.bank is None:
             raise ValueError("engine was built without --filter responses")
         _OBS_STEPS.inc(tier="bank")
+        if row is not None:
+            return (live.bank_row_fn(live.fwd, live.bwd, live.bank_gains,
+                                     signals, self._row_id(row)),
+                    live.version)
         if self.placement is not None:
             y = live.bank_fn(live.fwd, live.bwd, live.bank_gains,
                              self.placement.place(signals))

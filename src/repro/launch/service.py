@@ -14,15 +14,17 @@ bridges the two:
     queue grow without bound.
   * a dispatcher thread COALESCES queued requests that share a dispatch
     group — same size bucket, same quality tier (or the filter bank) —
-    into one zero-padded signal block and answers them all with a single
-    fused engine dispatch: same-graph requests stack along the row axis,
-    different graphs land on their own batch rows.  Row counts are
-    quantized (``quantize_rows``) so steady-state dispatches reuse a
-    handful of compiled programs.
+    and answers them all in one dispatch: same-graph requests stack
+    along the row axis of that graph's zero-padded block, and each
+    graph's block is walked alone by a row-selecting program, launched
+    back to back (placed engines, and buckets whose whole block is
+    small, walk one whole-bucket block instead).
+    Row counts are quantized (``quantize_rows``) so steady-state
+    dispatches reuse a handful of compiled programs.
   * ``maintain()`` (drift scoring, refresh/extend/refit, versioned hot
     swap — DESIGN.md §11) runs on a background maintainer thread, never
     on the serving path.  The hot path takes no lock around jitted calls:
-    it reads the engine's immutable ``_LiveVersion`` once per dispatch
+    each launch reads the engine's immutable ``_LiveVersion`` once
     (``step_versioned``), so every response is served by exactly one
     consistent version and carries that version number.
   * every stage is instrumented with an INJECTABLE clock: per-tier
@@ -61,6 +63,16 @@ from repro.obs.metrics import (bucket_counts, geometric_edges,
 
 BANK = "__bank__"          # pseudo-tier routing a request to the filter bank
 
+#: Largest whole-bucket block, in elements (graphs x padded rows x
+#: width), that a bucket of several graphs still walks in one launch
+#: rather than one launch a served graph.  Every stage of the walk pays
+#: a fixed cost whatever its block, about what streaming 2**19 f32
+#: elements through the stage costs (TPU v5e, n = 4096: 14 us fixed and
+#: 14 us more for each 128 rows).  At or below this size the graphs a
+#: per-graph layout skips save less than that fixed cost, and each
+#: launch beyond the first pays it again.
+WHOLE_BLOCK_ELEMENTS = 1 << 19
+
 # -- serving-path telemetry (DESIGN.md §15): every live service records
 # into the process-wide registry; per-service isolation comes from the
 # `service` label ------------------------------------------------------
@@ -79,8 +91,12 @@ _OBS_SIGNAL_ELEMENTS = obs.counter(
     ("service", "tier"))
 _OBS_BLOCK_ELEMENTS = obs.counter(
     "service_block_elements_total",
-    "block elements (batch x padded rows x bucket width) the dispatches "
-    "walked", ("service", "tier"))
+    "block elements (padded rows x bucket width, summed over the graph "
+    "blocks) the dispatches walked", ("service", "tier"))
+_OBS_GRAPH_BLOCKS = obs.counter(
+    "service_graph_blocks_total",
+    "graph blocks (one graph's padded rows) the dispatches walked",
+    ("service", "tier"))
 _OBS_QUEUE_DEPTH = obs.gauge("service_queue_depth",
                              "queue depth sampled at the last dispatch",
                              ("service",))
@@ -269,17 +285,28 @@ class _Request:
 
 @dataclass(frozen=True)
 class _Layout:
-    """One dispatch's block: request -> (batch row, row offset), the
-    block's (b, r_pad, n) shape and its signal vs walked elements (the
-    bank's filter count multiplies both, so it is left out)."""
+    """One dispatch's graph blocks: request -> (block, row offset), each
+    block's batch row (None on an unbatched engine) and padded row
+    count, whether the blocks stack into one whole-bucket (b, r_pad, n)
+    launch, and the signal vs walked elements (the bank's filter count
+    multiplies both, so it is left out)."""
 
     offsets: List[Tuple[int, int]]
-    batched: bool
-    b: int
-    r_pad: int
+    rows: List[Optional[int]]
+    r_pads: List[int]
+    whole: bool
     n: int
     signal_elements: int
     block_elements: int
+
+    @property
+    def b(self) -> int:
+        """Graph blocks walked."""
+        return len(self.r_pads)
+
+    @property
+    def r_pad(self) -> int:
+        return max(self.r_pads)
 
 
 @dataclass(frozen=True)
@@ -374,6 +401,7 @@ class AsyncFGFTService:
         self._coalesced = 0
         self._signal_elements = 0
         self._block_elements = 0
+        self._graph_blocks = 0
         self._occ_max = 0
         self._maintain_ticks = 0
         self._maintain_errors = 0
@@ -542,27 +570,56 @@ class AsyncFGFTService:
         return cached
 
     def _layout(self, batch: List[_Request]) -> "_Layout":
-        """Where each request of ``batch`` lands in the dispatch block
-        and how much of the block is signal: same-graph requests stack
-        along the row axis, each graph fills its own batch row, rows are
-        quantized to ``r_pad``."""
+        """Where each request of ``batch`` lands and how much of the
+        walked blocks is signal: same-graph requests stack along the row
+        axis of their graph's block.
+
+        An engine that serves row steps walks only the batch's graphs,
+        each block's rows quantized alone, unless its bucket's whole
+        block at the batch's quantized rows is at most
+        ``WHOLE_BLOCK_ELEMENTS``: then one launch walks the whole
+        bucket.  The choice depends only on the bucket and the quantized
+        rows, and a per-graph block never walks fewer rows than the
+        smallest count that chooses per-graph blocks, so each (bucket,
+        tier, rows) has one program, the one a single request of those
+        rows compiles.  A placed engine always walks the whole bucket,
+        every block at the largest quantized row count (its batch axis
+        is split over devices, and a row index must not cross them)."""
         route0 = self._routes[batch[0].graph_id]
-        offsets = []                            # request -> its row slice
+        eng = route0.engine
+        n = eng.basis.n
+        q = self.row_quantum
+        offsets = []                            # request -> (batch row, off)
         used: Dict[int, int] = {}               # batch row -> rows filled
         signal = 0
         for req in batch:
-            route = self._routes[req.graph_id]
-            off = used.get(route.row, 0)
-            offsets.append((route.row, off))
-            used[route.row] = off + req.signal.shape[0]
+            row = self._routes[req.graph_id].row
+            off = used.get(row, 0)
+            offsets.append((row, off))
+            used[row] = off + req.signal.shape[0]
             signal += req.signal.size           # rows x the graph's n
-        r_pad = quantize_rows(max(used.values()), self.row_quantum)
-        eng = route0.engine
-        n = eng.basis.n
         b = int(np.shape(eng.basis.spectrum)[0]) if route0.batched else 1
-        return _Layout(offsets=offsets, batched=route0.batched, b=b,
-                       r_pad=r_pad, n=n, signal_elements=signal,
-                       block_elements=b * r_pad * n)
+        r_all = quantize_rows(max(used.values()), q)
+        whole = route0.batched and (
+            not eng.row_steps
+            or 1 < b and b * r_all * n <= WHOLE_BLOCK_ELEMENTS)
+        if whole:
+            rows = list(range(b))
+            r_pads = [r_all] * b
+        else:
+            # the fewest rows whose whole block exceeds the limit
+            floor = (quantize_rows(WHOLE_BLOCK_ELEMENTS // (b * n) + 1, q)
+                     if b > 1 else q)
+            rows = list(used)                   # first-come block order
+            block = {row: k for k, row in enumerate(rows)}
+            offsets = [(block[row], off) for row, off in offsets]
+            r_pads = [max(quantize_rows(used[row], q), floor)
+                      for row in rows]
+            if not route0.batched:
+                rows = [None]
+        return _Layout(offsets=offsets, rows=rows, r_pads=r_pads,
+                       whole=whole, n=n, signal_elements=signal,
+                       block_elements=sum(r_pads) * n)
 
     def _run_batch(self, batch: List[_Request],
                    t_collect: Optional[float] = None):
@@ -588,12 +645,13 @@ class AsyncFGFTService:
                     "block_elements": lay.block_elements}
         with tracer.span("serve.dispatch", cat="serve", args=args):
             try:
-                y, version = self._fused_dispatch(batch, lay)
+                ys, versions = self._fused_dispatch(batch, lay)
             except Exception as exc:  # noqa: BLE001 — fail the batch, not the service
                 self._fail(batch, exc)
                 return
             with tracer.span("serve.reply", cat="serve"):
-                self._reply(batch, lay, y, version, label, t_collect, t0)
+                self._reply(batch, lay, ys, versions, label, t_collect,
+                            t0)
 
     def _fail(self, batch: List[_Request], exc: Exception):
         with self._cond:
@@ -601,15 +659,15 @@ class AsyncFGFTService:
         for req in batch:
             req.future.set_exception(exc)
 
-    def _reply(self, batch: List[_Request], lay: "_Layout", y, version,
+    def _reply(self, batch: List[_Request], lay: "_Layout", ys, versions,
                label: str, t_collect: float, t0: float):
-        """Crop each request's answer out of the host block ``y``, do
-        the batch's bookkeeping and resolve its futures."""
+        """Crop each request's answer out of its graph block's host
+        answer in ``ys``, do the batch's bookkeeping and resolve its
+        futures with the version that block was served by."""
         results = []
-        for req, (row, off) in zip(batch, lay.offsets):
+        for req, (k, off) in zip(batch, lay.offsets):
             r, size = req.signal.shape
-            yb = y[row] if lay.batched else y
-            results.append(yb[..., off:off + r, :size])
+            results.append(ys[k][..., off:off + r, :size])
         t1 = self._clock()
         with self._cond:
             self._dispatches += 1
@@ -618,6 +676,7 @@ class AsyncFGFTService:
             self._served += len(batch)
             self._signal_elements += lay.signal_elements
             self._block_elements += lay.block_elements
+            self._graph_blocks += lay.b
             depth_now = len(self._queue)
         tracer = obs.default_tracer()
         if obs.recording_enabled():
@@ -634,11 +693,14 @@ class AsyncFGFTService:
                     _OBS_SIGNAL_ELEMENTS.labels(service=self.name,
                                                 tier=label),
                     _OBS_BLOCK_ELEMENTS.labels(service=self.name,
-                                               tier=label))
-            dispatches, signal, block = dchild
+                                               tier=label),
+                    _OBS_GRAPH_BLOCKS.labels(service=self.name,
+                                             tier=label))
+            dispatches, signal, block, blocks = dchild
             dispatches.inc()
             signal.inc(lay.signal_elements)
             block.inc(lay.block_elements)
+            blocks.inc(lay.b)
             self._obs_depth.set(depth_now)
             stage_obs = self._stage_children(label)
             stage_obs["batch"].observe_many(t0 - t_collect, len(batch))
@@ -648,7 +710,8 @@ class AsyncFGFTService:
             stage_obs["total"].observe_seq(
                 [t1 - req.t_submit for req in batch])
         tid = threading.get_ident()
-        for req, yr in zip(batch, results):
+        for req, (k, _), yr in zip(batch, lay.offsets, results):
+            version = versions[k]
             queue_s = t0 - req.t_submit
             self.latency.record(f"{label}/queue", queue_s)
             self.latency.record(f"{label}/service", t1 - t0)
@@ -679,41 +742,57 @@ class AsyncFGFTService:
                 trace_id=req.trace_id))
 
     def _fused_dispatch(self, batch: List[_Request], lay: "_Layout"):
-        """ONE fused engine dispatch answering every request in ``batch``
-        (all share a dispatch group), laid out by ``lay``; returns the
-        whole answer block on the host and its serving version.  Rows
-        are independent under every kernel in the stack (they broadcast
-        over the leading axes), so the coalesced answer matches the
-        per-request loop — bitwise for the G family
-        (tests/test_service.py)."""
-        import jax.numpy as jnp
+        """The engine launches answering every request in ``batch`` (all
+        share a dispatch group), laid out by ``lay``: every graph block
+        is built, put and launched back to back, every answer's copy to
+        the host is queued, the device is waited for once, and every
+        answer is pulled.  Returns the host answer and serving version
+        of each graph block.  Rows are independent under every kernel
+        in the stack (they broadcast over the leading axes), so the
+        coalesced answer matches the per-request loop — bitwise for the
+        G family (tests/test_service.py)."""
+        import jax
         eng = self._routes[batch[0].graph_id].engine
         tier = batch[0].tier
         tracer = obs.default_tracer()
         with tracer.span("serve.build", cat="serve"):
-            shape = (lay.r_pad, lay.n)
-            block = np.zeros((lay.b,) + shape if lay.batched else shape,
-                             np.float32)
-            for req, (row, off) in zip(batch, lay.offsets):
-                r, size = req.signal.shape
-                dst = block[row] if lay.batched else block
-                dst[off:off + r, :size] = req.signal
-        with tracer.span("serve.put", cat="serve"):
-            x = jnp.asarray(block)
-        with tracer.span("serve.launch", cat="serve"):
-            if tier == BANK:
-                y, version = eng.step_bank_versioned(x)
+            if lay.whole:
+                host = [np.zeros((lay.b, lay.r_pad, lay.n), np.float32)]
+                views = list(host[0])
+                rows = [None]
             else:
-                y, version = eng.step_versioned(x, self._h, tier=tier)
+                host = views = [np.zeros((r, lay.n), np.float32)
+                                for r in lay.r_pads]
+                rows = lay.rows
+            for req, (k, off) in zip(batch, lay.offsets):
+                r, size = req.signal.shape
+                views[k][off:off + r, :size] = req.signal
+        with tracer.span("serve.put", cat="serve"):
+            xs = jax.device_put(host)
+
+        def launch(x, row):
+            # row None: the engine's own step over the block it is handed
+            kw = {} if row is None else {"row": row}
+            if tier == BANK:
+                return eng.step_bank_versioned(x, **kw)
+            return eng.step_versioned(x, self._h, tier=tier, **kw)
+
+        with tracer.span("serve.launch", cat="serve"):
+            out = [launch(x, row) for x, row in zip(xs, rows)]
+        ys = [y for y, _ in out]
         with tracer.span("serve.device", cat="serve"):
-            # queue the answer's copy to the host behind the step first,
-            # as a bare pull would, so that waiting for the device on its
-            # own adds no round trip
-            y.copy_to_host_async()
-            y.block_until_ready()
+            # queue the answers' copies to the host behind the steps
+            # first, as a bare pull would, so that waiting for the device
+            # on its own adds no round trip
+            for y in ys:
+                y.copy_to_host_async()
+            jax.block_until_ready(ys)
         with tracer.span("serve.pull", cat="serve"):
-            y = np.asarray(y)
-        return y, version
+            ys = [np.asarray(y) for y in ys]
+        versions = [v for _, v in out]
+        if lay.whole:
+            ys, versions = list(ys[0]), versions * lay.b
+        return ys, versions
 
     # -- background maintenance (dynamic engines; DESIGN.md §11) -----------
 
@@ -801,6 +880,7 @@ class AsyncFGFTService:
             self._depth_peak = len(self._queue)
             self._dispatches = self._coalesced = self._occ_max = 0
             self._signal_elements = self._block_elements = 0
+            self._graph_blocks = 0
             self._maintain_ticks = self._maintain_errors = 0
             self._swaps = 0
         self.latency = LatencyRecorder(max_samples=self.latency.max_samples)
@@ -820,9 +900,11 @@ class AsyncFGFTService:
                           "max": self.max_queue},
                 "dispatches": self._dispatches,
                 # block fill: signal_elements / block_elements is the
-                # share of the walked (b, r_pad, w) blocks that is signal
+                # share of the walked (r_pad, w) graph blocks that is
+                # signal; graph_blocks counts the blocks walked
                 "signal_elements": self._signal_elements,
                 "block_elements": self._block_elements,
+                "graph_blocks": self._graph_blocks,
                 "batch": {
                     "cap": self.max_batch,
                     "occupancy_mean": (self._coalesced / self._dispatches

@@ -73,6 +73,46 @@ def test_placed_fleet_owns_devices_and_serves_collective_free():
     assert res["all_devices_used"] == list(range(8))
 
 
+def test_placed_router_dispatches_one_whole_bucket_launch():
+    """Through the async front door a placed bucket keeps its one
+    whole-bucket launch a dispatch (its batch axis is split over
+    devices, so no row program may select a graph), and the answers are
+    the router's own ``step``."""
+    res = run_in_mesh_subprocess(_FLEET_PRELUDE + """
+    from repro.launch.service import AsyncFGFTService
+
+    mesh = make_local_mesh()
+    r = RaggedFGFTServeEngine(fleet(), n_iter=1, mesh=mesh,
+                              placement="auto")
+    sig = signals()
+    want = r.step(sig)
+    w = r.widths[0]
+    eng = r.engines[w]
+    graphs = r.bucket_of[w][:2]
+    steps0 = eng.stats["steps"]["full"]
+    svc = AsyncFGFTService(r, auto_start=False)
+    futs = [svc.submit(g, sig[g]) for g in graphs]
+    served = svc.drain_once()
+    st = svc.stats()
+    svc.close()
+    diff = max(float(np.abs(f.result(timeout=0).y - want[g]).max())
+               for f, g in zip(futs, graphs))
+    print(json.dumps({
+        "served": served, "dispatches": st["dispatches"],
+        "graph_blocks": st["graph_blocks"],
+        "launches": eng.stats["steps"]["full"] - steps0,
+        "bucket_graphs": len(r.bucket_of[w]),
+        "placed": eng.placement is not None, "row_steps": eng.row_steps,
+        "diff": diff}))
+    """, devices=4)
+    assert res["placed"] and not res["row_steps"]
+    assert res["served"] == 2 and res["dispatches"] == 1
+    assert res["launches"] == 1
+    # the whole bucket is walked, not only the two graphs asked about
+    assert res["graph_blocks"] == res["bucket_graphs"] > 2
+    assert res["diff"] <= 1e-6, res
+
+
 def test_overlapped_maintenance_touches_only_dirty_bucket():
     """A dirty bucket's refit bumps ONLY that bucket's serving version;
     clean buckets keep serving their version untouched (and the placed

@@ -144,6 +144,63 @@ def test_batched_operator_backend_parity():
                                atol=2e-5, rtol=2e-5)
 
 
+# -- row plans: one graph of the batched tables, its row an argument ----
+
+def _row_operands(basis, mode):
+    spec = basis.spectrum
+    if mode == "operator":
+        return 1.0 / (1.0 + jnp.abs(spec))
+    return jnp.stack([1.0 / (1.0 + jnp.abs(spec)), jnp.exp(-jnp.abs(spec)),
+                      jnp.ones_like(spec)], axis=1)
+
+
+@pytest.mark.parametrize("mode", ["operator", "bank"])
+@pytest.mark.parametrize("family", ["sym", "general"])
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_row_plan_matches_whole_bucket_row_every_cut(family, mode, backend):
+    """Every ladder cut on the oracle backend; a middle cut and the full
+    chain on the interpreted Pallas one."""
+    basis, _ = _batched_basis(family, b=3)
+    d = _row_operands(basis, mode)
+    x = jnp.asarray(np.random.default_rng(6).standard_normal(
+        (3, 4, basis.n)).astype(np.float32))
+    cuts = _cuts(basis.fwd, backend)
+    if backend == "pallas":                     # interpreted: two rungs
+        cuts = [cuts[len(cuts) // 2]]
+    for k in cuts + [None]:
+        plan = ApplyPlan(family=family, mode=mode, n=basis.n, batched=True,
+                         backend=backend, num_stages=k)
+        row_plan = dataclasses.replace(plan, row=True)
+        fwd, bwd = plan.prepare(basis.fwd), plan.prepare(basis.bwd)
+        whole = np.asarray(plan.program()(fwd, bwd, d, x))
+        for g in range(3):
+            got = np.asarray(row_plan.program()(fwd, bwd, d, x[g],
+                                                jnp.int32(g)))
+            assert got.shape == whole[g].shape
+            np.testing.assert_allclose(got, whole[g], atol=1e-6, rtol=1e-6)
+    # the three-pass baseline takes the row the same way
+    three = dataclasses.replace(plan, row=True, fused=False)
+    np.testing.assert_allclose(
+        np.asarray(three.program()(fwd, bwd, d, x[1], jnp.int32(1))),
+        whole[1], atol=3e-5, rtol=3e-5)
+
+
+@pytest.mark.parametrize("mode, cut, name", [
+    ("operator", None, "plan_sym_operator_n16_row"),
+    ("bank", 3, "plan_sym_bank_n16_k3_row"),
+])
+def test_row_plan_has_a_stable_module_name(mode, cut, name):
+    basis, _ = _batched_basis("sym", b=3)
+    plan = ApplyPlan(family="sym", mode=mode, n=16, batched=True,
+                     num_stages=cut, row=True)
+    assert plan.program_name == name
+    text = plan.program().lower(
+        plan.prepare(basis.fwd), plan.prepare(basis.bwd),
+        _row_operands(basis, mode), jnp.ones((3, 16), jnp.float32),
+        jnp.int32(0)).as_text()
+    assert f"module @jit_{name} " in text
+
+
 # -- bf16 precision policy ----------------------------------------------
 
 def test_with_precision_casts_values_only():
@@ -321,6 +378,8 @@ def test_plan_validation():
         ApplyPlan(family="sym", mode="apply", n=0)
     with pytest.raises(ValueError):
         ApplyPlan(family="sym", mode="apply", n=8, block_b=0)
+    with pytest.raises(ValueError, match="row requires"):
+        ApplyPlan(family="sym", mode="operator", n=8, row=True)
 
 
 # -- persisted autotuner -------------------------------------------------

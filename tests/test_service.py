@@ -8,6 +8,7 @@ import pytest
 
 import jax.numpy as jnp
 
+import repro.launch.service as service_mod
 from repro.launch.serve import FGFTServeEngine, RaggedFGFTServeEngine
 from repro.launch.service import (AsyncFGFTService, LatencyRecorder,
                                   ServiceClosed, ShedError, load_slo_stats,
@@ -193,7 +194,44 @@ def ragged3_engine():
 
     # sizes 5/6/7 -> one bucket of width 8 holding all three graphs
     return RaggedFGFTServeEngine([s(5, 0), s(6, 1), s(7, 2)], 12,
-                                 n_iter=1, tiers={"full": 1.0})
+                                 n_iter=1, tiers={"full": 1.0, "draft": 0.5},
+                                 filters="heat,lowpass")
+
+
+@pytest.fixture
+def per_graph(monkeypatch):
+    """Every bucket of several graphs walks per-graph blocks.  The
+    buckets here are far smaller than ``WHOLE_BLOCK_ELEMENTS``, which
+    walks them whole by default."""
+    monkeypatch.setattr(service_mod, "WHOLE_BLOCK_ELEMENTS", 0)
+
+
+@pytest.fixture(params=["whole", "per-graph"])
+def layout(request, monkeypatch):
+    """Both dispatch layouts of a row-step engine."""
+    if request.param == "per-graph":
+        monkeypatch.setattr(service_mod, "WHOLE_BLOCK_ELEMENTS", 0)
+    return request.param
+
+
+class CompileEvents:
+    """Counts JAX compile events (tracing, lowering, backend compiles)
+    while armed, as the chip benchmark counts them in its window."""
+
+    _live = None
+
+    def __init__(self):
+        import jax
+        self.count = 0
+        self.armed = False
+        if CompileEvents._live is None:
+            def listener(event, duration, *args, **kwargs):
+                live = CompileEvents._live
+                if live is not None and live.armed \
+                        and "/jax/core/compile" in event:
+                    live.count += 1
+            jax.monitoring.register_event_duration_secs_listener(listener)
+        CompileEvents._live = self
 
 
 def signals_for(engine, gid, rows, seed):
@@ -474,18 +512,91 @@ def test_equivalence_ragged_buckets(ragged_engine):
             if gid == 1] == [2, 2]
 
 
-@pytest.mark.parametrize("requests, signal, block", [
-    # one request: 3 rows of n = 5 in a (3 graphs, 8 rows, w = 8) block
-    ([(0, 3)], 3 * 5, 3 * 8 * 8),
-    # two graphs of the 3-graph bucket, each on its own batch row
-    ([(0, 3), (2, 3)], 3 * 5 + 3 * 7, 3 * 8 * 8),
+def mixed_queue(engine, graphs, bank=True):
+    """Tiers, the bank (where the engine has one) and uneven row counts
+    over every graph of one bucket, in one queue."""
+    reqs = []
+    for i, rows in enumerate([1, 3, 2, 5, 1, 4, 2, 3, 6, 2, 1, 3]):
+        gid = graphs[i % len(graphs)]
+        kind = (i // len(graphs)) % (3 if bank else 2)
+        tier, is_bank = ((None, True) if kind == 2
+                         else ("full" if kind == 0 else "draft", False))
+        reqs.append((gid, signals_for(engine, gid, rows, 70 + i), tier,
+                     is_bank))
+    return reqs
+
+
+def whole_bucket_rows(engine, w, requests, h):
+    """Each request answered by its graph's row of the whole-bucket
+    ``step``/``step_bank`` over a (B, r, w) block holding it alone."""
+    eng = engine.engines[w] if isinstance(engine, RaggedFGFTServeEngine) \
+        else engine
+    outs = []
+    for gid, x, tier, bank in requests:
+        row = (engine.bucket_of[w].index(gid)
+               if isinstance(engine, RaggedFGFTServeEngine) else gid)
+        block = np.zeros((np.shape(eng.basis.spectrum)[0], x.shape[0],
+                          eng.basis.n), np.float32)
+        block[row, :, :x.shape[1]] = x
+        y = (eng.step_bank(jnp.asarray(block)) if bank
+             else eng.step(jnp.asarray(block), h, tier=tier))
+        outs.append(np.asarray(y)[row][..., :x.shape[1]])
+    return outs
+
+
+def test_per_graph_blocks_match_loop_and_whole_bucket(ragged3_engine,
+                                                      per_graph):
+    reqs = mixed_queue(ragged3_engine, [0, 1, 2])
+    svc = AsyncFGFTService(ragged3_engine, h=lowpass, auto_start=False)
+    futs = [svc.submit(gid, x, tier=tier, bank=bank)
+            for gid, x, tier, bank in reqs]
+    drain_all(svc)
+    got = [f.result(timeout=0) for f in futs]
+    st = svc.stats()
+    ref = reference_loop(ragged3_engine, reqs, h=lowpass)
+    whole = whole_bucket_rows(ragged3_engine, 8, reqs, lowpass)
+    for (gid, x, _, bank), a, b, c in zip(reqs, got, ref, whole):
+        f = len(ragged3_engine.engines[8].bank)
+        assert a.y.shape == ((f,) if bank else ()) + x.shape
+        assert np.array_equal(a.y, b.y)           # bitwise: G family
+        np.testing.assert_allclose(a.y, c, atol=1e-6, rtol=1e-6)
+    # one dispatch per group (full, draft, bank), each walking the
+    # three graphs' own blocks; same-graph requests stacked in them
+    assert st["dispatches"] == 3 and st["graph_blocks"] == 9
+    assert max(r.batch_size for r in got) == 6
+    assert {r.tier for r in got} == {"full", "draft", "bank"}
+
+
+def test_per_graph_blocks_general_family(gen_engine, per_graph):
+    reqs = mixed_queue(gen_engine, [0, 1], bank=False)
+    got = coalesced(gen_engine, reqs, h=lowpass)
+    ref = reference_loop(gen_engine, reqs, h=lowpass)
+    whole = whole_bucket_rows(gen_engine, None, reqs, lowpass)
+    for a, b, c in zip(got, ref, whole):
+        np.testing.assert_allclose(a.y, b.y, atol=1e-5, rtol=1e-5)
+        np.testing.assert_allclose(a.y, c, atol=1e-5, rtol=1e-5)
+    assert max(r.batch_size for r in got) > 1
+
+
+@pytest.mark.parametrize("requests, signal, whole, block, blocks", [
+    # one request: 3 rows of n = 5 in a (3 graphs, 8 rows, w = 8) block,
+    # or in graph 0's own (8 rows, w = 8) block
+    ([(0, 3)], 3 * 5, 3 * 8 * 8, 8 * 8, 1),
+    # two graphs of the 3-graph bucket, each in its own block
+    ([(0, 3), (2, 3)], 3 * 5 + 3 * 7, 3 * 8 * 8, 8 * 8 + 8 * 8, 2),
     # two requests on one graph stack to 9 rows, quantized to 16
-    ([(1, 3), (1, 6)], 9 * 6, 3 * 16 * 8),
-], ids=["one-request", "two-graphs", "one-graph-stacked"])
-def test_fill_counters_match_block_arithmetic(ragged3_engine, requests,
-                                              signal, block):
+    ([(1, 3), (1, 6)], 9 * 6, 3 * 16 * 8, 16 * 8, 1),
+    # graph 1 stacks 9 rows (16), graph 2 has 2 (8): walked alone
+    ([(1, 3), (2, 2), (1, 6)], 9 * 6 + 2 * 7, 3 * 16 * 8,
+     16 * 8 + 8 * 8, 2),
+], ids=["one-request", "two-graphs", "one-graph-stacked", "uneven-graphs"])
+def test_fill_counters_match_block_arithmetic(ragged3_engine, layout,
+                                              requests, signal, whole,
+                                              block, blocks):
     from repro import obs
-    name = f"fill-{len(requests)}-{requests[-1][0]}"
+    tracer = obs.default_tracer()
+    tracer.clear()
+    name = f"fill-{layout}-{len(requests)}-{requests[-1][0]}"
     svc = AsyncFGFTService(ragged3_engine, clock=FakeClock(),
                            auto_start=False, max_batch=8, name=name)
     futs = [svc.submit(gid, signals_for(ragged3_engine, gid, rows, gid))
@@ -495,17 +606,118 @@ def test_fill_counters_match_block_arithmetic(ragged3_engine, requests,
         f.result(timeout=0)
     st = svc.stats()
     svc.close()
+    per_graph = {}
+    for gid, rows in requests:
+        per_graph[gid] = per_graph.get(gid, 0) + rows
+    r_pad = max(quantize_rows(r) for r in per_graph.values())
+    if layout == "whole":
+        # the bucket's three graphs at the largest quantized row count
+        assert whole == 3 * r_pad * 8
+        block, blocks = whole, 3
+    else:
+        # the graphs' own blocks: sum over the served graphs of
+        # quantize_rows(the graph's rows) x w
+        assert block == sum(quantize_rows(r) * 8
+                            for r in per_graph.values())
+        assert blocks == len(per_graph)
     assert st["signal_elements"] == signal
     assert st["block_elements"] == block
+    assert st["graph_blocks"] == blocks
+    (disp,) = [s for s in tracer.spans() if s["name"] == "serve.dispatch"]
+    assert disp["args"]["b"] == blocks
+    assert disp["args"]["r_pad"] == r_pad
     snap = obs.default_registry().collect()
     for key, want in (("service_signal_elements_total", signal),
-                      ("service_block_elements_total", block)):
+                      ("service_block_elements_total", block),
+                      ("service_graph_blocks_total", blocks)):
         mine = [s for s in snap[key]["series"]
                 if s["labels"] == {"service": name, "tier": "full"}]
         assert [s["value"] for s in mine] == [want]
     svc.reset_stats()
     assert svc.stats()["signal_elements"] == 0
     assert svc.stats()["block_elements"] == 0
+    assert svc.stats()["graph_blocks"] == 0
+
+
+def test_layout_whole_at_or_below_limit_per_graph_above(ragged3_engine,
+                                                        monkeypatch):
+    """The bucket's whole block at the batch's quantized rows picks the
+    layout; per-graph blocks never walk fewer rows than the smallest
+    count that picks them."""
+    # (3 graphs, 8 rows, w = 8) is at the limit, (3, 16, 8) above it
+    monkeypatch.setattr(service_mod, "WHOLE_BLOCK_ELEMENTS", 3 * 8 * 8)
+    cases = [([(0, 3), (2, 3)], 3, 3 * 8 * 8),
+             # graph 2's 2 rows walk 16, the fewest above the limit
+             ([(1, 9), (2, 2)], 2, 2 * 16 * 8)]
+    for requests, blocks, block in cases:
+        reqs = [(gid, signals_for(ragged3_engine, gid, rows, 40 + gid),
+                 None, False) for gid, rows in requests]
+        svc = AsyncFGFTService(ragged3_engine, h=lowpass, max_batch=8,
+                               auto_start=False)
+        futs = [svc.submit(gid, x) for gid, x, _, _ in reqs]
+        assert drain_all(svc) == [len(reqs)]
+        got = [f.result(timeout=0) for f in futs]
+        st = svc.stats()
+        svc.close()
+        assert (st["graph_blocks"], st["block_elements"]) == (blocks,
+                                                              block)
+        ref = reference_loop(ragged3_engine, reqs, h=lowpass)
+        for a, b in zip(got, ref):
+            assert np.array_equal(a.y, b.y)
+
+
+def test_single_requests_compile_every_program_the_layouts_use(
+        ragged3_engine, monkeypatch):
+    """One request of each quantized row count compiles every program a
+    mixed dispatch then runs, on either side of the limit: nothing
+    compiles inside the served load."""
+    # quantum 3: rungs 3, 6, 12; (3 graphs, 6 rows, w = 8) is the limit
+    monkeypatch.setattr(service_mod, "WHOLE_BLOCK_ELEMENTS", 3 * 6 * 8)
+    svc = AsyncFGFTService(ragged3_engine, h=lowpass, max_batch=8,
+                           row_quantum=3, auto_start=False)
+    for rows in (5, 10):                # rungs 6 (whole) and 12 (per graph)
+        fut = svc.submit(0, signals_for(ragged3_engine, 0, rows, rows))
+        drain_all(svc)
+        fut.result(timeout=0)
+    svc.reset_stats()
+    events = CompileEvents()
+    events.armed = True
+    # whole at rung 6; per graph at rung 12, graph 2 floored from 3 to 12
+    for requests in ([(0, 5), (2, 1)], [(1, 10), (2, 2)]):
+        futs = [svc.submit(gid, signals_for(ragged3_engine, gid, rows, 9))
+                for gid, rows in requests]
+        assert drain_all(svc) == [len(requests)]
+        for f in futs:
+            f.result(timeout=0)
+    events.armed = False
+    svc.close()
+    assert svc.stats()["graph_blocks"] == 3 + 2
+    assert events.count == 0
+
+
+def test_warmed_engine_serves_without_compiles(ragged3_engine, layout):
+    """``warmup`` compiles the per-graph programs too: a warmed engine's
+    first dispatch through the front door compiles nothing."""
+    eng = ragged3_engine.engines[8]
+    # quantum 5: a row count (10) no other test compiles
+    eng.warmup(jnp.zeros((3, 10, 8), jnp.float32))
+    svc = AsyncFGFTService(ragged3_engine, max_batch=8, row_quantum=5,
+                           auto_start=False)
+    events = CompileEvents()
+    events.armed = True
+    futs = [svc.submit(1, signals_for(ragged3_engine, 1, 7, 1)),
+            svc.submit(2, signals_for(ragged3_engine, 2, 7, 2), tier=None,
+                       bank=False),
+            svc.submit(0, signals_for(ragged3_engine, 0, 6, 3), bank=True)]
+    drain_all(svc)
+    for f in futs:
+        f.result(timeout=0)
+    events.armed = False
+    st = svc.stats()
+    svc.close()
+    assert st["dispatches"] == 2
+    assert st["graph_blocks"] == (3 + 3 if layout == "whole" else 2 + 1)
+    assert events.count == 0
 
 
 # ---------------------------------------------------------------------------

@@ -125,3 +125,25 @@ def test_serving_program_compiles_at_4096(one_chip, native_pallas, family,
         tables, tables, diag, _spec(one_chip, (b, rows, n))).compile()
     text = compiled.as_text()
     assert ("tpu_custom_call" in text) == (backend == "pallas")
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+@pytest.mark.parametrize("mode", ["operator", "bank"])
+@pytest.mark.parametrize("family", ["sym", "general"])
+def test_row_program_compiles_at_4096(one_chip, native_pallas, family,
+                                      mode, backend):
+    """The front door's per-graph program on the same bucket: one
+    graph's 8 signals against the three graphs' tables, spectra or
+    gains, the graph's row an argument."""
+    n, b, rows, filters = 4096, 3, 8, 5
+    width = n // 2 if family == "sym" else n
+    plan = ApplyPlan(family=family, mode=mode, n=n, batched=True,
+                     backend=backend, block_b=bf.DEFAULT_BLOCK_B, row=True)
+    tables = _tables(one_chip, family, b, 64, width)
+    diag = (_spec(one_chip, (b, n)) if mode == "operator"
+            else _spec(one_chip, (b, filters, n)))
+    compiled = plan.program().lower(
+        tables, tables, diag, _spec(one_chip, (rows, n)),
+        _spec(one_chip, (), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (backend == "pallas")
