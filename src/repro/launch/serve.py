@@ -694,6 +694,18 @@ class FGFTServeEngine:
         live = self._live
         return self._step_on(live, signals, h, tier, row), live.version
 
+    def walk_stages(self, tier: Optional[str]) -> int:
+        """Stages one launch walks on one graph block of the live
+        version, over both legs: the tier's ``num_stages`` for its
+        analysis and again for its synthesis; for ``tier=None``, the
+        bank, the full tables once each way (fused) or once each way
+        per filter (three-pass)."""
+        live = self._live
+        if tier is None:
+            legs = 2 if self._fused else 2 * len(live.bank)
+            return legs * int(live.basis.fwd.num_stages)
+        return 2 * int(live.tiers[tier]["num_stages"])
+
     def step_bank(self, signals: jnp.ndarray) -> jnp.ndarray:
         """All F bank responses on every graph: (B, R, n) ->
         (B, F, R, n), one fused dispatch (full tier; DESIGN.md §8)."""

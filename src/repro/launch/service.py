@@ -638,11 +638,14 @@ class AsyncFGFTService:
         label = "bank" if batch[0].tier == BANK else batch[0].tier
         args = None
         if tracer.enabled:
+            stages = self._routes[batch[0].graph_id].engine.walk_stages(
+                None if batch[0].tier == BANK else batch[0].tier)
             args = {"tier": label, "w": lay.n, "b": lay.b,
                     "r_pad": lay.r_pad, "requests": len(batch),
                     "rows": sum(req.signal.shape[0] for req in batch),
                     "signal_elements": lay.signal_elements,
-                    "block_elements": lay.block_elements}
+                    "block_elements": lay.block_elements,
+                    "walk_stages": lay.b * stages}
         with tracer.span("serve.dispatch", cat="serve", args=args):
             try:
                 ys, versions = self._fused_dispatch(batch, lay)

@@ -581,11 +581,13 @@ def test_service_dispatch_emits_stage_spans_in_order(sym_engine):
         assert a["ts"] + a["dur"] <= b["ts"]
     assert disp["ts"] <= kids[0]["ts"]
     assert kids[-1]["ts"] + kids[-1]["dur"] <= disp["ts"] + disp["dur"]
-    # rows 2 + 1 stack on graph 0, 3 land on graph 2: r_pad = 8
+    # rows 2 + 1 stack on graph 0, 3 land on graph 2: r_pad = 8; the
+    # whole bucket's three blocks walk both legs of the full tier
     assert disp["args"] == {
         "tier": "full", "w": 16, "b": 3, "r_pad": 8, "requests": 3,
         "rows": 6, "signal_elements": 6 * 16,
-        "block_elements": 3 * 8 * 16}
+        "block_elements": 3 * 8 * 16,
+        "walk_stages": 3 * 2 * sym_engine.tiers["full"]["num_stages"]}
     # the profiler mirror saw the same nesting, one span per stage
     names = [(op, name) for op, name, _ in fake.log]
     assert names == ([("enter", "serve.dispatch")]

@@ -578,6 +578,158 @@ def test_per_graph_blocks_general_family(gen_engine, per_graph):
     assert max(r.batch_size for r in got) > 1
 
 
+@pytest.fixture(scope="module")
+def ragged3_gen_engine():
+    def d(n, seed):
+        return np.random.default_rng(seed).standard_normal((n, n)).astype(
+            np.float32)
+
+    # directed twin of ragged3_engine: one bucket of width 8, T chains
+    return RaggedFGFTServeEngine([d(5, 0), d(6, 1), d(7, 2)], 12,
+                                 n_iter=1, kind="general",
+                                 tiers={"full": 1.0, "draft": 0.5},
+                                 filters="heat,lowpass")
+
+
+@pytest.mark.parametrize("kind", ["tier", "bank"])
+@pytest.mark.parametrize("family", ["sym", "general"])
+def test_walk_stages_arg_sums_plan_stages_over_blocks_and_legs(
+        request, family, layout, kind):
+    from repro import obs
+    router = request.getfixturevalue(
+        "ragged3_engine" if family == "sym" else "ragged3_gen_engine")
+    eng = router.engines[8]
+    assert eng.basis.kind == family
+    tracer = obs.default_tracer()
+    tracer.clear()
+    svc = AsyncFGFTService(router, h=lowpass, clock=FakeClock(),
+                           auto_start=False, max_batch=8)
+    bank = kind == "bank"
+    tier = None if bank else "draft"
+    futs = [svc.submit(gid, signals_for(router, gid, rows, gid), tier=tier,
+                       bank=bank) for gid, rows in ((0, 3), (2, 2))]
+    assert drain_all(svc) == [2]
+    for f in futs:
+        f.result(timeout=0)
+    svc.close()
+    (disp,) = [s for s in tracer.spans() if s["name"] == "serve.dispatch"]
+    # the stages of the programs a block's launch runs: the draft cut of
+    # both legs, or the bank's full tables analysed once and synthesized
+    # once for every filter
+    per_block = (2 * eng.basis.fwd.num_stages if bank
+                 else 2 * eng.tiers["draft"]["num_stages"])
+    assert 0 < eng.tiers["draft"]["num_stages"] <= eng.basis.fwd.num_stages
+    assert eng.basis.fwd.num_stages == eng.basis.bwd.num_stages
+    blocks = 3 if layout == "whole" else 2
+    assert disp["args"]["b"] == blocks
+    assert disp["args"]["walk_stages"] == blocks * per_block
+
+
+def _directed_fleet(sizes, seed=0):
+    """Directed Laplacians L = D_out - A of random graphs: each edge of
+    an undirected G(n, p) keeps one direction, chosen at random."""
+    from repro.graphs.generators import directed_variant, erdos_renyi
+    laps = []
+    for k, n in enumerate(sizes):
+        adj = directed_variant(erdos_renyi(n, 0.2, seed=seed + k),
+                               seed=seed + k)
+        laps.append((np.diag(adj.sum(axis=1)) - adj).astype(np.float32))
+    return laps
+
+
+def _t_legs(factors, w, k):
+    """(Tbar_k, Tbar_k^{-1}) as float64 (w, w) from the first k factors
+    of one T chain in application order (a shear x_i += a x_j, kind 1;
+    a scaling x_i *= a, kind 0)."""
+    kind, i, j, a = (np.asarray(f) for f in factors)
+    a = a.astype(np.float64)
+    fwd, inv_t = np.eye(w), np.eye(w)
+    for t in range(k):
+        p, q = i[t], j[t]
+        if kind[t] == 1:
+            fwd[p] += a[t] * fwd[q]
+            inv_t[q] -= a[t] * inv_t[p]
+        else:
+            fwd[p] *= a[t]
+            inv_t[p] /= a[t]
+    return fwd, inv_t.T
+
+
+def test_directed_fleet_at_alpha_twelfth_saved_loaded_and_served(
+        tmp_path, per_graph):
+    """A directed fleet at g = w log2 w / 12 in its widest bucket, fitted,
+    saved, restored and served with per-graph blocks on every tier and
+    the bank: each answer is the float64 dense T operator of the
+    restored chain, and each chain beats the identity's objective."""
+    sizes = [12, 26, 28, 30]
+    laps = _directed_fleet(sizes)
+    tiers = {"draft": 0.25, "half": 0.5, "full": 1.0}
+    bank_names = "heat,tikhonov,lowpass,highpass,bandpass"
+    fitted = RaggedFGFTServeEngine(laps, round(32 * 5 / 12), n_iter=3,
+                                   kind="general", tiers=tiers,
+                                   filters=bank_names)
+    fitted.save(tmp_path / "fleet")
+    router = RaggedFGFTServeEngine.load(tmp_path / "fleet")
+    assert sorted(router.engines) == [16, 32]
+    # alpha = g / (w log2 w) = 1/12 in both buckets (rounded to a factor)
+    for w, g in ((16, 5), (32, 13)):
+        assert router.engines[w].basis.num_transforms == g
+    svc = AsyncFGFTService(router, h=lowpass, max_batch=8, auto_start=False)
+    reqs = []
+    for gid in range(len(sizes)):
+        for k, tier in enumerate(list(tiers) + [None]):
+            reqs.append((gid, tier, signals_for(router, gid, 2 + k,
+                                                10 * gid + k)))
+    futs = [svc.submit(gid, x, tier=tier, bank=tier is None)
+            for gid, tier, x in reqs]
+    drain_all(svc)
+    got = [f.result(timeout=0) for f in futs]
+    st = svc.stats()
+    svc.close()
+    assert st["served"] == len(reqs) and st["errors"] == 0
+    # per-graph blocks: one block a served graph in every dispatch
+    assert st["graph_blocks"] == sum(
+        len({gid for gid, t, _ in reqs
+             if t == tier and router.widths[gid] == w})
+        for w in router.engines for tier in list(tiers) + [None])
+    for (gid, tier, x), res in zip(reqs, got):
+        w = router.widths[gid]
+        eng = router.engines[w]
+        row = router.bucket_of[w].index(gid)
+        factors = [np.asarray(f)[row] for f in eng.basis.factors]
+        spectrum = np.asarray(eng.basis.spectrum, np.float64)[row]
+        n = sizes[gid]
+        if tier is None:
+            k = eng.basis.num_transforms
+            gains = np.asarray(eng.bank.gains(), np.float64)[row]
+        else:
+            k = eng.tiers[tier]["num_transforms"]
+            gains = lowpass(spectrum)[None]
+        synth, ana = _t_legs(factors, w, k)
+        gains = gains.copy()
+        gains[:, n:] = 0.0
+        xp = np.zeros((x.shape[0], w))
+        xp[:, :n] = x
+        want = (((xp @ ana.T)[None] * gains[:, None, :]) @ synth.T)[..., :n]
+        if tier is not None:
+            want = want[0]
+        assert res.y.shape == want.shape
+        np.testing.assert_allclose(res.y, want, atol=1e-5, rtol=1e-5)
+    for gid, lap in enumerate(laps):
+        w = router.widths[gid]
+        eng = router.engines[w]
+        row = router.bucket_of[w].index(gid)
+        factors = [np.asarray(f)[row] for f in eng.basis.factors]
+        spectrum = np.asarray(eng.basis.spectrum, np.float64)[row]
+        synth, ana = _t_legs(factors, w, eng.basis.num_transforms)
+        big = np.zeros((w, w))
+        big[:sizes[gid], :sizes[gid]] = lap
+        total = float((big ** 2).sum())
+        fit = float((((synth * spectrum) @ ana - big) ** 2).sum()) / total
+        identity = float(((big - np.diag(np.diag(big))) ** 2).sum()) / total
+        assert fit < identity, (gid, fit, identity)
+
+
 @pytest.mark.parametrize("requests, signal, whole, block, blocks", [
     # one request: 3 rows of n = 5 in a (3 graphs, 8 rows, w = 8) block,
     # or in graph 0's own (8 rows, w = 8) block
