@@ -142,7 +142,7 @@ _WORKER = """
             (eng.placement.batch, sig[0].shape[0], eng.basis.n),
             jnp.float32))
         txt = live.fns[tier].lower(
-            live.fwd, live.bwd, live.tiers[tier]["spectrum"],
+            *live.operands(tier), live.tiers[tier]["spectrum"],
             xp).compile().as_text()
         collectives += sum(hlo.collective_bytes(txt)["counts"].values())
 

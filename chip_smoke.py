@@ -25,9 +25,10 @@ Usage:
   python3 chip_smoke.py --chips 4   four chips, only the placed fleets:
                                     both families (g cut, n not) with
                                     placement="auto" against the
-                                    one-device router (bitwise sym,
-                                    <= 1e-5 general) and zero
-                                    collectives in every bucket step
+                                    one-device router (bitwise sym
+                                    walked, <= 1e-6 sym dense, <= 1e-5
+                                    general) and zero collectives in
+                                    every bucket step
   JAX_PLATFORMS=cpu python3 chip_smoke.py --rehearse [--chips 4]
                                     tiny subgraphs on the CPU (with
                                     XLA_FLAGS=--xla_force_host_platform_
@@ -75,6 +76,11 @@ GEN_TOL = 1e-3                # (Tbar^-1 conditioning enters here)
 PALLAS_TOL = 1e-5             # pallas vs xla, relative to max |y|
 CPU_TOL = 0.01                # chip vs CPU objective, relative
 PLACED_GEN_TOL = 1e-5         # placed vs one-device, general family
+#: placed vs one-device, sym family served dense: both build the same
+#: legs by the same walk, but the compiler may tile a batched f32 matmul
+#: differently for a device's share of the batch than for the whole
+#: batch, and so sum its 4096 terms in another order
+PLACED_DENSE_TOL = 1e-6
 CHURN_EDGES = 20
 REHEARSAL_SIZES = (12, 26, 28, 30)
 
@@ -429,12 +435,25 @@ def run_four_chips(smoke: Smoke):
                     tiers=TIERS, block_b=BLOCK_B)
             for w in sorted(placed.engines):
                 log(f"{family} bucket {w} -> devices "
-                    f"{list(placed.placement[w].device_ids)}")
+                    f"{list(placed.placement[w].device_ids)}, lowering "
+                    f"{placed.engines[w].lowering} (one-device "
+                    f"{one.engines[w].lowering})")
+                if placed.engines[w].lowering != one.engines[w].lowering:
+                    failures.append(f"{family} bucket {w}: placed and "
+                                    f"one-device lowerings differ")
             used = {d.id for eng in placed.engines.values()
                     for d in eng._live.fwd[0].devices()}
             check(len(used) == len(devices),
                   f"placed fleet uses devices {sorted(used)} of "
                   f"{len(devices)}")
+            for w in sorted(placed.engines):
+                a, b = placed.engines[w]._live, one.engines[w]._live
+                if a.legs is not None and b.legs is not None:
+                    same = all(np.array_equal(np.asarray(x), np.asarray(y))
+                               for cut in b.legs
+                               for x, y in zip(a.legs[cut], b.legs[cut]))
+                    log(f"{family} bucket {w} dense legs placed vs "
+                        f"one-device: {'bitwise' if same else 'differ'}")
             got, _ = smoke.serve(placed)
             worst, exact = {}, {}
             for (gid, _, tier, bank), a, b in zip(smoke.requests, want, got):
@@ -444,12 +463,14 @@ def run_four_chips(smoke: Smoke):
                                  float(np.abs(a.y - b.y).max()) / scale)
                 exact[key] = exact.get(key, True) and np.array_equal(a.y, b.y)
             for (w, key), err in sorted(worst.items()):
-                ok = (exact[(w, key)] if family == "sym"
-                      else err <= PLACED_GEN_TOL)
+                limit = (PLACED_GEN_TOL if family != "sym" else
+                         PLACED_DENSE_TOL
+                         if placed.engines[w].lowering == "dense" else 0.0)
+                ok = exact[(w, key)] or err <= limit
                 log(f"{family} bucket {w} {key} placed vs one-device: max "
                     f"relative difference {err:.3e}, "
                     f"{'bitwise' if exact[(w, key)] else 'not bitwise'} "
-                    f"(limit {'bitwise' if family == 'sym' else PLACED_GEN_TOL})"
+                    f"(limit {limit or 'bitwise'})"
                     f"{'' if ok else ' FAILED'}")
                 if not ok:
                     failures.append(f"{family} bucket {w} {key}: {err:.3e}")
@@ -458,7 +479,7 @@ def run_four_chips(smoke: Smoke):
                 xp = eng.placement.place(jax.numpy.zeros(
                     (eng.placement.batch, ROWS, w), jax.numpy.float32))
                 txt = live.fns[tier].lower(
-                    live.fwd, live.bwd, live.tiers[tier]["spectrum"],
+                    *live.operands(tier), live.tiers[tier]["spectrum"],
                     xp).compile().as_text()
                 count = sum(hlo.collective_bytes(txt)["counts"].values())
                 log(f"{family} bucket {w}: {count} collective(s) in the "

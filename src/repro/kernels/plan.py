@@ -45,9 +45,31 @@ bank re-runs its analysis per filter) — kept as a first-class plan so
 parity tests and the fig13 speedup gate exercise the exact path the
 fused programs replace.
 
+Lowering policy: ``lowering="walk"`` (default) runs the staged tables
+stage by stage.  ``lowering="dense"`` (fused f32 operator and bank
+plans) serves each leg as ONE f32 matmul on the MXU against the leg's
+dense w x w matrix, built once per basis version by ``dense_legs`` (the
+identity walked through the same tables; ``ApplyPlan.legs`` builds them
+from prepared tables and places them like those), so the cost no longer
+grows with the stage count.  Dense programs take the legs in place of
+the tables:
+
+  * mode "operator":  ``program(legs, diag, x)``
+  * mode "bank":      ``program(legs, gains, x)``
+
+with ``legs`` a tuple: ``(U,)`` for sym (analysis ``x @ U``, synthesis
+the same array contracted transposed, ``c @ U^T``, with no second
+copy) and ``(A, S)`` for general (``A = Tbar^-T``, ``S = Tbar^T``), each
+(w, w) or (B, w, w) when batched.  Every matmul runs at
+``Precision.HIGHEST`` with f32 accumulation: a default-precision f32
+matmul on a TPU rounds its inputs to bf16.  ``choose_lowering`` picks
+the lowering from what the caller observes (platform, precision,
+memory).
+
 Ragged fleets need no extra plan state: masked fits emit tables that
 act as the identity on padding coordinates (core/staging.py), and
-callers mask bank/filter gains where ``h(0) != 0``.
+callers mask bank/filter gains where ``h(0) != 0``.  The dense legs
+inherit that: a pad coordinate's row and column are the identity's.
 """
 from __future__ import annotations
 
@@ -57,6 +79,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from jax import lax
 
 from repro import obs
 from repro.core.staging import (StagedG, StagedT, TABLE_PRECISIONS,
@@ -75,6 +99,12 @@ obs.configure(annotation=jax.profiler.TraceAnnotation)
 PLAN_FAMILIES = ("sym", "general")
 PLAN_MODES = ("apply", "operator", "bank")
 PLAN_BACKENDS = ("xla", "pallas")
+PLAN_LOWERINGS = ("walk", "dense")
+
+#: free device memory a version's dense legs need, in copies of those
+#: legs: the live version's, the next version's built beside them
+#: during a hot swap, and the identity walk that builds them
+DENSE_LEG_COPIES = 3
 
 #: rows-per-grid-step default shared by every Pallas kernel; a persisted
 #: autotune entry (kernels/autotune.py) overrides it per plan key.
@@ -124,6 +154,9 @@ class ApplyPlan:
     #: walk one graph of the batched tables, its row an argument (see
     #: the module docstring): the serving front door's per-graph blocks
     row: bool = False
+    #: "walk" the staged tables, or serve "dense" legs as matmuls (see
+    #: the module docstring)
+    lowering: str = "walk"
 
     def __post_init__(self):
         if self.family not in PLAN_FAMILIES:
@@ -153,6 +186,14 @@ class ApplyPlan:
         if self.row and (not self.batched or self.placement is not None):
             raise ValueError("row requires batched=True and no placement "
                              "(a row index must not cross devices)")
+        if self.lowering not in PLAN_LOWERINGS:
+            raise ValueError(f"lowering must be one of {PLAN_LOWERINGS}, "
+                             f"got {self.lowering!r}")
+        if self.lowering == "dense" and (
+                self.mode == "apply" or self.precision != "f32"
+                or not self.fused):
+            raise ValueError("the dense lowering serves fused f32 operator "
+                             "and bank plans")
         if self.mode != "apply" and self.keep != "head":
             # operator/bank legs derive their own orientation; canonical
             # keep="head" keeps equivalent plans on one cache entry
@@ -188,6 +229,17 @@ class ApplyPlan:
             return tuple(self.placement.place_leaf(a)
                          for a in table_arrays(staged))
         return table_arrays(staged)
+
+    def legs(self, fwd: tuple, bwd: tuple, cuts) -> dict:
+        """``dense_legs`` of the table tuples ``prepare`` returned, at
+        each cut: built where those tables live and, with a
+        ``placement``, pinned batch-split onto the bucket's devices like
+        them (a pad graph's legs are the identity)."""
+        legs = dense_legs(self._staged(fwd), self._staged(bwd), cuts)
+        if self.placement is None:
+            return legs
+        return {cut: tuple(map(self.placement.place_leaf, leg))
+                for cut, leg in legs.items()}
 
     def place(self, arr):
         """Pad (zeros) + device_put a per-graph operand (diag spectrum,
@@ -227,7 +279,8 @@ class ApplyPlan:
         plan: family, mode, width, ladder cut and row selection."""
         cut = "" if self.num_stages is None else f"_k{self.num_stages}"
         row = "_row" if self.row else ""
-        return f"plan_{self.family}_{self.mode}_n{self.n}{cut}{row}"
+        dense = "_dense" if self.lowering == "dense" else ""
+        return f"plan_{self.family}_{self.mode}_n{self.n}{cut}{row}{dense}"
 
     def _obs_labels(self) -> dict:
         return {"family": self.family, "mode": self.mode,
@@ -238,7 +291,7 @@ class ApplyPlan:
         embedding inside LARGER jitted programs (the Hutchinson drift
         scorer wraps the operator leg this way) without nesting a second
         dispatch cache."""
-        op = self._dispatch()
+        op = self._dense() if self.lowering == "dense" else self._dispatch()
         if self.precision != "f32":
             op = _accumulate_f32(op)
         return _select_row(op) if self.row else op
@@ -251,15 +304,22 @@ class ApplyPlan:
 
     def operator(self, fwd, bwd, diag: jnp.ndarray,
                  x: jnp.ndarray) -> jnp.ndarray:
-        return self.crop(self.program()(self.prepare(fwd),
-                                        self.prepare(bwd),
+        return self.crop(self.program()(*self._tables(fwd, bwd),
                                         self.place(diag), self.place(x)))
 
     def bank(self, fwd, bwd, gains: jnp.ndarray,
              x: jnp.ndarray) -> jnp.ndarray:
-        return self.crop(self.program()(self.prepare(fwd),
-                                        self.prepare(bwd),
+        return self.crop(self.program()(*self._tables(fwd, bwd),
                                         self.place(gains), self.place(x)))
+
+    def _tables(self, fwd, bwd) -> tuple:
+        """Both prepared table sets: a walked program's leading
+        arguments.  A dense program takes legs built once per version
+        (``legs``), never per call."""
+        if self.lowering == "dense":
+            raise ValueError("a dense plan's program takes its legs: build "
+                             "them once with legs() and call program()")
+        return self.prepare(fwd), self.prepare(bwd)
 
     # -- dispatch ----------------------------------------------------------
 
@@ -355,6 +415,44 @@ class ApplyPlan:
             return out.reshape((g.shape[0],) + x.shape)
         return bank_op
 
+    def _dense(self):
+        """legs -> arrays map of a dense plan: the operator or bank as
+        f32 matmuls against the legs (module docstring).  Batched legs
+        and operands carry the batch on axis 0, which every matmul
+        keeps as a batch dimension; a row plan's legs are the row's
+        (``_select_row``)."""
+        n, sym = self.n, self.family == "sym"
+        batched = self.batched and not self.row
+        lead = 1 if batched else 0              # contraction axis offset
+        batch = ((0,), (0,)) if batched else ((), ())
+
+        def mm(a, b, b_axis):
+            # a (..., R, n) against b (..., n, n) on b's axis ``b_axis``
+            return lax.dot_general(
+                a, b, (((lead + 1,), (lead + b_axis,)), batch),
+                precision=lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+
+        def legs_op(legs, scale, x):
+            # scale: (..., n) diagonal or (..., F, n) gains; the answer
+            # is (..., R, n) or (..., F, R, n)
+            shape = x.shape
+            x = x.reshape((shape[0], -1, n) if batched else (-1, n))
+            c = mm(x, legs[0], 0)
+            if self.mode == "operator":
+                c = c * jnp.expand_dims(scale, -2)
+            else:
+                # every filter in one matmul over the stacked (F R, n)
+                c = jnp.expand_dims(scale, -2) * jnp.expand_dims(c, -3)
+                c = c.reshape(c.shape[:-3] + (-1, n))
+            y = mm(c, legs[0], 1) if sym else mm(c, legs[1], 0)
+            if self.mode == "operator":
+                return y.reshape(shape)
+            f = scale.shape[-2]
+            head = (shape[0], f) if batched else (f,)
+            return y.reshape(head + (shape[1:] if batched else shape))
+        return legs_op
+
     def _three_pass(self):
         """The UNFUSED baseline program: analysis, diagonal scale and
         synthesis as separate dispatches through cached "apply" plans (a
@@ -409,6 +507,74 @@ def _select_row(op):
                                  axis=0, keepdims=False)
         return op(*jax.tree.map(pick, operands), x)
     return row_op
+
+
+def choose_lowering(platform: str, precision: str, leg_bytes: int,
+                    free_bytes: Optional[int]) -> str:
+    """"dense" where the dense legs pay (a TPU's MXU), keep the served
+    precision (f32: bf16 tables keep the walk) and ``DENSE_LEG_COPIES``
+    copies of them fit in ``free_bytes``: the device's free memory
+    (``bytes_limit - bytes_in_use``) plus the legs of the version they
+    replace; "walk" otherwise, the CPU included."""
+    if platform != "tpu" or precision != "f32" or free_bytes is None:
+        return "walk"
+    return ("dense" if DENSE_LEG_COPIES * leg_bytes <= free_bytes
+            else "walk")
+
+
+def dense_leg_count(family: str) -> int:
+    """Dense (w, w) matrices one cut of a family takes: sym contracts
+    one array both ways; general keeps analysis and synthesis."""
+    return 1 if family == "sym" else 2
+
+
+def dense_legs(fwd, bwd, cuts) -> dict:
+    """Dense legs of each operator cut (``None`` = the full chain) of a
+    staged pair, by walking the identity through its tables once per
+    basis version: ``{cut: legs}`` in the dense programs' form (module
+    docstring).  sym walks the adjoint tables ONCE, keeping the state
+    at each cut (the cuts are head prefixes); general walks the forward
+    head once the same way and each cut's inverse tail on its own."""
+    cuts = tuple(sorted(set(cuts),
+                        key=lambda k: float("inf") if k is None else k))
+    build = _legs_program("sym" if isinstance(fwd, StagedG) else "general",
+                          fwd.n, fwd.idx_i.ndim == 3, cuts)
+    return dict(zip(cuts, build(table_arrays(fwd), table_arrays(bwd))))
+
+
+@functools.lru_cache(maxsize=None)
+def _legs_program(family: str, n: int, batched: bool, cuts: tuple):
+    """Jitted ``dense_legs`` body for one (family, width, cuts): a hot
+    swap with unchanged shapes and ladder rebuilds its legs without a
+    compile."""
+    cls = StagedG if family == "sym" else StagedT
+    walk = {("sym", False): _ref.staged_g_apply,
+            ("sym", True): _ref.batched_g_apply,
+            ("general", False): _ref.staged_t_apply,
+            ("general", True): _ref.batched_t_apply}[family, batched]
+
+    def stages(tables, lo, hi):
+        return cls(*(a[..., lo:hi, :] for a in tables), None, n)
+
+    def build(fwd_t, bwd_t):
+        total = fwd_t[0].shape[-2]
+        ks = [total if k is None else k for k in cuts]
+        eye = jnp.eye(n, dtype=jnp.float32)
+        if batched:
+            eye = jnp.broadcast_to(eye, fwd_t[0].shape[:1] + (n, n))
+        # the head prefixes: sym's adjoint, general's forward tables
+        heads, state, done = [], eye, 0
+        for k in ks:
+            state = walk(stages(bwd_t if family == "sym" else fwd_t,
+                                done, k), state)
+            heads.append(state)
+            done = k
+        if family == "sym":
+            return tuple((u,) for u in heads)
+        return tuple((walk(stages(bwd_t, total - k, total), eye), s)
+                     for k, s in zip(ks, heads))
+    build.__name__ = build.__qualname__ = f"dense_legs_{family}_n{n}"
+    return jax.jit(build)
 
 
 #: per-plan cache telemetry (DESIGN.md §15): misses increment INSIDE
@@ -482,3 +648,4 @@ def clear_plan_cache() -> None:
     effect for that plan after a clear)."""
     _compile.cache_clear()
     _scale_program.cache_clear()
+    _legs_program.cache_clear()

@@ -123,6 +123,37 @@ class _LiveVersion:
     #: response each tier served: a dispatch's per-graph launches, and
     #: the dispatches after it, filter the spectrum once
     gains: Dict[str, tuple]
+    #: tier (and ``None`` for the bank) -> the cut its programs serve
+    #: (``None``: the full chain)
+    cuts: Dict[Optional[str], Optional[int]]
+    #: cut -> dense legs (kernels/plan.py), built at swap time, when the
+    #: programs take legs in place of the staged tables; None when they
+    #: walk the tables
+    legs: Optional[Dict[Optional[int], tuple]]
+
+    @property
+    def lowering(self) -> str:
+        return "walk" if self.legs is None else "dense"
+
+    def operands(self, key: Optional[str]) -> tuple:
+        """The leading arguments of tier ``key``'s programs (``None``:
+        the bank's): both table sets walked, the cut's legs dense."""
+        if self.legs is None:
+            return self.fwd, self.bwd
+        return (self.legs[self.cuts[key]],)
+
+
+def _device_facts(devices) -> tuple:
+    """(platform, least free memory ``bytes_limit - bytes_in_use``, or
+    None where a device reports none) of the devices an engine serves
+    on: what ``plan.choose_lowering`` decides from."""
+    free = []
+    for dev in devices:
+        stats = dev.memory_stats()
+        if not stats or "bytes_limit" not in stats:
+            return devices[0].platform, None
+        free.append(stats["bytes_limit"] - stats.get("bytes_in_use", 0))
+    return devices[0].platform, min(free)
 
 
 def parse_tiers(spec: str) -> Dict[str, float]:
@@ -527,13 +558,32 @@ class FGFTServeEngine:
         cuts it lacks are refit here."""
         from repro.kernels.plan import ApplyPlan
 
+        full_stages = int(basis.fwd.num_stages)
+        ladder = {name: basis.select_tier(fraction=frac)
+                  for name, frac in self._tier_spec.items()}
+        # each tier's program cut (None: the full chain), and the bank's
+        served = {name: None if k >= full_stages else k
+                  for name, (k, _) in ladder.items()}
+        if self._filters:
+            served[None] = None
+        old = self._live
+        # a Lemma-1 refresh keeps the chain (its factors and staging),
+        # so the legs too: only the spectrum changes
+        keep_legs = (old is not None and old.legs is not None
+                     and basis.factors is old.basis.factors
+                     and full_stages == int(old.basis.fwd.num_stages)
+                     and set(served.values()) == set(old.legs))
+        lowering = ("dense" if keep_legs
+                    else self._lowering(basis, len(set(served.values()))))
+
         def _plan(mode, num_stages=None, row=False):
             return ApplyPlan(family=basis.kind, mode=mode, n=basis.n,
                              batched=basis.batched, backend=self.backend,
                              num_stages=num_stages,
                              precision=self._precision,
                              fused=self._fused, block_b=self._block_b,
-                             placement=self.placement, row=row)
+                             placement=self.placement, row=row,
+                             lowering=lowering)
 
         def _place(arr):
             # per-graph operands (tier spectra, bank gains) pad with zero
@@ -544,14 +594,12 @@ class FGFTServeEngine:
                 return arr
             return self.placement.place(arr)
 
-        full_stages = int(basis.fwd.num_stages)
         rows = self.row_steps and basis.batched
         tiers: Dict[str, dict] = {}
         fns: Dict[str, Any] = {}
         row_fns: Dict[str, Any] = {}
-        for name, frac in self._tier_spec.items():
-            n_stages, n_comp = basis.select_tier(fraction=frac)
-            cut = None if n_stages >= full_stages else n_stages
+        for name, (n_stages, n_comp) in ladder.items():
+            cut = served[name]
             if cut is None or basis.kind != "sym":
                 spec = basis.spectrum
             elif tier_spectra and (cut, n_comp) in tier_spectra:
@@ -585,11 +633,24 @@ class FGFTServeEngine:
         else:
             fwd_t = _tables(basis.fwd, self._precision)
             bwd_t = _tables(basis.bwd, self._precision)
+        legs = None
+        if keep_legs:
+            legs = old.legs
+        elif lowering == "dense":
+            # the programs take the legs of their cut in place of the
+            # tables, built once for the version where the tables live
+            with obs.default_tracer().span(
+                    "serve.densify", cat="serve",
+                    args={"family": basis.kind, "w": basis.n,
+                          "cuts": len(set(served.values()))}):
+                legs = _plan("operator").legs(fwd_t, bwd_t,
+                                              served.values())
+                jax.block_until_ready(list(legs.values()))
         self._live = _LiveVersion(
             basis=basis, fwd=fwd_t, bwd=bwd_t, tiers=tiers,
             fns=fns, bank=bank, bank_gains=bank_gains, bank_fn=bank_fn,
             version=version, row_fns=row_fns, bank_row_fn=bank_row_fn,
-            gains={})
+            gains={}, cuts=served, legs=legs)
         _OBS_VERSION.set(version, family=basis.kind)
         if version > 0:
             _OBS_SWAPS.inc(family=basis.kind)
@@ -606,6 +667,43 @@ class FGFTServeEngine:
         self.stats["tiers"] = {name: {k: t[k] for k in
                                       ("num_stages", "num_transforms")}
                                for name, t in tiers.items()}
+
+    def _lowering(self, basis, num_cuts: int) -> str:
+        """How this version's operator and bank programs run: dense legs
+        on a TPU for a fused f32 xla engine, unplaced on one device or
+        placed over a bucket's, where its legs (one or two w x w
+        matrices per graph a device holds, for each of the ``num_cuts``
+        distinct cuts served) fit the free memory of those devices as
+        ``plan.choose_lowering`` counts it, the legs of the version they
+        replace included; the walk otherwise (the CPU, bf16 tables, the
+        Pallas kernels, the three-pass baseline, mesh-sharded engines
+        without a placement)."""
+        from repro.kernels.plan import choose_lowering, dense_leg_count
+        if not (self.backend == "xla" and self._fused
+                and (self.placement is not None or self.mesh is None
+                     or self.mesh.size == 1)):
+            return "walk"
+        if self.placement is not None:
+            devices = list(self.placement.mesh().devices.flat)
+            per_device = self.placement.batch_padded // len(devices)
+        else:
+            devices = jax.devices()[:1]
+            per_device = int(np.shape(basis.spectrum)[0]) \
+                if basis.batched else 1
+        leg_bytes = (num_cuts * dense_leg_count(basis.kind) * per_device
+                     * basis.n ** 2 * 4)
+        platform, free = _device_facts(devices)
+        old = self._live
+        if free is not None and old is not None and old.legs is not None:
+            # the legs this version replaces are freed once it is live
+            free += sum(leg.nbytes for legs in old.legs.values()
+                        for leg in legs) // len(devices)
+        return choose_lowering(platform, self._precision, leg_bytes, free)
+
+    @property
+    def lowering(self) -> str:
+        """The live version's lowering: "dense" or "walk"."""
+        return self._live.lowering
 
     @property
     def basis(self):
@@ -664,16 +762,16 @@ class FGFTServeEngine:
              else self._gains(live, tier, h))
         self.stats["steps"][tier] += 1
         _OBS_STEPS.inc(tier=tier)
+        ops = live.operands(tier)
         if rid is not None:
-            return live.row_fns[tier](live.fwd, live.bwd, d, signals, rid)
+            return live.row_fns[tier](*ops, d, signals, rid)
         if self.placement is not None:
             # callers hand true-B blocks; pad rows are zero signals on
             # identity pad tables, so the padded rows compute zeros that
             # the crop discards — per-device work, no collectives
-            y = live.fns[tier](live.fwd, live.bwd, d,
-                               self.placement.place(signals))
+            y = live.fns[tier](*ops, d, self.placement.place(signals))
             return y[:self.placement.batch]
-        return live.fns[tier](live.fwd, live.bwd, d, signals)
+        return live.fns[tier](*ops, d, signals)
 
     def step(self, signals: jnp.ndarray, h=None,
              tier: Optional[str] = None) -> jnp.ndarray:
@@ -699,8 +797,11 @@ class FGFTServeEngine:
         version, over both legs: the tier's ``num_stages`` for its
         analysis and again for its synthesis; for ``tier=None``, the
         bank, the full tables once each way (fused) or once each way
-        per filter (three-pass)."""
+        per filter (three-pass).  0 under the dense lowering, which
+        walks no stage."""
         live = self._live
+        if live.lowering == "dense":
+            return 0
         if tier is None:
             legs = 2 if self._fused else 2 * len(live.bank)
             return legs * int(live.basis.fwd.num_stages)
@@ -720,15 +821,16 @@ class FGFTServeEngine:
         if live.bank is None:
             raise ValueError("engine was built without --filter responses")
         _OBS_STEPS.inc(tier="bank")
+        ops = live.operands(None)
         if row is not None:
-            return (live.bank_row_fn(live.fwd, live.bwd, live.bank_gains,
-                                     signals, self._row_id(row)),
+            return (live.bank_row_fn(*ops, live.bank_gains, signals,
+                                     self._row_id(row)),
                     live.version)
         if self.placement is not None:
-            y = live.bank_fn(live.fwd, live.bwd, live.bank_gains,
+            y = live.bank_fn(*ops, live.bank_gains,
                              self.placement.place(signals))
             return y[:self.placement.batch], live.version
-        return (live.bank_fn(live.fwd, live.bwd, live.bank_gains, signals),
+        return (live.bank_fn(*ops, live.bank_gains, signals),
                 live.version)
 
     # -- streaming updates + drift-triggered refits (DESIGN.md §11) --------

@@ -97,6 +97,10 @@ _OBS_GRAPH_BLOCKS = obs.counter(
     "service_graph_blocks_total",
     "graph blocks (one graph's padded rows) the dispatches walked",
     ("service", "tier"))
+_OBS_DENSE_BLOCKS = obs.counter(
+    "service_dense_blocks_total",
+    "graph blocks served by the dense lowering (matmuls against dense "
+    "legs, no stage walked)", ("service", "tier"))
 _OBS_QUEUE_DEPTH = obs.gauge("service_queue_depth",
                              "queue depth sampled at the last dispatch",
                              ("service",))
@@ -289,7 +293,8 @@ class _Layout:
     block's batch row (None on an unbatched engine) and padded row
     count, whether the blocks stack into one whole-bucket (b, r_pad, n)
     launch, and the signal vs walked elements (the bank's filter count
-    multiplies both, so it is left out)."""
+    multiplies both, so it is left out), and the engine's lowering
+    ("dense" or "walk")."""
 
     offsets: List[Tuple[int, int]]
     rows: List[Optional[int]]
@@ -298,6 +303,7 @@ class _Layout:
     n: int
     signal_elements: int
     block_elements: int
+    lowering: str
 
     @property
     def b(self) -> int:
@@ -402,6 +408,7 @@ class AsyncFGFTService:
         self._signal_elements = 0
         self._block_elements = 0
         self._graph_blocks = 0
+        self._dense_blocks = 0
         self._occ_max = 0
         self._maintain_ticks = 0
         self._maintain_errors = 0
@@ -619,7 +626,8 @@ class AsyncFGFTService:
                 rows = [None]
         return _Layout(offsets=offsets, rows=rows, r_pads=r_pads,
                        whole=whole, n=n, signal_elements=signal,
-                       block_elements=sum(r_pads) * n)
+                       block_elements=sum(r_pads) * n,
+                       lowering=eng.lowering)
 
     def _run_batch(self, batch: List[_Request],
                    t_collect: Optional[float] = None):
@@ -645,7 +653,8 @@ class AsyncFGFTService:
                     "rows": sum(req.signal.shape[0] for req in batch),
                     "signal_elements": lay.signal_elements,
                     "block_elements": lay.block_elements,
-                    "walk_stages": lay.b * stages}
+                    "walk_stages": lay.b * stages,
+                    "lowering": lay.lowering}
         with tracer.span("serve.dispatch", cat="serve", args=args):
             try:
                 ys, versions = self._fused_dispatch(batch, lay)
@@ -680,6 +689,8 @@ class AsyncFGFTService:
             self._signal_elements += lay.signal_elements
             self._block_elements += lay.block_elements
             self._graph_blocks += lay.b
+            dense = lay.b if lay.lowering == "dense" else 0
+            self._dense_blocks += dense
             depth_now = len(self._queue)
         tracer = obs.default_tracer()
         if obs.recording_enabled():
@@ -698,12 +709,16 @@ class AsyncFGFTService:
                     _OBS_BLOCK_ELEMENTS.labels(service=self.name,
                                                tier=label),
                     _OBS_GRAPH_BLOCKS.labels(service=self.name,
+                                             tier=label),
+                    _OBS_DENSE_BLOCKS.labels(service=self.name,
                                              tier=label))
-            dispatches, signal, block, blocks = dchild
+            dispatches, signal, block, blocks, dense_blocks = dchild
             dispatches.inc()
             signal.inc(lay.signal_elements)
             block.inc(lay.block_elements)
             blocks.inc(lay.b)
+            if dense:
+                dense_blocks.inc(dense)
             self._obs_depth.set(depth_now)
             stage_obs = self._stage_children(label)
             stage_obs["batch"].observe_many(t0 - t_collect, len(batch))
@@ -883,7 +898,7 @@ class AsyncFGFTService:
             self._depth_peak = len(self._queue)
             self._dispatches = self._coalesced = self._occ_max = 0
             self._signal_elements = self._block_elements = 0
-            self._graph_blocks = 0
+            self._graph_blocks = self._dense_blocks = 0
             self._maintain_ticks = self._maintain_errors = 0
             self._swaps = 0
         self.latency = LatencyRecorder(max_samples=self.latency.max_samples)
@@ -904,10 +919,12 @@ class AsyncFGFTService:
                 "dispatches": self._dispatches,
                 # block fill: signal_elements / block_elements is the
                 # share of the walked (r_pad, w) graph blocks that is
-                # signal; graph_blocks counts the blocks walked
+                # signal; graph_blocks counts the blocks walked, and
+                # dense_blocks those of them served by the dense lowering
                 "signal_elements": self._signal_elements,
                 "block_elements": self._block_elements,
                 "graph_blocks": self._graph_blocks,
+                "dense_blocks": self._dense_blocks,
                 "batch": {
                     "cap": self.max_batch,
                     "occupancy_mean": (self._coalesced / self._dispatches

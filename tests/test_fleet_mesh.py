@@ -110,7 +110,7 @@ def test_placed_router_dispatches_one_whole_bucket_launch():
     assert res["launches"] == 1
     # the whole bucket is walked, not only the two graphs asked about
     assert res["graph_blocks"] == res["bucket_graphs"] > 2
-    assert res["diff"] <= 1e-6, res
+    assert res["diff"] == 0.0, res
 
 
 def test_overlapped_maintenance_touches_only_dirty_bucket():
@@ -218,3 +218,57 @@ def test_placed_matches_unplaced_from_same_checkpoint(tmp_path):
     assert res["diff"] == 0.0, res
     out = np.load(tmp_path / "out_0.npy")            # saved by the writer
     assert out.shape == (2, _SIZES[0])
+
+
+def test_placed_dense_legs_own_devices_and_match_one_device():
+    """On a TPU (its facts faked here) a placed fleet serves dense too:
+    each bucket's legs live only on its devices, the lowered dense step
+    holds zero collectives, and every tier and the bank answer as the
+    one-device dense router from the same checkpoint does, bitwise."""
+    res = run_in_mesh_subprocess(_FLEET_PRELUDE + """
+    import tempfile
+    import repro.launch.serve as serve_mod
+    from repro.runtime import hlo_analysis as hlo
+
+    serve_mod._device_facts = lambda devices: ("tpu", 16 << 30)
+    mesh = make_local_mesh()
+    tiers = {"full": 1.0, "draft": 0.5}
+    with tempfile.TemporaryDirectory() as d:
+        RaggedFGFTServeEngine(fleet(), n_iter=1, tiers=tiers,
+                              filters="heat,lowpass").save(d)
+        placed = RaggedFGFTServeEngine.load(d, placement="auto", mesh=mesh)
+        flat = RaggedFGFTServeEngine.load(d, placement=False)
+    ownership, collectives, lowering = {}, {}, {}
+    for w, eng in placed.engines.items():
+        live, tier = eng._live, eng.default_tier
+        got = set()
+        for legs in live.legs.values():
+            for leaf in legs:
+                got |= {dev.id for dev in leaf.sharding.device_set}
+        ownership[str(w)] = [sorted(eng.placement.device_ids), sorted(got)]
+        lowering[str(w)] = [eng.lowering, flat.engines[w].lowering]
+        xp = eng.placement.place(jnp.zeros(
+            (eng.placement.batch, 2, eng.basis.n), jnp.float32))
+        txt = live.fns[tier].lower(
+            *live.operands(tier), live.tiers[tier]["spectrum"],
+            xp).compile().as_text()
+        collectives[str(w)] = sum(
+            hlo.collective_bytes(txt)["counts"].values())
+    sig = signals()
+    diff = 0.0
+    for tier in tiers:
+        for a, b in zip(placed.step(sig, tier=tier),
+                        flat.step(sig, tier=tier)):
+            diff = max(diff, float(np.abs(np.asarray(a) - np.asarray(b)).max()
+                                   / max(np.abs(np.asarray(b)).max(), 1e-30)))
+    for a, b in zip(placed.step_bank(sig), flat.step_bank(sig)):
+        diff = max(diff, float(np.abs(np.asarray(a) - np.asarray(b)).max()
+                               / max(np.abs(np.asarray(b)).max(), 1e-30)))
+    print(json.dumps({"ownership": ownership, "collectives": collectives,
+                      "lowering": lowering, "diff": diff}))
+    """, devices=4)
+    assert all(v == ["dense", "dense"] for v in res["lowering"].values()), res
+    for w, (want, got) in res["ownership"].items():
+        assert got == want, f"bucket {w} legs leaked off its devices"
+    assert all(c == 0 for c in res["collectives"].values()), res
+    assert res["diff"] == 0.0, res

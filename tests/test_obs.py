@@ -587,7 +587,8 @@ def test_service_dispatch_emits_stage_spans_in_order(sym_engine):
         "tier": "full", "w": 16, "b": 3, "r_pad": 8, "requests": 3,
         "rows": 6, "signal_elements": 6 * 16,
         "block_elements": 3 * 8 * 16,
-        "walk_stages": 3 * 2 * sym_engine.tiers["full"]["num_stages"]}
+        "walk_stages": 3 * 2 * sym_engine.tiers["full"]["num_stages"],
+        "lowering": "walk"}
     # the profiler mirror saw the same nesting, one span per stage
     names = [(op, name) for op, name, _ in fake.log]
     assert names == ([("enter", "serve.dispatch")]

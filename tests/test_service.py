@@ -625,6 +625,189 @@ def test_walk_stages_arg_sums_plan_stages_over_blocks_and_legs(
     assert disp["args"]["walk_stages"] == blocks * per_block
 
 
+def _tpu_facts(monkeypatch, free=16 << 30):
+    """The lowering chooser reads the devices as a TPU with ``free``
+    bytes free: the engines built or swapped meanwhile serve dense legs
+    (on the CPU) where those fit."""
+    import repro.launch.serve as serve_mod
+    monkeypatch.setattr(serve_mod, "_device_facts",
+                        lambda devices: ("tpu", free))
+
+
+@pytest.fixture(scope="module")
+def dense_routers(ragged3_engine, ragged3_gen_engine, tmp_path_factory):
+    """family -> the ragged3 router saved and loaded as a TPU would load
+    it: the same bases, served by the dense lowering."""
+    out = {}
+    with pytest.MonkeyPatch.context() as mp:
+        _tpu_facts(mp)
+        for family, router in (("sym", ragged3_engine),
+                               ("general", ragged3_gen_engine)):
+            path = tmp_path_factory.mktemp(f"dense-{family}")
+            router.save(path)
+            out[family] = RaggedFGFTServeEngine.load(path)
+    return out
+
+
+@pytest.mark.parametrize("kind", ["tier", "bank"])
+@pytest.mark.parametrize("family", ["sym", "general"])
+def test_dense_engine_dispatch_span_and_counters(request, dense_routers,
+                                                 family, layout, kind):
+    """A dense engine's dispatch names its lowering, walks no stage and
+    counts every block it serves dense, in ``stats()`` and the registry;
+    its answers equal the walking engine's.  The CPU picks the walk."""
+    import jax
+    from repro import obs
+    from repro.launch.serve import _device_facts
+    walk = request.getfixturevalue(
+        "ragged3_engine" if family == "sym" else "ragged3_gen_engine")
+    dense = dense_routers[family]
+    assert _device_facts(jax.devices()[:1])[0] == "cpu"
+    assert walk.engines[8].lowering == "walk"
+    assert dense.engines[8].lowering == "dense"
+    bank = kind == "bank"
+    tier, label = (None, "bank") if bank else ("draft", "draft")
+    reqs = [(gid, signals_for(walk, gid, rows, gid))
+            for gid, rows in ((0, 3), (2, 2))]
+    tracer = obs.default_tracer()
+    tracer.clear()
+    answers, stats = {}, {}
+    for name, router in (("walk", walk), ("dense", dense)):
+        svc = AsyncFGFTService(router, h=lowpass, clock=FakeClock(),
+                               auto_start=False, max_batch=8,
+                               name=f"{name}-{family}-{layout}-{kind}")
+        futs = [svc.submit(gid, x, tier=tier, bank=bank) for gid, x in reqs]
+        assert drain_all(svc) == [2]
+        answers[name] = [f.result(timeout=0).y for f in futs]
+        stats[name] = svc.stats()
+        svc.close()
+    spans = {s["args"]["lowering"]: s["args"] for s in tracer.spans()
+             if s["name"] == "serve.dispatch"}
+    blocks = 3 if layout == "whole" else 2
+    assert spans["walk"]["walk_stages"] > 0
+    assert spans["dense"]["walk_stages"] == 0
+    assert spans["dense"]["b"] == blocks
+    assert stats["dense"]["graph_blocks"] == blocks
+    assert stats["dense"]["dense_blocks"] == blocks
+    assert stats["walk"]["dense_blocks"] == 0
+    assert service_mod._OBS_DENSE_BLOCKS.value(
+        service=f"dense-{family}-{layout}-{kind}", tier=label) == blocks
+    for a, b in zip(answers["dense"], answers["walk"]):
+        np.testing.assert_allclose(a, b, rtol=1e-5,
+                                   atol=1e-5 * np.abs(b).max())
+
+
+@pytest.mark.parametrize("kw, free, want", [
+    ({}, 16 << 30, "dense"),
+    ({}, 1 << 10, "walk"),                  # three copies of the legs
+    ({"precision": "bf16"}, 16 << 30, "walk"),
+    ({"backend": "pallas"}, 16 << 30, "walk"),
+    ({"fused": False}, 16 << 30, "walk"),
+])
+def test_engine_lowering_follows_the_device(sym_batch48, monkeypatch, kw,
+                                            free, want):
+    """Only a fused f32 xla engine whose legs fit the free memory of a
+    TPU serves dense; the same engine on the CPU walks."""
+    mats, basis = sym_batch48
+    make = lambda: FGFTServeEngine(  # noqa: E731
+        mats, basis=basis, tiers={"full": 1.0, "draft": 0.5},
+        filters="heat", **kw)
+    assert make().lowering == "walk"
+    _tpu_facts(monkeypatch, free)
+    assert make().lowering == want
+
+
+def test_router_buckets_share_the_free_memory(monkeypatch):
+    """Each bucket decides from the memory free when it installs, the
+    legs of the buckets installed before it taken: legs that fit the
+    device alone but not beside the smaller bucket's keep the walk."""
+    import gc
+    import jax
+    import repro.launch.serve as serve_mod
+    from repro.core.fgft import laplacian
+    from repro.graphs import community_graph
+    from repro.kernels.plan import DENSE_LEG_COPIES
+    laps = [laplacian(community_graph(n, seed=n)) for n in (12, 14, 28, 30)]
+    used = []
+
+    def build(limit):
+        def facts(devices):
+            gc.collect()
+            used.append(sum(a.nbytes for a in jax.live_arrays()))
+            return "tpu", limit - used[-1]
+        monkeypatch.setattr(serve_mod, "_device_facts", facts)
+        used.clear()
+        return RaggedFGFTServeEngine(laps, 64, n_iter=1,
+                                     tiers={"full": 1.0})
+
+    # one full-chain sym leg a graph: (2, w, w) f32 a bucket
+    legs = {w: 2 * w * w * 4 for w in (16, 32)}
+    probe = build(1 << 40)
+    assert [e.lowering for e in probe.engines.values()] == ["dense"] * 2
+    used_16, used_32 = used
+    # the bucket 32 alone (its in-use memory less bucket 16's legs)
+    # fits three copies of its legs, beside bucket 16's it does not
+    limit = used_32 + DENSE_LEG_COPIES * legs[32] - legs[16] // 2
+    assert limit - (used_32 - legs[16]) >= DENSE_LEG_COPIES * legs[32]
+    del probe
+    router = build(limit)
+    assert limit - used[0] >= DENSE_LEG_COPIES * legs[16]
+    assert router.engines[16].lowering == "dense"
+    assert router.engines[32].lowering == "walk"
+
+
+@pytest.mark.parametrize("action", ["refresh", "refit"])
+def test_dense_legs_survive_same_shape_hot_swap(monkeypatch, action):
+    """A swap on a dense engine reuses every compiled program, the leg
+    build's included: a Lemma-1 refresh keeps the chain and so its legs
+    (no leg build runs), a refit builds new ones.  The new version
+    answers as the walk of its basis."""
+    from repro.kernels import plan as plan_mod
+    from repro.kernels.plan import ApplyPlan
+    from repro.dynamic import RefitPolicy
+    from repro.graphs import weight_jitter
+    _tpu_facts(monkeypatch)
+    eng = _dyn_engine()
+    if action == "refit":
+        eng.controller.policy = RefitPolicy(
+            refresh=1e-9, extend=1e-9, refit=1e-9, num_probes=16,
+            max_extends=0)
+    x = jnp.asarray(np.random.default_rng(4).standard_normal(
+        (2, 4, 12)).astype(np.float32))
+    eng.warmup(x)
+    live0 = eng._live
+    assert live0.lowering == "dense"
+    progs = [live0.fns["full"], live0.row_fns["full"]]
+    sizes0 = [p._cache_size() for p in progs]
+    builds0 = plan_mod._legs_program.cache_info().misses
+    built = []
+    dense_legs = plan_mod.dense_legs
+    monkeypatch.setattr(plan_mod, "dense_legs",
+                        lambda *a: built.append(1) or dense_legs(*a))
+    y0 = np.asarray(eng.step(x, lowpass))
+    adj = (np.abs(np.asarray(eng._laps_host[0])) *
+           (1 - np.eye(12))).astype(np.float32)
+    eng.apply_updates(0, weight_jitter(adj, 6, scale=0.2, seed=1))
+    assert eng.maintain()["action"] == action
+    live1 = eng._live
+    assert live1.version == live0.version + 1
+    assert live1.lowering == "dense"
+    kept = live1.legs is live0.legs
+    assert kept == (action == "refresh")
+    assert len(built) == (0 if kept else 1)
+    assert [live1.fns["full"], live1.row_fns["full"]] == progs
+    assert [p._cache_size() for p in progs] == sizes0
+    assert plan_mod._legs_program.cache_info().misses == builds0
+    y1 = np.asarray(eng.step(x, lowpass))
+    assert np.abs(y1 - y0).max() > 0
+    basis = eng.basis
+    want = ApplyPlan(family=basis.kind, mode="operator", n=12,
+                     batched=True).operator(basis.fwd, basis.bwd,
+                                            lowpass(basis.spectrum), x)
+    np.testing.assert_allclose(y1, np.asarray(want), rtol=1e-5,
+                               atol=1e-5 * np.abs(np.asarray(want)).max())
+
+
 def _directed_fleet(sizes, seed=0):
     """Directed Laplacians L = D_out - A of random graphs: each edge of
     an undirected G(n, p) keeps one direction, chosen at random."""
@@ -877,8 +1060,7 @@ def test_warmed_engine_serves_without_compiles(ragged3_engine, layout):
 # ---------------------------------------------------------------------------
 
 
-@pytest.fixture()
-def dyn_engine():
+def _dyn_engine():
     from repro.core.fgft import laplacian
     from repro.dynamic import RefitPolicy
     from repro.graphs import erdos_renyi
@@ -889,6 +1071,11 @@ def dyn_engine():
                            policy=RefitPolicy(refresh=1e-9, extend=10.0,
                                               refit=10.0, num_probes=16,
                                               max_extends=0))
+
+
+@pytest.fixture()
+def dyn_engine():
+    return _dyn_engine()
 
 
 def test_maintain_now_inline_counts_swaps(dyn_engine):

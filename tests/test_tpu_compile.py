@@ -12,6 +12,7 @@ worker imports this file.  These tests compile in their own process for
 the same reason.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -22,7 +23,8 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
 
 from repro.core import eigenbasis as eb
 from repro.kernels import butterfly as bf
-from repro.kernels.plan import ApplyPlan, clear_plan_cache
+from repro.kernels.plan import (ApplyPlan, _legs_program, clear_plan_cache,
+                                dense_leg_count)
 
 
 @pytest.fixture(scope="module")
@@ -147,3 +149,84 @@ def test_row_program_compiles_at_4096(one_chip, native_pallas, family,
         _spec(one_chip, (), jnp.int32)).compile()
     text = compiled.as_text()
     assert ("tpu_custom_call" in text) == (backend == "pallas")
+
+
+def _entry(text: str) -> list:
+    """The instructions of a compiled module's entry computation."""
+    body = text[text.index("\nENTRY "):]
+    return body[:body.index("\n}")].splitlines()[1:]
+
+
+@pytest.mark.parametrize("row", [False, True])
+@pytest.mark.parametrize("mode", ["operator", "bank"])
+@pytest.mark.parametrize("family", ["sym", "general"])
+def test_dense_program_compiles_at_4096(one_chip, family, mode, row):
+    """The dense lowering of the same bucket, whole and per graph: the
+    matmuls read the (3, 4096, 4096) legs in place (a row's slice fuses
+    into them), so the entry computation materializes no leg."""
+    n, b, rows, filters = 4096, 3, 8, 5
+    plan = ApplyPlan(family=family, mode=mode, n=n, batched=True,
+                     lowering="dense", row=row)
+    legs = tuple(_spec(one_chip, (b, n, n))
+                 for _ in range(dense_leg_count(family)))
+    scale = (_spec(one_chip, (b, n)) if mode == "operator"
+             else _spec(one_chip, (b, filters, n)))
+    x = _spec(one_chip, (rows, n) if row else (b, rows, n))
+    args = (legs, scale, x) + ((_spec(one_chip, (), jnp.int32),) if row
+                               else ())
+    text = plan.program().lower(*args).compile().as_text()
+    assert "tpu_custom_call" not in text
+    made = [line for line in _entry(text)
+            if re.search(r"= f32\[(\d+,)?4096,4096\]", line)
+            and " parameter(" not in line]
+    assert made == []
+
+
+@pytest.mark.parametrize("family, stages, cuts", [
+    ("sym", 1206, (300, 600, None)),
+    ("general", 98, (26, 39, None)),
+])
+def test_dense_leg_build_compiles_at_4096(one_chip, family, stages, cuts):
+    """The swap-time leg build for a bucket of three graphs at the
+    fleets' full depths and three tier cuts fits the chip."""
+    n, b = 4096, 3
+    width = n // 2 if family == "sym" else n
+    tables = _tables(one_chip, family, b, stages, width)
+    compiled = _legs_program(family, n, True, cuts).lower(
+        tables, tables).compile()
+    mem = compiled.memory_analysis()
+    legs = len(cuts) * dense_leg_count(family) * b * n * n * 4
+    # the legs, and the output tuple's few index bytes
+    assert legs <= mem.output_size_in_bytes < legs + (1 << 20)
+    assert mem.temp_size_in_bytes + legs < 4e9
+
+
+@pytest.mark.parametrize("mode", ["operator", "bank"])
+@pytest.mark.parametrize("family", ["sym", "general"])
+def test_placed_dense_program_splits_over_four_chips(topo, family, mode):
+    """A placed bucket of four graphs at n = 4096, one a chip: the dense
+    step and the leg build that feeds it, both on batch-split operands,
+    compile for the 2x2 mesh with no collective."""
+    from repro.runtime import hlo_analysis as hlo
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
+    n, b, rows, filters = 4096, 4, 8, 5
+
+    def split(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(
+            mesh, PartitionSpec("data", *(None,) * (len(shape) - 1))))
+
+    plan = ApplyPlan(family=family, mode=mode, n=n, batched=True,
+                     lowering="dense")
+    legs = tuple(split((b, n, n)) for _ in range(dense_leg_count(family)))
+    scale = (split((b, n)) if mode == "operator"
+             else split((b, filters, n)))
+    step = plan.program().lower(legs, scale,
+                                split((b, rows, n))).compile().as_text()
+    assert sum(hlo.collective_bytes(step)["counts"].values()) == 0
+    stages, width = (1206, n // 2) if family == "sym" else (98, n)
+    tables = (tuple(split((b, stages, width), jnp.int32) for _ in range(2))
+              + tuple(split((b, stages, width))
+                      for _ in range(3 if family == "sym" else 2)))
+    build = _legs_program(family, n, True, (None,)).lower(
+        tables, tables).compile().as_text()
+    assert sum(hlo.collective_bytes(build)["counts"].values()) == 0
