@@ -33,11 +33,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.plan import ApplyPlan, leg_orientation
+from repro.runtime.sharding import matrix_batch_sharding
+from repro.tables import StagedG, StagedT
 from . import gtransform as gt
 from . import ttransform as tt
-from .staging import (StagedG, StagedT, default_cut_ladder,
-                      pack_g_batch_pair, pack_g_pair, pack_t_batch_pair,
-                      pack_t_pair, select_cut)
+from .staging import (default_cut_ladder, pack_g_batch_pair, pack_g_pair,
+                      pack_t_batch_pair, pack_t_pair, select_cut)
 from .types import GFactors, TFactors
 
 SYMMETRIC = "sym"
@@ -357,7 +359,6 @@ class ApproxEigenbasis:
         if mesh is not None and batched:
             # unbatched (n, n) input has no batch axis to spread — only a
             # (B, n, n) stack shards; awkward B falls back to replication
-            from repro.runtime.sharding import matrix_batch_sharding
             mats = jax.device_put(
                 mats, matrix_batch_sharding(mesh, mats.ndim,
                                             batch=mats.shape[0]))
@@ -461,7 +462,6 @@ class ApproxEigenbasis:
                              f"{g_old}, got {num_transforms}")
         n = self.n
         if mesh is not None and self.batched:
-            from repro.runtime.sharding import matrix_batch_sharding
             mats = jax.device_put(
                 mats, matrix_batch_sharding(mesh, mats.ndim,
                                             batch=mats.shape[0]))
@@ -508,7 +508,6 @@ class ApproxEigenbasis:
 
     def _plan(self, mode: str, backend: str, num_stages: Optional[int],
               precision: str, keep: str = "head", fused: bool = True):
-        from repro.kernels.plan import ApplyPlan
         return ApplyPlan(family=self.kind, mode=mode, n=self.n,
                          batched=self.batched, backend=backend,
                          num_stages=num_stages, keep=keep,
@@ -525,7 +524,6 @@ class ApproxEigenbasis:
         ``select_tier``; DESIGN.md §9).  ``precision="bf16"`` runs bf16
         table storage with f32 accumulation (DESIGN.md §13).
         """
-        from repro.kernels.plan import leg_orientation
         staged = self.bwd if inverse else self.fwd
         keep = leg_orientation(self.kind)[0 if inverse else 1]
         plan = self._plan("apply", backend, num_stages, precision, keep)
@@ -596,7 +594,6 @@ class ApproxEigenbasis:
         code change (runtime/sharding.py)."""
         if not self.batched:
             return self
-        from repro.runtime.sharding import matrix_batch_sharding
         batch = int(self.spectrum.shape[0])
 
         def put(leaf):

@@ -22,9 +22,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.plan import ApplyPlan
+from repro.tables import StagedG
 from . import gtransform as gt
 from .baselines import factorize_orthonormal
-from .staging import StagedG, pack_g, pack_g_adjoint
+from .staging import pack_g, pack_g_adjoint
 
 
 class ButterflyParams(NamedTuple):
@@ -166,7 +168,6 @@ def compressed_linear_apply(comp: CompressedLinear, x: jnp.ndarray,
     """y ~= W x through the compressed factors: the fused symmetric
     operator (H) followed by the staged Q apply.  ``x``: (..., n);
     ``backend`` as in kernels/plan.py (DESIGN.md §4)."""
-    from repro.kernels.plan import ApplyPlan
     y = ApplyPlan.for_staged(comp.h_fwd, mode="operator",
                              backend=backend).operator(
         comp.h_fwd, comp.h_adj, comp.diag, x)

@@ -19,9 +19,10 @@ import numpy as np
 
 from . import gtransform as gt
 from . import ttransform as tt
-from .staging import StagedG, StagedT, pack_g_pair, pack_t_pair, select_cut
-from .types import GFactors, TFactors
 from repro.kernels.plan import ApplyPlan, leg_orientation
+from repro.tables import StagedG, StagedT
+from .staging import pack_g_pair, pack_t_pair, select_cut
+from .types import GFactors, TFactors
 
 
 def laplacian(adj: np.ndarray, normalized: bool = False) -> np.ndarray:

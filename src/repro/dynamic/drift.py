@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.gtransform import full_f32_matmuls
-from repro.core.staging import table_arrays as _tables
+from repro.tables import table_arrays as _tables
 from repro.kernels.plan import ApplyPlan
 
 _EPS = 1e-30

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.gtransform import full_f32_matmuls
+from repro.kernels.plan import ApplyPlan
 
 
 
@@ -223,7 +224,6 @@ def _prefix_spectrum_program(batched: bool, n: int,
     """Cached jitted per-tier Lemma-1 refresh on the ``num_stages``
     prefix basis (DESIGN.md §9 tiers keep their own refit spectrum
     across hot swaps; ``None`` = full chain)."""
-    from repro.kernels.plan import ApplyPlan
     table_op = ApplyPlan(family="sym", mode="apply", n=n,
                          batched=batched, keep="tail",
                          num_stages=num_stages).table_op()

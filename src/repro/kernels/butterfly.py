@@ -43,7 +43,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.staging import StagedG, table_arrays, truncate_staged
+from repro.tables import StagedG, table_arrays, truncate_staged
 
 DEFAULT_BLOCK_B = 128
 

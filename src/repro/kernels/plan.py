@@ -13,7 +13,7 @@ construct plans instead of hand-wiring kernel dispatch, so the
 nothing" invariant (DESIGN.md §11) holds by construction: programs take
 the staged tables as ARGUMENTS and are cached on the plan alone.
 
-Program signatures (``tables`` = ``core/staging.py::table_arrays``
+Program signatures (``tables`` = ``repro/tables.py::table_arrays``
 tuples, i.e. the device arrays without the host ``cuts``/``n`` tail —
 ``ApplyPlan.prepare`` produces them under the plan's precision policy):
 
@@ -83,8 +83,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro import obs
-from repro.core.staging import (StagedG, StagedT, TABLE_PRECISIONS,
-                                pad_batch, table_arrays, with_precision)
+from repro.tables import (StagedG, StagedT, TABLE_PRECISIONS, pad_batch,
+                          table_arrays, with_precision)
 from repro.runtime.sharding import BucketPlacement
 from . import butterfly as _bf
 from . import ref as _ref
@@ -219,7 +219,7 @@ class ApplyPlan:
         (prepare once per basis version, off the hot path).
 
         With a ``placement``, the batch axis first pads to the per-device
-        quantum with structural no-op rows (staging.pad_batch) and every
+        quantum with structural no-op rows (tables.pad_batch) and every
         leaf is device_put onto the bucket's sub-mesh, batch-split — the
         compiled program then runs collective-free, each device owning
         its graphs end-to-end."""

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.staging import StagedG, StagedT, truncate_staged
+from repro.tables import StagedG, StagedT, truncate_staged
 
 
 def staged_g_apply(staged: StagedG, x: jnp.ndarray,

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.staging import StagedT, table_arrays, truncate_staged
+from repro.tables import StagedT, table_arrays, truncate_staged
 from .butterfly import (DEFAULT_BLOCK_B, _STATIC, batch_of_one,
                         chain_call)
 

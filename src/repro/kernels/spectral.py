@@ -23,7 +23,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.staging import StagedG, StagedT, table_arrays, truncate_staged
+from repro.tables import StagedG, StagedT, table_arrays, truncate_staged
 from .butterfly import (DEFAULT_BLOCK_B, G_NOOP, _STATIC, _g_pair,
                         batch_of_one, chain_call)
 from .shear import T_NOOP, _t_pair

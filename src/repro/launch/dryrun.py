@@ -70,16 +70,10 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
 
     t0 = time.time()
     if shape.mode == "train":
-        if overrides and overrides.get("pod_compress"):
-            bundle = steps_lib.make_pod_compressed_train_step(
-                cfg, mesh, seq_len=shape.seq_len,
-                global_batch=shape.global_batch, fsdp=recipe["fsdp"],
-                moment_dtype=recipe["moment_dtype"])
-        else:
-            bundle = steps_lib.make_train_step(
-                cfg, mesh, seq_len=shape.seq_len,
-                global_batch=shape.global_batch,
-                fsdp=recipe["fsdp"], moment_dtype=recipe["moment_dtype"])
+        bundle = steps_lib.make_train_step(
+            cfg, mesh, seq_len=shape.seq_len,
+            global_batch=shape.global_batch,
+            fsdp=recipe["fsdp"], moment_dtype=recipe["moment_dtype"])
         args = (bundle.abstract_state, bundle.abstract_batch)
     elif shape.mode == "prefill":
         bundle = steps_lib.make_prefill_step(

@@ -1,66 +1,55 @@
-"""Serving driver: batched prefill + decode over a slot-based KV cache,
-plus a batched fast-graph-Fourier-transform service (--fgft).
+"""Graph-filter serving: the engines, the ragged router, and the CLI.
 
-CPU smoke (LM):
-  python -m repro.launch.serve --arch qwen2-1.5b --smoke --requests 8 \
-      --prompt-len 32 --gen-len 16
+``FGFTServeEngine`` factorizes a whole fleet of graph Laplacians in ONE
+jitted fit (core/eigenbasis.py) and then serves spectral-filter requests
+for all graphs per step through the batched fused ``Ubar diag(d) Ubar^T``
+operator — B graph Fourier transforms per dispatch instead of one.  Named
+quality TIERS map to anytime prefixes of the staged tables: each tier is
+its own jitted program over the cut tables (fewer stages -> proportionally
+less work), selectable per step, with per-tier counts in the serve stats.
+``RaggedFGFTServeEngine`` routes a fleet of mixed sizes through
+power-of-two buckets, one engine each.
 
-CPU smoke (FGFT — many graphs per step, DESIGN.md §7):
-  python -m repro.launch.serve --fgft --graphs 8 --graph-n 64 \
-      --transforms 384 --filter-steps 20
+The CLI (``serve_fgft``) builds one fleet and one engine whatever the
+mode, then serves it.  CPU smokes:
 
-CPU smoke (anytime quality tiers — per-step accuracy/latency dial,
-DESIGN.md §9; add --directed for the T-transform family):
-  python -m repro.launch.serve --fgft --graphs 8 --graph-n 64 \
+  # many graphs per step (DESIGN.md §7), every tier timed (§9)
+  python -m repro.launch.serve --graphs 8 --graph-n 64 \
       --tiers full:1.0,balanced:0.5,draft:0.25 --filter-steps 20
-
-CPU smoke (spectral filter bank — F responses per graph per step through
-the fused analysis->scale->synthesis path, DESIGN.md §8):
+  # the spectral filter bank through the fused path (§8); add
+  # --directed anywhere for the T-transform family
   python -m repro.launch.serve --filter heat,tikhonov,wavelets:4 \
       --graphs 8 --graph-n 64 --filter-steps 20
-
-CPU smoke (heterogeneous fleet — graphs of mixed sizes routed through
-power-of-two buckets, one masked jitted fit + one jitted dispatch per
-bucket per step, DESIGN.md §10):
-  python -m repro.launch.serve --fgft --ragged --graphs 9 \
+  # a fleet of mixed sizes in power-of-two buckets (§10)
+  python -m repro.launch.serve --ragged --graphs 9 \
       --graph-sizes 24,48,64 --filter-steps 20
-
-CPU smoke (EVOLVING fleet — streaming edge updates, drift-triggered
-refits off the hot path, versioned hot swaps, DESIGN.md §11; combine
-with --ragged for per-bucket swaps):
-  python -m repro.launch.serve --fgft --dynamic --graphs 4 \
-      --graph-n 48 --update-rounds 4 --churn 0.02 --filter-steps 10
-
-The LM engine keeps a fixed pool of batch slots; finished requests release
-their slot and the next queued request prefills into it (continuous
-batching at slot granularity — decode never stalls on stragglers within
-the batch; finished rows keep decoding into a scratch position and are
-masked out, which is the SPMD-friendly form of request eviction).
-
-The FGFT engine factorizes a whole fleet of graph Laplacians in ONE jitted
-fit (core/eigenbasis.py) and then serves spectral-filter requests for all
-graphs per step through the batched fused ``Ubar diag(d) Ubar^T`` kernel —
-B graph Fourier transforms per dispatch instead of one.  Named quality
-TIERS map to anytime prefixes of the staged tables: each tier is its own
-jitted program over the cut tables (fewer stages -> proportionally less
-work), selectable per step, with per-tier counts in the serve stats.
+  # an evolving fleet: edge churn, drift-triggered refits, hot swaps (§11)
+  python -m repro.launch.serve --dynamic --graphs 4 --graph-n 48 \
+      --update-rounds 4 --churn 0.02 --filter-steps 10
+  # the async front door under a closed-loop load (§12)
+  python -m repro.launch.serve --serve-async --graphs 4 --graph-n 32 \
+      --load-requests 64 --load-workers 4
 """
 from __future__ import annotations
 
 import argparse
 import pathlib
+import threading
 import time
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.configs import ARCH_NAMES, get_config
 from repro.launch.mesh import auto_mesh, make_local_mesh
-from repro.models import transformer as tfm
+from repro.launch.service import (AsyncFGFTService, ShedError,
+                                  closed_loop_load, open_loop_load)
+from repro.runtime.sharding import (FleetPlacement, fleet_placement,
+                                    matrix_batch_sharding)
+from repro.tables import TABLE_PRECISIONS, table_arrays, with_precision
 
 DEFAULT_TIERS = {"full": 1.0, "balanced": 0.5, "draft": 0.25}
 
@@ -94,9 +83,7 @@ _OBS_MAINTAIN = obs.counter("maintain_actions_total",
 def _tables(staged, precision: str = "f32") -> tuple:
     """Device table arrays of a StagedG/StagedT at the serving precision
     (``precision="bf16"`` casts the value tables ONCE per swap, matching
-    ``ApplyPlan.prepare``; deferred import keeps serve.py import-light
-    before mesh setup)."""
-    from repro.core.staging import table_arrays, with_precision
+    ``ApplyPlan.prepare``)."""
     return table_arrays(with_precision(staged, precision))
 
 
@@ -179,19 +166,9 @@ def parse_tiers(spec: str) -> Dict[str, float]:
 
 
 def parse_args(argv=None):
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", choices=ARCH_NAMES)
-    ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--requests", type=int, default=8)
-    ap.add_argument("--batch-slots", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=32)
-    ap.add_argument("--gen-len", type=int, default=16)
-    ap.add_argument("--max-len", type=int, default=128)
+    ap = argparse.ArgumentParser(
+        description="Fit a fleet of graphs and serve filters over it.")
     ap.add_argument("--seed", type=int, default=0)
-    # batched FGFT service
-    ap.add_argument("--fgft", action="store_true",
-                    help="serve batched graph Fourier transforms instead "
-                         "of an LM")
     ap.add_argument("--graphs", type=int, default=8,
                     help="number of graphs served per step (B)")
     ap.add_argument("--graph-n", type=int, default=64)
@@ -237,13 +214,13 @@ def parse_args(argv=None):
                          "tables (DESIGN.md §9)")
     ap.add_argument("--filter", default=None,
                     help="serve a spectral filter BANK through the fused "
-                         "analysis->scale->synthesis path (implies "
-                         "--fgft); comma-separated responses, e.g. "
+                         "analysis->scale->synthesis path; "
+                         "comma-separated responses, e.g. "
                          "'heat:3.0,tikhonov,lowpass,wavelets:4' "
                          "(repro/spectral/filters.py::named_responses)")
     # dynamic (evolving-graph) serving, DESIGN.md §11
     ap.add_argument("--dynamic", action="store_true",
-                    help="serve an EVOLVING fleet (implies --fgft): per "
+                    help="serve an EVOLVING fleet: per "
                          "round, stream edge-update batches into the "
                          "engine (apply_updates), run the drift-triggered "
                          "refit controller (maintain) off the hot path, "
@@ -259,8 +236,8 @@ def parse_args(argv=None):
                          "(default: the RefitPolicy defaults)")
     # async serving front-end (DESIGN.md §12)
     ap.add_argument("--serve-async", action="store_true",
-                    help="serve through the ASYNC front-end (implies "
-                         "--fgft): bounded request queue with load "
+                    help="serve through the ASYNC front-end: "
+                         "bounded request queue with load "
                          "shedding, cross-tenant micro-batching into "
                          "fused dispatches, background maintenance, "
                          "per-tier SLO stats (launch/service.py)")
@@ -287,8 +264,6 @@ def parse_args(argv=None):
                     help="write metrics.json + metrics.prom snapshots "
                          "of the obs registry into DIR on exit")
     args = ap.parse_args(argv)
-    if args.filter or args.ragged or args.dynamic or args.serve_async:
-        args.fgft = True
     args.policy = None
     if args.drift_thresholds:
         try:
@@ -299,8 +274,6 @@ def parse_args(argv=None):
                      "floats: refresh,extend,refit")
         from repro.dynamic.refit import RefitPolicy
         args.policy = RefitPolicy(refresh=lo, extend=mid, refit=hi)
-    if not args.fgft and args.arch is None:
-        ap.error("--arch is required unless --fgft/--filter is given")
     args.tier_map = (parse_tiers(args.tiers) if args.tiers
                      else dict(DEFAULT_TIERS))
     try:
@@ -365,7 +338,6 @@ class FGFTServeEngine:
         # deferred import: repro.core builds jnp constants at import time,
         # and launch modules must not touch jax state before mesh setup
         from repro.core import ApproxEigenbasis
-        from repro.core.staging import TABLE_PRECISIONS
         if precision not in TABLE_PRECISIONS:
             raise ValueError(f"precision must be one of "
                              f"{TABLE_PRECISIONS}, got {precision!r}")
@@ -959,7 +931,6 @@ class FGFTServeEngine:
         basis = self._live.basis
         laps = jnp.asarray(self._laps_host)
         if self.mesh is not None and basis.batched:
-            from repro.runtime.sharding import matrix_batch_sharding
             laps = jax.device_put(
                 laps, matrix_batch_sharding(self.mesh, laps.ndim,
                                             batch=laps.shape[0]))
@@ -1200,7 +1171,6 @@ def _resolve_fleet_placement(placement, mesh, bucket_of):
     loudly instead of mis-routing."""
     if placement is None:
         return None
-    from repro.runtime.sharding import FleetPlacement, fleet_placement
     if isinstance(placement, str):
         if placement != "auto":
             raise ValueError(f"placement must be None, 'auto' or a "
@@ -1581,316 +1551,259 @@ class RaggedFGFTServeEngine:
         return router
 
 
-def serve_fgft(args) -> dict:
-    """Build B graph Laplacians, fit them in one jit, serve filter steps
-    at every configured quality tier."""
-    from repro.core.fgft import laplacian
-    from repro.graphs import community_graph, directed_variant
+# ---------------------------------------------------------------------------
+# The CLI driver: one fleet builder, one engine constructor and one churn
+# routine under every mode; then the synchronous step loop or the async
+# front door under load.
+# ---------------------------------------------------------------------------
 
-    if args.serve_async:
-        from repro.launch.service import serve_fgft_async
-        return serve_fgft_async(args)
-    if args.dynamic:
-        return serve_fgft_dynamic(args)
-    if args.ragged:
-        return serve_fgft_ragged(args)
-    b, n = args.graphs, args.graph_n
-    g = args.transforms or int(2 * n * np.log2(n))
-    adjs = [community_graph(n, seed=s) for s in range(b)]
+def _lowpass(lam):
+    return 1.0 / (1.0 + lam)
+
+
+def _fleet(args):
+    """(sizes, GraphStream) of the CLI's fleet: --graphs community graphs
+    of side --graph-n, or of the --graph-sizes cycled under --ragged;
+    --directed orients their edges at random."""
+    from repro.dynamic import GraphStream
+    from repro.graphs import community_graph, directed_variant
+    sizes = ([args.size_list[i % len(args.size_list)]
+              for i in range(args.graphs)] if args.ragged
+             else [args.graph_n] * args.graphs)
+    adjs = [community_graph(n, seed=s) for s, n in enumerate(sizes)]
     if args.directed:
         adjs = [directed_variant(a, seed=s) for s, a in enumerate(adjs)]
-    laps = np.stack([laplacian(a) for a in adjs])
-    # --directed pins the factorization family explicitly: a numerically
-    # symmetric directed Laplacian must NOT silently reroute through the
-    # G path (the T path was unreachable from the service before this
-    # flag existed)
-    kind = "general" if args.directed else "auto"
-    mesh = make_local_mesh()
+    return sizes, GraphStream(adjs, directed=args.directed)
+
+
+def _engine(args, laps):
+    """The fleet's engine: the ragged router under --ragged, else one
+    FGFTServeEngine over the (B, n, n) stack.  --directed pins the T
+    family: a numerically symmetric directed Laplacian must not reroute
+    through the G path."""
+    kw = dict(backend=args.backend, mesh=make_local_mesh(),
+              filters=args.filter, tiers=args.tier_map,
+              kind="general" if args.directed else "auto",
+              dynamic=args.dynamic, policy=args.policy,
+              precision=args.precision, fused=args.fused)
+    if args.ragged:
+        return RaggedFGFTServeEngine(laps, args.transforms, **kw)
+    n = args.graph_n
+    g = args.transforms or int(2 * n * np.log2(n))
+    return FGFTServeEngine(jnp.asarray(np.stack(laps)), g, **kw)
+
+
+def _churn(args, stream, engine, rnd: int):
+    """One churn round: perturb --churn of every graph's edge slots, in
+    the stream and the engine in lockstep."""
+    from repro.graphs import edge_perturbation
+    for gid, n in enumerate(stream.sizes):
+        batch = edge_perturbation(
+            stream.adjs[gid], max(int(args.churn * n * (n - 1) / 2), 1),
+            seed=args.seed + 1000 * (rnd + 1) + gid,
+            directed=args.directed)
+        engine.apply_updates(gid, stream.apply(gid, batch))
+
+
+def serve_fgft(args) -> dict:
+    """Build the fleet, fit it, and serve it in the mode the flags name:
+    the async front door under load (--serve-async), update rounds
+    (--dynamic), the filter bank (--filter) or every quality tier."""
+    sizes, stream = _fleet(args)
+    laps = stream.laplacians()
     t0 = time.time()
-    engine = FGFTServeEngine(jnp.asarray(laps), g, backend=args.backend,
-                             mesh=mesh, filters=args.filter, kind=kind,
-                             tiers=args.tier_map,
-                             precision=args.precision, fused=args.fused)
+    engine = _engine(args, laps)
     fit_s = time.time() - t0
-    denom = (laps * laps).sum((1, 2))
-    rel = np.asarray(engine.basis.objective) / np.maximum(denom, 1e-30)
+    engines = (list(engine.engines.values()) if args.ragged
+               else [engine])
+    top = engines[-1]                 # the widest bucket, or the engine
+    if args.ragged:
+        rel = engine.rel_errors()
+    else:
+        denom = (np.stack(laps) ** 2).sum((1, 2))
+        rel = np.asarray(engine.basis.objective) / np.maximum(denom, 1e-30)
+    out = {"rel_error": rel, "kind": top.basis.kind, "sizes": sizes}
+    if args.ragged:
+        out["buckets"] = sorted(engine.engines)
+    print(f"[fgft] fitted {args.graphs} graphs (sizes "
+          f"{sorted(set(sizes))}, kind={top.basis.kind}) into "
+          f"{len(engines)} bucket(s) in {fit_s:.1f}s, mean rel error "
+          f"{rel.mean():.4f}")
+    if args.serve_async:
+        return {**out, **_serve_load(args, engine, sizes, stream)}
     rng = np.random.default_rng(args.seed)
-    x = jnp.asarray(rng.standard_normal(
-        (b, args.signals, n)).astype(np.float32))
-    print(f"[fgft] fitted {b} graphs (n={n}, g={g}, "
-          f"kind={engine.basis.kind}) in one jit: {fit_s:.1f}s, "
-          f"mean rel error {rel.mean():.4f}")
-    if args.filter:
-        f = len(engine.bank)
-        y = jax.block_until_ready(engine.step_bank(x))   # warmup/compile
+
+    def signals():
+        x = [rng.standard_normal((args.signals, n)).astype(np.float32)
+             for n in sizes]
+        return x if args.ragged else jnp.asarray(np.stack(x))
+
+    if args.dynamic:
+        return {**out, **_serve_rounds(args, engine, stream, signals)}
+    x = signals()
+
+    def timed(step) -> float:
+        """Wall seconds of --filter-steps calls of ``step``."""
         t0 = time.time()
+        y = None
         for _ in range(args.filter_steps):
-            y = engine.step_bank(x)
+            y = step(x)
         jax.block_until_ready(y)
-        dt = max(time.time() - t0, 1e-9)
-        served = args.filter_steps * b * f
-        print(f"[fgft] served {served} filter responses "
-              f"({f} filters x {b} graphs x {args.filter_steps} steps, "
+        return max(time.time() - t0, 1e-9)        # --filter-steps 0 ok
+
+    if args.filter:
+        jax.block_until_ready(engine.step_bank(x))      # warmup/compile
+        dt = timed(engine.step_bank)
+        f = len(top.bank)
+        served = args.filter_steps * args.graphs * f
+        print(f"[fgft] served {served} filter responses ({f} filters x "
+              f"{args.graphs} graphs x {args.filter_steps} steps, "
               f"{args.signals} signals each) in {dt:.2f}s — "
               f"{served / dt:.1f} responses/s through the fused bank "
-              f"path [{args.backend}]")
-        return {"rel_error": rel, "responses_per_s": served / dt,
-                "filters": engine.bank.names}
-    lowpass = lambda lam: 1.0 / (1.0 + lam)  # noqa: E731
+              f"path, {len(engines)} dispatch(es) a step [{args.backend}]")
+        return {**out, "responses_per_s": served / dt,
+                "filters": top.bank.names}
+    for name in top.tiers:
+        jax.block_until_ready(engine.step(x, _lowpass, tier=name))
+    for e in engines:                 # warmup/compile doesn't count
+        e.stats["steps"] = dict.fromkeys(e.tiers, 0)
     tier_stats = {}
-    for name, tier in engine.tiers.items():
-        y = jax.block_until_ready(engine.step(x, lowpass, tier=name))
-        engine.stats["steps"][name] = 0      # warmup/compile doesn't count
-        t0 = time.time()
-        for _ in range(args.filter_steps):
-            y = engine.step(x, lowpass, tier=name)
-        jax.block_until_ready(y)
-        dt = max(time.time() - t0, 1e-9)                 # --filter-steps 0 ok
-        served = args.filter_steps * b
-        tier_stats[name] = {
-            "transforms_per_s": served / dt,
-            "num_stages": tier["num_stages"],
-            "num_transforms": tier["num_transforms"],
-        }
-        print(f"[fgft]   tier {name!r}: g'={tier['num_transforms']}/{g} "
-              f"({tier['num_stages']} stages) — {served / dt:.1f} "
+    for name, tier in top.tiers.items():
+        rate = args.filter_steps * args.graphs / timed(
+            lambda x: engine.step(x, _lowpass, tier=name))
+        tier_stats[name] = {"transforms_per_s": rate,
+                            "num_stages": tier["num_stages"],
+                            "num_transforms": tier["num_transforms"]}
+        print(f"[fgft]   tier {name!r}: g'={tier['num_transforms']} of "
+              f"{top.tiers[top.default_tier]['num_transforms']} "
+              f"({tier['num_stages']} stages) — {rate:.1f} "
               f"graph-transforms/s [{args.backend}]")
     # headline number: the highest-quality tier, whatever its name.  The
     # stat is therefore "speedup_vs_best"; the old "speedup_vs_full" key
     # claimed a baseline tier named "full" but was silently computed
     # against the default (best) tier — it survives only as a deprecated
     # alias, and only when a tier named "full" actually exists.
-    base = tier_stats[engine.default_tier]["transforms_per_s"]
-    for name, ts in tier_stats.items():
+    base = tier_stats[top.default_tier]["transforms_per_s"]
+    for ts in tier_stats.values():
         ts["speedup_vs_best"] = ts["transforms_per_s"] / base
         if "full" in tier_stats:
             # deprecated alias: honest only against the tier literally
             # named "full" (== speedup_vs_best whenever full IS the best)
             ts["speedup_vs_full"] = (ts["transforms_per_s"]
                                      / tier_stats["full"]["transforms_per_s"])
-    served = args.filter_steps * b * len(engine.tiers)
+    served = args.filter_steps * args.graphs * len(tier_stats)
     print(f"[fgft] served {served} graph-filter requests across "
-          f"{len(engine.tiers)} tiers ({engine.stats['steps']})")
-    return {"rel_error": rel, "transforms_per_s": base,
-            "kind": engine.basis.kind, "tiers": tier_stats,
+          f"{len(tier_stats)} tiers")
+    return {**out, "transforms_per_s": base, "tiers": tier_stats,
             "stats": engine.stats}
 
 
-def serve_fgft_ragged(args) -> dict:
-    """Serve a heterogeneous fleet: --graphs Laplacians whose sizes cycle
-    through --graph-sizes, bucketed/fitted/dispatched per power-of-two
-    bucket (DESIGN.md §10)."""
-    from repro.core.fgft import laplacian
-    from repro.graphs import community_graph, directed_variant
-
-    sizes = [args.size_list[i % len(args.size_list)]
-             for i in range(args.graphs)]
-    adjs = [community_graph(n, seed=s) for s, n in enumerate(sizes)]
-    if args.directed:
-        adjs = [directed_variant(a, seed=s) for s, a in enumerate(adjs)]
-    laps = [laplacian(a) for a in adjs]
-    kind = "general" if args.directed else "auto"
-    mesh = make_local_mesh()
-    t0 = time.time()
-    router = RaggedFGFTServeEngine(
-        laps, args.transforms, backend=args.backend, mesh=mesh, kind=kind,
-        filters=args.filter, tiers=args.tier_map,
-        precision=args.precision, fused=args.fused)
-    fit_s = time.time() - t0
-    rel = router.rel_errors()
-    print(f"[fgft] fitted {len(laps)} graphs (sizes {sorted(set(sizes))}) "
-          f"into {router.num_buckets} buckets "
-          f"{sorted(router.engines)} in {fit_s:.1f}s, "
-          f"mean rel error {rel.mean():.4f}")
-    rng = np.random.default_rng(args.seed)
-    signals = [rng.standard_normal((args.signals, n)).astype(np.float32)
-               for n in sizes]
-    if args.filter:
-        f = len(next(iter(router.engines.values())).bank)
-        ys = router.step_bank(signals)       # warmup/compile per bucket
-        t0 = time.time()
-        for _ in range(args.filter_steps):
-            ys = router.step_bank(signals)
-        dt = max(time.time() - t0, 1e-9)
-        served = args.filter_steps * len(laps) * f
-        for y, n in zip(ys, sizes):
-            assert y.shape == (f, args.signals, n)
-        print(f"[fgft] served {served} ragged filter responses "
-              f"({f} filters x {len(laps)} graphs x {args.filter_steps} "
-              f"steps) in {dt:.2f}s — {served / dt:.1f} responses/s "
-              f"across {router.num_buckets} fused bank dispatches/step "
-              f"[{args.backend}]")
-        return {"rel_error": rel, "responses_per_s": served / dt,
-                "sizes": sizes, "buckets": sorted(router.engines)}
-    lowpass = lambda lam: 1.0 / (1.0 + lam)  # noqa: E731
-    ys = router.step(signals, lowpass)       # warmup/compile per bucket
-    router.reset_step_stats()                # warmup doesn't count
-    t0 = time.time()
-    for _ in range(args.filter_steps):
-        ys = router.step(signals, lowpass)
-    dt = max(time.time() - t0, 1e-9)
-    served = args.filter_steps * len(laps)
-    for y, n in zip(ys, sizes):
-        assert y.shape == (args.signals, n)
-    print(f"[fgft] served {served} ragged graph-filter requests "
-          f"({len(laps)} graphs x {args.filter_steps} steps, "
-          f"{args.signals} signals each) in {dt:.2f}s — "
-          f"{served / dt:.1f} graph-transforms/s across "
-          f"{router.num_buckets} bucket dispatches/step [{args.backend}]")
-    return {"rel_error": rel, "transforms_per_s": served / dt,
-            "sizes": sizes, "buckets": sorted(router.engines),
-            "stats": router.stats}
-
-
-def serve_fgft_dynamic(args) -> dict:
-    """Serve an EVOLVING fleet (DESIGN.md §11): per round, apply one
-    edge-update batch per graph, run the drift-triggered maintenance
-    tick (off the hot path), then keep answering filter queries through
-    the hot-swapped basis versions.  Works for both the uniform-size
-    engine and the ragged router (--ragged)."""
-    from repro.dynamic import GraphStream
-    from repro.graphs import (community_graph, directed_variant,
-                              edge_perturbation)
-
-    b = args.graphs
-    if args.ragged:
-        sizes = [args.size_list[i % len(args.size_list)] for i in range(b)]
-    else:
-        sizes = [args.graph_n] * b
-    adjs = [community_graph(n, seed=s) for s, n in enumerate(sizes)]
-    if args.directed:
-        adjs = [directed_variant(a, seed=s) for s, a in enumerate(adjs)]
-    stream = GraphStream(adjs, directed=args.directed)
-    laps = stream.laplacians()
-    kind = "general" if args.directed else "auto"
-    mesh = make_local_mesh()
-    t0 = time.time()
-    if args.ragged:
-        engine = RaggedFGFTServeEngine(
-            laps, args.transforms, backend=args.backend, mesh=mesh,
-            kind=kind, filters=args.filter, tiers=args.tier_map,
-            dynamic=True, policy=args.policy,
-            precision=args.precision, fused=args.fused)
-    else:
-        g = args.transforms or int(2 * args.graph_n
-                                   * np.log2(args.graph_n))
-        engine = FGFTServeEngine(
-            jnp.asarray(np.stack(laps)), g, backend=args.backend,
-            mesh=mesh, kind=kind, filters=args.filter,
-            tiers=args.tier_map, dynamic=True, policy=args.policy,
-            precision=args.precision, fused=args.fused)
-    fit_s = time.time() - t0
-    print(f"[fgft] fitted evolving fleet of {b} graphs in {fit_s:.1f}s; "
-          f"streaming {args.update_rounds} rounds at churn {args.churn}")
-    rng = np.random.default_rng(args.seed)
-
-    def signal_block():
-        if args.ragged:
-            return [rng.standard_normal((args.signals, n)).astype(
-                np.float32) for n in sizes]
-        return jnp.asarray(rng.standard_normal(
-            (b, args.signals, len(laps[0]))).astype(np.float32))
-
-    lowpass = lambda lam: 1.0 / (1.0 + lam)  # noqa: E731
-    ys = engine.step(signal_block(), lowpass)    # warmup/compile
+def _serve_rounds(args, engine, stream, signals) -> dict:
+    """--dynamic (DESIGN.md §11): per round, churn every graph, run the
+    drift-triggered maintenance tick off the hot path, then keep
+    answering filter steps through the hot-swapped versions."""
+    ys = engine.step(signals(), _lowpass)        # warmup/compile
     actions = []
     t_serve = t_maintain = 0.0
     for rnd in range(args.update_rounds):
-        for gid in range(b):
-            budget = max(int(args.churn * sizes[gid]
-                             * (sizes[gid] - 1) / 2), 1)
-            batch = edge_perturbation(
-                stream.adjs[gid], budget,
-                seed=args.seed + 1000 * (rnd + 1) + gid,
-                directed=args.directed)
-            dl = stream.apply(gid, batch)
-            engine.apply_updates(gid, dl)
+        _churn(args, stream, engine, rnd)
         t0 = time.time()
         res = engine.maintain()
         t_maintain += time.time() - t0
-        if args.ragged:
-            acts = sorted({r["action"] for r in res.values()})
-            actions.append("+".join(acts))
-            drift_max = max(float(np.max(r["post_drift"]))
-                            for r in res.values())
-        else:
-            actions.append(res["action"])
-            drift_max = float(np.max(res["post_drift"]))
+        ticks = list(res.values()) if args.ragged else [res]
+        actions.append("+".join(sorted({r["action"] for r in ticks})))
+        # maintain() already scored post-action drift; an extra fleet-
+        # wide probe pass here would just distort the serve/maintain split
+        drift_max = max(float(np.max(r["post_drift"])) for r in ticks)
         t0 = time.time()
         for _ in range(args.filter_steps):
-            ys = engine.step(signal_block(), lowpass)
-        jax.block_until_ready(ys if not args.ragged else ys[0])
+            ys = engine.step(signals(), _lowpass)
+        jax.block_until_ready(ys)
         t_serve += time.time() - t0
-        # maintain() already scored post-action drift; an extra fleet-
-        # wide probe pass here would just distort the serve/maintain
-        # split it prints
         print(f"[fgft]   round {rnd}: action={actions[-1]}, max drift "
               f"{drift_max:.4f}, versions {engine.versions.tolist()}")
-    served = args.update_rounds * args.filter_steps * b
+    served = args.update_rounds * args.filter_steps * args.graphs
     print(f"[fgft] served {served} graph-filter requests across "
-          f"{args.update_rounds} update rounds "
+          f"{args.update_rounds} update rounds at churn {args.churn} "
           f"(serve {t_serve:.2f}s, maintain {t_maintain:.2f}s) "
           f"[{args.backend}]")
-    dyn_stats = (engine.stats["dynamic"] if not args.ragged
-                 else {w: s["dynamic"] for w, s in engine.stats.items()})
+    dyn_stats = ({w: s["dynamic"] for w, s in engine.stats.items()}
+                 if args.ragged else engine.stats["dynamic"])
     print(f"[fgft] dynamic stats: {dyn_stats}")
     return {"actions": actions, "versions": engine.versions.tolist(),
             "serve_s": t_serve, "maintain_s": t_maintain,
             "stats": dyn_stats}
 
 
-class ServeEngine:
-    """Slot-based batched serving on top of prefill/decode_step."""
+def _serve_load(args, engine, sizes, stream) -> dict:
+    """--serve-async (DESIGN.md §12): wrap the engine in the async front
+    door and drive a closed- or open-loop load through it, with churn and
+    background maintenance under --dynamic; print the SLO summary."""
+    rng = np.random.default_rng(args.seed)
+    tiers = sorted(args.tier_map)
+    requests = []
+    for i in range(args.load_requests):
+        gid = i % args.graphs
+        x = rng.standard_normal((args.signals, sizes[gid])).astype(
+            np.float32)
+        requests.append((gid, x, None, True) if args.filter
+                        else (gid, x, tiers[i % len(tiers)], False))
+    interval = args.maintain_interval if args.dynamic else None
+    with AsyncFGFTService(engine, h=None if args.filter else _lowpass,
+                          max_queue=args.max_queue,
+                          max_batch=args.max_batch,
+                          maintain_interval=interval) as service:
+        # warm every (tier, shape) program before the timed load; a
+        # tight --max-queue sheds mid-burst, so drain and resubmit
+        # instead of crashing before the timed load starts
+        warm = []
+        for gid, x, tier, bank in requests[:args.graphs * len(tiers)]:
+            try:
+                warm.append(service.submit(gid, x, tier=tier, bank=bank))
+            except ShedError:
+                for f in warm:
+                    f.result()
+                warm = [service.submit(gid, x, tier=tier, bank=bank)]
+        for f in warm:
+            f.result()
+        service.reset_stats()                   # compile time isn't SLO
+        churn_stop = threading.Event()
 
-    def __init__(self, cfg, batch_slots: int, max_len: int):
-        self.cfg = cfg
-        self.b = batch_slots
-        self.max_len = max_len
-        self.params, _ = tfm.init_params(cfg, jax.random.PRNGKey(0))
-        self.cache, _ = tfm.init_cache(cfg, batch_slots, max_len)
-        self.pos = np.zeros(batch_slots, np.int32)
-        self.active = np.zeros(batch_slots, bool)
-        self.memory = None
-        self._decode = jax.jit(
-            lambda p, c, b: tfm.decode_step(p, cfg, c, b))
+        def churn():
+            rnd = 0
+            while not churn_stop.is_set():
+                _churn(args, stream, engine, rnd)
+                service.request_maintain()
+                rnd += 1
+                churn_stop.wait(0.05)
 
-    def _make_memory(self, rng, s):
-        if self.cfg.family == "vlm":
-            return jnp.asarray(rng.standard_normal(
-                (self.b, self.cfg.num_patches, self.cfg.d_model),
-                np.float32) * 0.02)
-        if self.cfg.family == "audio":
-            return jnp.asarray(rng.standard_normal(
-                (self.b, max(s // self.cfg.enc_ratio, 1), self.cfg.d_model),
-                np.float32) * 0.02)
-        return None
-
-    def prefill_slot(self, slot: int, prompt: np.ndarray, rng):
-        """Prefill one slot (batched across slots in production; per-slot
-        here for clarity — the cache scatter is slot-local either way)."""
-        s = len(prompt)
-        toks = np.zeros((self.b, s), np.int32)
-        toks[slot] = prompt
-        batch = {"tokens": jnp.asarray(toks)}
-        mem = self._make_memory(rng, s)
-        if mem is not None:
-            batch["memory"] = mem
-            self.memory = mem
-        logits, self.cache, _ = tfm.prefill(self.params, self.cfg,
-                                            self.cache, batch)
-        self.pos[slot] = s
-        self.active[slot] = True
-        return int(jnp.argmax(logits[slot, -1]))
-
-    def decode(self, tokens: np.ndarray):
-        """One decode step for all slots. tokens: (slots,) int32."""
-        batch = {"token": jnp.asarray(tokens[:, None]),
-                 "pos": jnp.asarray(self.pos)}
-        if self.memory is not None:
-            batch["memory"] = self.memory
-        logits, self.cache = self._decode(self.params, self.cache, batch)
-        self.pos[self.active] += 1
-        return np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
+        churner = threading.Thread(target=churn, name="churn")
+        if args.dynamic:
+            churner.start()
+        t0 = time.time()
+        if args.qps > 0:
+            results = open_loop_load(service, requests, args.qps)["results"]
+        else:
+            results = closed_loop_load(service, requests,
+                                       workers=args.load_workers)
+        elapsed = max(time.time() - t0, 1e-9)
+        churn_stop.set()
+        if churner.is_alive():
+            churner.join()
+        stats = service.stats()
+    failed = stats["errors"], stats["maintain"]["errors"]
+    if any(failed):
+        raise RuntimeError(f"[svc] {failed[0]} request(s) and {failed[1]} "
+                           f"maintenance tick(s) failed; no rate reported")
+    qps = len(results) / elapsed
+    print(f"[svc] {len(results)} requests in {elapsed:.2f}s -> "
+          f"{qps:.1f} qps sustained "
+          f"[{'open' if args.qps > 0 else 'closed'}-loop, "
+          f"{args.backend}]")
+    print(obs.format_slo(stats))
+    return {"qps": qps, "stats": stats,
+            "versions": sorted({r.version for r in results}),
+            "results": len(results)}
 
 
 def _export_obs(args):
@@ -1910,60 +1823,9 @@ def main(argv=None):
     from repro.launch.compile_cache import configure_compile_cache
     configure_compile_cache()
     try:
-        return _serve_main(args)
+        return serve_fgft(args)
     finally:
         _export_obs(args)
-
-
-def _serve_main(args):
-    if args.fgft:
-        return serve_fgft(args)
-    cfg = get_config(args.arch, smoke=args.smoke)
-    mesh = make_local_mesh()
-    rng = np.random.default_rng(args.seed)
-    with mesh:
-        engine = ServeEngine(cfg, args.batch_slots, args.max_len)
-        queue: List[np.ndarray] = [
-            rng.integers(0, cfg.vocab, args.prompt_len).astype(np.int32)
-            for _ in range(args.requests)]
-        done = 0
-        outputs = {}
-        slot_req: List[Optional[int]] = [None] * args.batch_slots
-        next_tok = np.zeros(args.batch_slots, np.int32)
-        remaining = np.zeros(args.batch_slots, np.int32)
-        req_id = 0
-        t0 = time.time()
-        decode_steps = 0
-        while done < args.requests:
-            # fill free slots
-            for slot in range(args.batch_slots):
-                if slot_req[slot] is None and queue:
-                    prompt = queue.pop(0)
-                    tok = engine.prefill_slot(slot, prompt, rng)
-                    slot_req[slot] = req_id
-                    outputs[req_id] = [tok]
-                    next_tok[slot] = tok
-                    remaining[slot] = args.gen_len - 1
-                    req_id += 1
-            toks = engine.decode(next_tok)
-            decode_steps += 1
-            for slot in range(args.batch_slots):
-                rid = slot_req[slot]
-                if rid is None:
-                    continue
-                outputs[rid].append(int(toks[slot]))
-                next_tok[slot] = toks[slot]
-                remaining[slot] -= 1
-                if remaining[slot] <= 0:
-                    engine.active[slot] = False
-                    slot_req[slot] = None
-                    done += 1
-        dt = time.time() - t0
-        total_tokens = sum(len(v) for v in outputs.values())
-        print(f"served {args.requests} requests, {total_tokens} tokens, "
-              f"{decode_steps} decode steps, {dt:.1f}s "
-              f"({total_tokens / dt:.1f} tok/s)")
-        return outputs
 
 
 if __name__ == "__main__":

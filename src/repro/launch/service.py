@@ -38,7 +38,7 @@ plus ``drain_once()`` runs the dispatcher inline on the caller's thread,
 and a fake clock makes every latency figure exact (tests/test_service.py).
 
 CPU smoke:
-  python -m repro.launch.serve --fgft --serve-async --graphs 4 \
+  python -m repro.launch.serve --serve-async --graphs 4 \
       --graph-n 32 --load-requests 64 --load-workers 4
 """
 from __future__ import annotations
@@ -1059,132 +1059,3 @@ def open_loop_load(service: AsyncFGFTService, requests: List[tuple],
     elapsed = max(time.monotonic() - t_start, 1e-9)
     return {"results": results, "shed": shed,
             "offered_qps": len(requests) / elapsed}
-
-
-def _print_slo(stats: dict):
-    """ONE formatting path for stats output: the obs text reporter
-    (obs/report.py) renders the snapshot; drivers only print it."""
-    print(obs.format_slo(stats))
-
-
-def serve_fgft_async(args) -> dict:
-    """CLI driver (``serve.py --fgft --serve-async``): build the fleet,
-    wrap it in the async front-end, run a closed- or open-loop load (with
-    churn + background maintenance when --dynamic), print the SLO
-    summary."""
-    import jax.numpy as jnp
-    from repro.core.fgft import laplacian
-    from repro.graphs import (community_graph, directed_variant,
-                              edge_perturbation)
-    from repro.launch.mesh import make_local_mesh
-    from repro.launch.serve import FGFTServeEngine, RaggedFGFTServeEngine
-
-    b = args.graphs
-    sizes = ([args.size_list[i % len(args.size_list)] for i in range(b)]
-             if args.ragged else [args.graph_n] * b)
-    adjs = [community_graph(n, seed=s) for s, n in enumerate(sizes)]
-    if args.directed:
-        adjs = [directed_variant(a, seed=s) for s, a in enumerate(adjs)]
-    laps = [laplacian(a) for a in adjs]
-    kind = "general" if args.directed else "auto"
-    mesh = make_local_mesh()
-    t0 = time.time()
-    if args.ragged:
-        engine = RaggedFGFTServeEngine(
-            laps, args.transforms, backend=args.backend, mesh=mesh,
-            kind=kind, filters=args.filter, tiers=args.tier_map,
-            dynamic=args.dynamic, policy=args.policy,
-            precision=getattr(args, "precision", "f32"),
-            fused=getattr(args, "fused", True))
-    else:
-        g = args.transforms or int(2 * args.graph_n
-                                   * np.log2(args.graph_n))
-        engine = FGFTServeEngine(
-            jnp.asarray(np.stack(laps)), g, backend=args.backend,
-            mesh=mesh, kind=kind, filters=args.filter,
-            tiers=args.tier_map, dynamic=args.dynamic,
-            policy=args.policy,
-            precision=getattr(args, "precision", "f32"),
-            fused=getattr(args, "fused", True))
-    print(f"[svc] fitted fleet of {b} graphs in {time.time() - t0:.1f}s")
-
-    rng = np.random.default_rng(args.seed)
-    tiers = sorted(args.tier_map)
-    requests = []
-    for i in range(args.load_requests):
-        gid = i % b
-        x = rng.standard_normal((args.signals, sizes[gid])).astype(
-            np.float32)
-        if args.filter:
-            requests.append((gid, x, None, True))
-        else:
-            requests.append((gid, x, tiers[i % len(tiers)], False))
-    lowpass = None if args.filter else (lambda lam: 1.0 / (1.0 + lam))
-    interval = args.maintain_interval if args.dynamic else None
-    with AsyncFGFTService(engine, h=lowpass, max_queue=args.max_queue,
-                          max_batch=args.max_batch,
-                          maintain_interval=interval) as service:
-        # warm every (tier, shape) program before the timed load; a
-        # tight --max-queue sheds mid-burst, so drain and resubmit
-        # instead of crashing before the timed load starts
-        warm = []
-        for req in requests[:min(len(requests), b * len(tiers))]:
-            try:
-                warm.append(service.submit(*req[:2], tier=req[2],
-                                           bank=req[3]))
-            except ShedError:
-                for f in warm:
-                    f.result()
-                warm = [service.submit(*req[:2], tier=req[2],
-                                       bank=req[3])]
-        for f in warm:
-            f.result()
-        service.reset_stats()                   # compile time isn't SLO
-        churn_stop = threading.Event()
-
-        def churn():
-            from repro.dynamic import GraphStream
-            stream = GraphStream(adjs, directed=args.directed)
-            rnd = 0
-            while not churn_stop.is_set():
-                for gid in range(b):
-                    budget = max(int(args.churn * sizes[gid]
-                                     * (sizes[gid] - 1) / 2), 1)
-                    batch = edge_perturbation(
-                        stream.adjs[gid], budget,
-                        seed=args.seed + 1000 * (rnd + 1) + gid,
-                        directed=args.directed)
-                    engine.apply_updates(gid, stream.apply(gid, batch))
-                service.request_maintain()
-                rnd += 1
-                churn_stop.wait(0.05)
-
-        churner = None
-        if args.dynamic:
-            churner = threading.Thread(target=churn, name="churn")
-            churner.start()
-        t0 = time.time()
-        if args.qps > 0:
-            out = open_loop_load(service, requests, args.qps)
-            results = out["results"]
-        else:
-            results = closed_loop_load(service, requests,
-                                       workers=args.load_workers)
-        elapsed = max(time.time() - t0, 1e-9)
-        if churner is not None:
-            churn_stop.set()
-            churner.join()
-        stats = service.stats()
-    failed = stats["errors"], stats["maintain"]["errors"]
-    if any(failed):
-        raise RuntimeError(f"[svc] {failed[0]} request(s) and {failed[1]} "
-                           f"maintenance tick(s) failed; no rate reported")
-    qps = len(results) / elapsed
-    print(f"[svc] {len(results)} requests in {elapsed:.2f}s -> "
-          f"{qps:.1f} qps sustained "
-          f"[{'open' if args.qps > 0 else 'closed'}-loop, "
-          f"{args.backend}]")
-    _print_slo(stats)
-    versions = sorted({r.version for r in results})
-    return {"qps": qps, "stats": stats, "versions": versions,
-            "results": len(results)}

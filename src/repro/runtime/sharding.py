@@ -1,14 +1,9 @@
-"""Logical-axis -> mesh-axis sharding rules (DP / TP / EP / SP / FSDP).
+"""Where the graph fleet lives on the mesh.
 
-Logical axes used by the model zoo:
-  batch     -> data parallel axes ("pod","data") / ("data",)
-  vocab, heads, ff, expert, inner -> tensor/expert parallel axis ("model")
-  kv_heads  -> "model" when divisible, else replicated (GQA with few KV heads)
-  embed     -> "data" when FSDP is on (fully-sharded params: required for
-               kimi-k2-1t); else replicated across data
-  kv_seq    -> decode-time sequence parallelism for underfilled batches
-               (long_500k: batch=1 shards the KV cache over "data")
-  layers    -> never sharded (scan axis)
+``matrix_batch_sharding`` spreads a fit's (B, ...) batch over the
+data-parallel axes; ``fleet_placement`` gives each ragged-router bucket
+its own device subset, so serving runs without collectives (DESIGN.md
+§14).  The LM stack's logical-axis rules are in runtime/lm_sharding.py.
 """
 from __future__ import annotations
 
@@ -20,8 +15,6 @@ from typing import Any, Dict, Mapping, Optional, Tuple
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.models.common import Axes, ModelConfig
 
 
 def dp_axes(mesh: Mesh):
@@ -54,83 +47,6 @@ def matrix_batch_sharding(mesh: Mesh, ndim: int,
     return NamedSharding(mesh, P(dp or None, *(None,) * (ndim - 1)))
 
 
-def make_rules(mesh: Mesh, cfg: ModelConfig, *, fsdp: bool = False,
-               seq_shard: bool = False,
-               global_batch: Optional[int] = None) -> Dict[str, Any]:
-    tp = mesh.shape.get("model", 1)
-    dp = dp_axes(mesh)
-    fsdp_n = mesh.shape.get("data", 1)
-
-    def fits(dim: int) -> bool:
-        return dim > 0 and dim % tp == 0
-
-    # batch: drop data-parallel axes until the global batch divides (decode
-    # at batch=1 falls back to a replicated batch + KV-seq sharding)
-    batch_rule: Any = dp
-    if global_batch is not None:
-        while batch_rule and global_batch % int(
-                np.prod([mesh.shape[a] for a in batch_rule])) != 0:
-            batch_rule = batch_rule[:-1]
-        batch_rule = batch_rule or None
-
-    return {
-        "batch": batch_rule,
-        "vocab": "model" if fits(cfg.vocab) else None,
-        "heads": "model" if fits(cfg.n_heads) else None,
-        "kv_heads": "model" if fits(cfg.n_kv_heads) else None,
-        "ff": "model" if fits(cfg.d_ff) else None,
-        "expert": "model" if fits(cfg.n_experts) else None,
-        "inner": "model",
-        "embed": ("data" if fsdp and cfg.d_model % fsdp_n == 0 else None),
-        "kv_seq": "data" if seq_shard else None,
-        "layers": None,
-        None: None,
-    }
-
-
-def spec_for(axes, rules) -> P:
-    """Map logical axes to a PartitionSpec, deduplicating mesh axes.
-
-    A mesh axis may appear at most once in a spec; the first logical axis
-    (left-to-right) claims it (e.g. MoE expert weights ("expert", "embed",
-    "ff") -> P("model", ..., None): "expert" wins the "model" axis and the
-    per-expert ff dim stays unsharded)."""
-    used = set()
-    out = []
-    for a in axes:
-        r = rules.get(a)
-        items = r if isinstance(r, tuple) else (r,) if r else ()
-        if any(m in used for m in items):
-            out.append(None)
-        else:
-            used.update(items)
-            out.append(r)
-    return P(*out)
-
-
-def sharding_tree(axes_tree, mesh: Mesh, rules) -> Any:
-    """Map an Axes-leaf tree to a NamedSharding tree (same structure)."""
-    return jax.tree.map(
-        lambda leaf: NamedSharding(mesh, spec_for(leaf.axes, rules)),
-        axes_tree, is_leaf=lambda x: isinstance(x, Axes))
-
-
-def batch_sharding(mesh: Mesh, rules, *, with_memory=False,
-                   mode: str = "train"):
-    """Shardings for input batches."""
-    bsp = rules["batch"]
-    tok = NamedSharding(mesh, P(bsp, None))
-    if mode in ("train", "prefill"):
-        out = {"tokens": tok}
-        if with_memory:
-            out["memory"] = NamedSharding(mesh, P(bsp, None, None))
-        return out
-    out = {"token": tok, "pos": NamedSharding(mesh, P(bsp))}
-    if with_memory:
-        out["memory"] = NamedSharding(mesh, P(bsp, None, None))
-    return out
-
-
 # ---------------------------------------------------------------------------
 # Fleet placement: whole ragged-router buckets -> disjoint device subsets.
 #
@@ -143,7 +59,7 @@ def batch_sharding(mesh: Mesh, rules, *, with_memory=False,
 # compiled-program cache key (kernels/plan.py).  Graphs partition along the
 # batch axis over the bucket's own single-axis sub-mesh; batches that don't
 # divide the device count are padded with structural no-op rows
-# (core/staging.py::pad_batch) rather than resharded.
+# (repro/tables.py::pad_batch) rather than resharded.
 # ---------------------------------------------------------------------------
 
 
@@ -216,7 +132,7 @@ class BucketPlacement:
     Frozen + tuple-valued -> hashable, so plans carrying a placement stay
     valid lru_cache keys.  ``batch_padded`` is the serving-time leading dim:
     the smallest multiple of the device count >= batch (pad rows are
-    structural no-ops, see staging.pad_batch)."""
+    structural no-ops, see tables.pad_batch)."""
     device_ids: Tuple[int, ...]
     batch: int
 
@@ -245,7 +161,7 @@ class BucketPlacement:
     def place(self, arr):
         """Pad axis 0 with zero rows to batch_padded and device_put.
 
-        For staged tables use staging.pad_batch first (pads are identity
+        For staged tables use tables.pad_batch first (pads are identity
         rotations there, not zeros) and place each leaf with this."""
         arr = jax.numpy.asarray(arr)
         pad = self.batch_padded - arr.shape[0]
@@ -318,21 +234,3 @@ def fleet_placement(mesh: Mesh, bucket_sizes: Mapping[Any, int],
 def single_bucket_placement(mesh: Mesh, batch: int) -> BucketPlacement:
     """All data-axis devices as one bucket (the non-ragged engine)."""
     return fleet_placement(mesh, {"all": batch})["all"]
-
-
-def check_divisibility(cfg: ModelConfig, mesh: Mesh, global_batch: int,
-                       mode: str):
-    """Human-readable divisibility report (surfaced by the dry-run)."""
-    tp = mesh.shape.get("model", 1)
-    dp = int(np.prod([mesh.shape[a] for a in dp_axes(mesh)]))
-    notes = []
-    if global_batch % dp != 0:
-        notes.append(f"batch {global_batch} not divisible by dp={dp}: "
-                     "falls back to sequence/KV sharding where possible")
-    if cfg.n_heads and cfg.n_heads % tp != 0:
-        notes.append(f"heads {cfg.n_heads} % tp={tp} != 0 (padded shards)")
-    if cfg.n_kv_heads and cfg.n_kv_heads % tp != 0:
-        notes.append(f"kv_heads {cfg.n_kv_heads} < tp={tp}: KV replicated")
-    if cfg.n_experts and cfg.n_experts % tp != 0:
-        notes.append(f"experts {cfg.n_experts} % tp={tp} != 0")
-    return notes

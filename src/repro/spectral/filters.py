@@ -28,6 +28,8 @@ from typing import Callable, Dict, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.plan import ApplyPlan
+
 Response = Callable[[jnp.ndarray], jnp.ndarray]
 
 
@@ -244,7 +246,6 @@ class SpectralFilterBank:
         runs the one-launch kernel).  ``fused=False`` is the per-filter
         composition — kept as the semantics baseline and the thing
         benchmarks/fig8_spectral.py races against."""
-        from repro.kernels.plan import ApplyPlan
         basis = self.basis
         if not fused:
             axis = 1 if basis.batched else 0

@@ -5,7 +5,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import get_config
 from repro.data.pipeline import SyntheticLM
 from repro.launch.mesh import make_local_mesh
-from repro.runtime import sharding as shd
+from repro.runtime import lm_sharding as shd
 
 
 def test_pipeline_deterministic():

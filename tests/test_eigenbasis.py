@@ -179,7 +179,7 @@ def test_kind_validation_and_auto():
 @pytest.mark.slow
 def test_fgft_serve_engine_smoke():
     from repro.launch.serve import serve_fgft, parse_args
-    args = parse_args(["--fgft", "--graphs", "3", "--graph-n", "24",
+    args = parse_args(["--graphs", "3", "--graph-n", "24",
                        "--transforms", "96", "--filter-steps", "2",
                        "--signals", "4"])
     out = serve_fgft(args)
@@ -290,7 +290,7 @@ def test_save_load_preserves_stage_cuts(sym_batch48, tmp_path):
 
 def test_fgft_serve_engine_tiers():
     from repro.launch.serve import serve_fgft, parse_args
-    args = parse_args(["--fgft", "--graphs", "2", "--graph-n", "16",
+    args = parse_args(["--graphs", "2", "--graph-n", "16",
                        "--transforms", "64", "--filter-steps", "2",
                        "--signals", "4",
                        "--tiers", "full:1.0,draft:0.25"])
@@ -309,7 +309,7 @@ def test_fgft_serve_engine_directed_kind():
     """--directed must reach the T-transform family (the kind= plumbing
     this PR adds; the service used to silently auto-route)."""
     from repro.launch.serve import serve_fgft, parse_args
-    args = parse_args(["--fgft", "--directed", "--graphs", "2",
+    args = parse_args(["--directed", "--graphs", "2",
                        "--graph-n", "12", "--transforms", "24",
                        "--filter-steps", "1", "--signals", "2",
                        "--tiers", "full:1.0"])
@@ -427,7 +427,7 @@ def test_serve_tier_stats_speedup_vs_best():
     speedup_vs_best; the old key survives only as a deprecated alias and
     only when a tier named "full" actually exists."""
     from repro.launch.serve import serve_fgft, parse_args
-    args = parse_args(["--fgft", "--graphs", "2", "--graph-n", "16",
+    args = parse_args(["--graphs", "2", "--graph-n", "16",
                        "--transforms", "64", "--filter-steps", "1",
                        "--signals", "2", "--tiers", "full:1.0,draft:0.25"])
     out = serve_fgft(args)
@@ -435,7 +435,7 @@ def test_serve_tier_stats_speedup_vs_best():
         assert "speedup_vs_best" in ts
         assert ts["speedup_vs_full"] == ts["speedup_vs_best"]
     assert out["tiers"]["full"]["speedup_vs_best"] == pytest.approx(1.0)
-    args = parse_args(["--fgft", "--graphs", "2", "--graph-n", "16",
+    args = parse_args(["--graphs", "2", "--graph-n", "16",
                        "--transforms", "64", "--filter-steps", "1",
                        "--signals", "2", "--tiers", "hq:1.0,draft:0.25"])
     out = serve_fgft(args)

@@ -1,5 +1,5 @@
-"""Integration tests: train driver (with checkpoint/restart), serve engine,
-graph generators, and the attention consistency across impls."""
+"""Integration tests: train driver (with checkpoint/restart), graph
+generators, and the dry-run's step builder on one device."""
 import numpy as np
 import pytest
 import jax
@@ -34,17 +34,6 @@ def test_train_driver_grad_compression(tmp_path):
         "--grad-compress-ratio", "0.25",
         "--ckpt-dir", str(tmp_path / "c"), "--log-every", "2"])
     assert np.isfinite(loss)
-
-
-@pytest.mark.slow
-def test_serve_driver(capsys):
-    from repro.launch import serve as serve_mod
-    outputs = serve_mod.main([
-        "--arch", "qwen2-1.5b", "--smoke", "--requests", "4",
-        "--batch-slots", "2", "--prompt-len", "8", "--gen-len", "4",
-        "--max-len", "32"])
-    assert len(outputs) == 4
-    assert all(len(v) == 4 for v in outputs.values())
 
 
 def test_graph_generators_shapes():

@@ -11,11 +11,12 @@ import pytest
 from repro.core import (ApproxEigenbasis, approximate_general,
                         approximate_symmetric, pad_ragged)
 from repro.core.fgft import laplacian
-from repro.core.staging import (pack_g_pair, pack_t_pair, with_precision)
+from repro.core.staging import pack_g_pair, pack_t_pair
 from repro.graphs import community_graph, directed_variant
 from repro.kernels import autotune, ref
 from repro.kernels.plan import (ApplyPlan, leg_orientation,
                                 clear_plan_cache, plan_cache_size)
+from repro.tables import with_precision
 
 
 def _pair(family, n, g, seed=0):

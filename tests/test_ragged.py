@@ -233,7 +233,7 @@ def test_ragged_router_matches_single_graph_serving(router):
 @pytest.mark.slow
 def test_serve_fgft_ragged_smoke():
     from repro.launch.serve import parse_args, serve_fgft
-    args = parse_args(["--fgft", "--ragged", "--graphs", "4",
+    args = parse_args(["--ragged", "--graphs", "4",
                        "--graph-sizes", "10,16", "--transforms", "48",
                        "--filter-steps", "2", "--signals", "3"])
     out = serve_fgft(args)
@@ -242,11 +242,12 @@ def test_serve_fgft_ragged_smoke():
     assert out["rel_error"].shape == (4,)
     assert np.all(np.isfinite(out["rel_error"]))
     assert out["transforms_per_s"] > 0
-    # warmup/compile is excluded from the per-tier counters (non-ragged
-    # serve_fgft convention): the stepped (default) tier counts exactly
-    # the timed steps, untouched tiers stay 0
+    # warmup/compile is excluded from the per-tier counters: every tier
+    # is timed, and each counts exactly its timed steps in every bucket
+    assert set(out["tiers"]) == {"full", "balanced", "draft"}
     for bucket_stats in out["stats"].values():
-        assert sorted(bucket_stats["steps"].values()) == [0, 0, 2]
+        assert bucket_stats["steps"] == {"full": 2, "balanced": 2,
+                                         "draft": 2}
 
 
 @pytest.mark.slow
@@ -259,7 +260,6 @@ def test_ragged_router_filter_bank():
                        "10,16", "--transforms", "32", "--filter-steps",
                        "2", "--signals", "3", "--filter",
                        "heat,tikhonov"])
-    assert args.fgft
     out = serve_fgft(args)
     assert out["responses_per_s"] > 0
     assert out["sizes"] == [10, 16]
@@ -285,7 +285,7 @@ def test_speedup_vs_full_alias_uses_the_full_tier():
     named "full", not the best tier — when "full" is NOT the best tier
     the two baselines differ."""
     from repro.launch.serve import parse_args, serve_fgft
-    args = parse_args(["--fgft", "--graphs", "2", "--graph-n", "16",
+    args = parse_args(["--graphs", "2", "--graph-n", "16",
                        "--transforms", "64", "--filter-steps", "1",
                        "--signals", "2", "--tiers", "full:0.5,hq:1.0"])
     out = serve_fgft(args)

@@ -9,7 +9,7 @@ import pytest
 from repro.launch import serve
 
 # one tiny fleet shared by every uniform cell: 2 graphs, n=12, g=24
-BASE = ["--fgft", "--graphs", "2", "--graph-n", "12", "--transforms",
+BASE = ["--graphs", "2", "--graph-n", "12", "--transforms",
         "24", "--filter-steps", "2", "--signals", "3", "--seed", "0"]
 RAGGED = ["--ragged", "--graphs", "3", "--graph-sizes", "6,12"]
 ASYNC = ["--serve-async", "--load-requests", "12", "--load-workers", "2",
@@ -105,15 +105,27 @@ def test_cli_serve_async_tight_queue_warmup():
 
 
 def test_cli_serve_async_implies_fgft():
-    args = serve.parse_args(["--serve-async"])
-    assert args.fgft
-    args = serve.parse_args(["--dynamic"])
-    assert args.fgft
+    """The CLI serves graphs and nothing else: no flag picks the mode
+    between an LM and the FGFT service, bare arguments give the graph
+    defaults, and the LM's flags are rejected."""
+    args = serve.parse_args([])
+    assert (args.graphs, args.graph_n, args.transforms) == (8, 64, 0)
+    assert args.tier_map == serve.DEFAULT_TIERS
+    assert not (args.ragged or args.dynamic or args.serve_async
+                or args.directed or args.filter)
+    assert serve.parse_args(["--serve-async"]).serve_async
+    assert serve.parse_args(["--dynamic"]).dynamic
+    for gone in (["--arch", "qwen2-1.5b"], ["--fgft"], ["--smoke"],
+                 ["--requests", "4"], ["--batch-slots", "2"],
+                 ["--prompt-len", "8"], ["--gen-len", "4"],
+                 ["--max-len", "32"]):
+        with pytest.raises(SystemExit):
+            serve.parse_args(gone)
 
 
 def test_cli_rejects_bad_tier_spec():
     with pytest.raises(SystemExit):
-        serve.parse_args(["--fgft", "--graph-sizes", "6,oops"])
+        serve.parse_args(["--graph-sizes", "6,oops"])
     with pytest.raises(ValueError):
         serve.parse_tiers("full")            # missing fraction
     with pytest.raises(ValueError):

@@ -7,10 +7,10 @@ import pytest
 from repro.core import (approximate_symmetric, approximate_general,
                         g_to_dense, t_to_dense, pack_g, pack_g_adjoint,
                         pack_t, pack_t_inverse)
-from repro.core.staging import (pack_g_batch, pack_t_batch, select_cut,
-                                truncate_staged)
+from repro.core.staging import pack_g_batch, pack_t_batch, select_cut
 from repro.core.types import GFactors, TFactors
 from repro.kernels import ref
+from repro.tables import truncate_staged
 
 
 def _sym(n, seed):
