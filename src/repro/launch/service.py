@@ -21,6 +21,10 @@ bridges the two:
     small, walk one whole-bucket block instead).
     Row counts are quantized (``quantize_rows``) so steady-state
     dispatches reuse a handful of compiled programs.
+  * the dispatcher only launches; a completion thread waits for each
+    dispatch, pulls its answers and replies, in launch order, while the
+    dispatcher builds and launches the next: at most ``PIPELINE_DEPTH``
+    dispatches are launched and unreplied.
   * ``maintain()`` (drift scoring, refresh/extend/refit, versioned hot
     swap — DESIGN.md §11) runs on a background maintainer thread, never
     on the serving path.  The hot path takes no lock around jitted calls:
@@ -43,10 +47,12 @@ CPU smoke:
 """
 from __future__ import annotations
 
+import itertools
 import json
 import math
 import os
 import pathlib
+import queue
 import threading
 import time
 import weakref
@@ -72,6 +78,16 @@ BANK = "__bank__"          # pseudo-tier routing a request to the filter bank
 #: per-graph layout skips save less than that fixed cost, and each
 #: launch beyond the first pays it again.
 WHOLE_BLOCK_ELEMENTS = 1 << 19
+
+#: Dispatches launched and not yet replied: one on the device, one whose
+#: answers the completion thread pulls and replies.  A deeper pipeline
+#: buys nothing while the host stages, not the device, set the pace.
+PIPELINE_DEPTH = 2
+
+# span lanes (``Tracer`` tids) of the pipeline's slots: two dispatches
+# whose spans overlap in time record under different lanes, so a reader
+# pairs each dispatch with its stages by lane and time containment
+_SPAN_LANES = itertools.count(1)
 
 # -- serving-path telemetry (DESIGN.md §15): every live service records
 # into the process-wide registry; per-service isolation comes from the
@@ -101,6 +117,10 @@ _OBS_DENSE_BLOCKS = obs.counter(
     "service_dense_blocks_total",
     "graph blocks served by the dense lowering (matmuls against dense "
     "legs, no stage walked)", ("service", "tier"))
+_OBS_OVERLAPPED = obs.counter(
+    "service_overlapped_dispatches_total",
+    "dispatches launched while an earlier one was still unreplied",
+    ("service", "tier"))
 _OBS_QUEUE_DEPTH = obs.gauge("service_queue_depth",
                              "queue depth sampled at the last dispatch",
                              ("service",))
@@ -315,6 +335,44 @@ class _Layout:
         return max(self.r_pads)
 
 
+@dataclass
+class _Dispatch:
+    """One coalesced dispatch between its two stages: ``_launch`` lays
+    it out, builds, puts and launches it, and ``_complete`` waits for
+    it, pulls and replies.  ``tid`` is the span lane its ``serve.*``
+    spans record under, ``ts`` its start on the tracer's clock,
+    ``in_flight`` the dispatches unreplied at its launch (None inline),
+    ``out`` each launch's (device answer, serving version), and
+    ``mirror`` its open ``serve.dispatch`` profiler annotation."""
+
+    batch: List[_Request]
+    lay: _Layout
+    label: str
+    t_collect: float
+    t0: float
+    tid: int
+    in_flight: Optional[int]
+    args: Optional[dict]
+    ts: float = 0.0
+    out: list = field(default_factory=list)
+    mirror: Any = None
+
+    def open_mirror(self, tracer):
+        """Enter the ``serve.dispatch`` annotation on this thread, unless
+        it is open already (the inline path runs both stages under
+        one)."""
+        if (self.mirror is None and tracer.enabled
+                and tracer.annotation is not None):
+            self.mirror = tracer.annotation("serve.dispatch",
+                                            **(self.args or {}))
+            self.mirror.__enter__()
+
+    def close_mirror(self):
+        if self.mirror is not None:
+            self.mirror.__exit__(None, None, None)
+            self.mirror = None
+
+
 @dataclass(frozen=True)
 class _Route:
     """Where one graph's requests dispatch: which engine, which batch row,
@@ -409,6 +467,7 @@ class AsyncFGFTService:
         self._block_elements = 0
         self._graph_blocks = 0
         self._dense_blocks = 0
+        self._overlapped = 0
         self._occ_max = 0
         self._maintain_ticks = 0
         self._maintain_errors = 0
@@ -417,6 +476,13 @@ class AsyncFGFTService:
         self._last_maint_error: Optional[BaseException] = None
         self._m_wake = threading.Event()
         self._m_done = threading.Condition()
+        # the dispatch pipeline: a free slot is its span lane; the
+        # dispatcher hands launched dispatches to the completion thread
+        self._lanes: queue.Queue = queue.Queue()
+        for _ in range(PIPELINE_DEPTH):
+            self._lanes.put(next(_SPAN_LANES))
+        self._handoff: queue.Queue = queue.Queue(maxsize=PIPELINE_DEPTH)
+        self._unreplied = 0
         self._threads: List[threading.Thread] = []
         _LIVE_SERVICES.add(self)
         if auto_start:
@@ -425,15 +491,16 @@ class AsyncFGFTService:
     # -- lifecycle ---------------------------------------------------------
 
     def start(self):
-        """Spawn the dispatcher (and, for dynamic engines, the maintainer)
-        threads; idempotent."""
+        """Spawn the dispatcher and completion (and, for dynamic engines,
+        the maintainer) threads; idempotent."""
         if self._threads:
             return
         if self._closed:
             raise ServiceClosed("service already closed")
-        worker = threading.Thread(target=self._dispatch_loop,
-                                  name=f"{self.name}-dispatch")
-        self._threads.append(worker)
+        for target, role in ((self._dispatch_loop, "dispatch"),
+                             (self._complete_loop, "complete")):
+            self._threads.append(threading.Thread(
+                target=target, name=f"{self.name}-{role}"))
         if getattr(self.engine, "dynamic", False):
             maint = threading.Thread(target=self._maintain_loop,
                                      name=f"{self.name}-maintain")
@@ -443,8 +510,9 @@ class AsyncFGFTService:
 
     def close(self, timeout: Optional[float] = 30.0):
         """Stop accepting work, drain the queue, join every thread.  The
-        dispatcher answers all already-queued requests before exiting, so
-        no accepted future is left unresolved."""
+        dispatcher launches all already-queued requests before exiting,
+        and the completion thread answers every launched dispatch before
+        it exits, so no accepted future is left unresolved."""
         with self._cond:
             self._closed = True
             self._cond.notify_all()
@@ -544,25 +612,58 @@ class AsyncFGFTService:
         thread; returns the number of requests answered (0 if the queue
         was empty).  This is the dispatcher's unit of work, exposed so
         tests (and the fig12 synchronous baseline) can pump the queue
-        deterministically without threads."""
+        deterministically without threads: both stages run serially,
+        under one ``serve.dispatch`` span on the caller's thread."""
         with self._cond:
             if not self._queue:
                 return 0
             batch = self._collect_locked()
-        t_collect = self._clock()
-        self._run_batch(batch, t_collect)
+        d = self._launch(batch, self._clock(), threading.get_ident(), None)
+        if d is not None:
+            self._complete(d)
         return len(batch)
 
     def _dispatch_loop(self):
+        """Collect and launch: take a free pipeline slot (its span lane)
+        before collecting, so the dispatcher blocks while
+        ``PIPELINE_DEPTH`` dispatches are unreplied, then hand the
+        launched dispatch to the completion thread."""
+        try:
+            while True:
+                lane = self._lanes.get()
+                with self._cond:
+                    while not self._queue and not self._closed:
+                        self._cond.wait()
+                    if not self._queue:
+                        return                  # closed and drained
+                    batch = self._collect_locked()
+                    in_flight = self._unreplied
+                    self._unreplied += 1
+                d = self._launch(batch, self._clock(), lane, in_flight)
+                if d is None:                   # failed at launch
+                    self._release(lane)
+                    continue
+                d.close_mirror()
+                self._handoff.put(d)
+        finally:
+            self._handoff.put(None)             # the completion thread's stop
+
+    def _complete_loop(self):
+        """Wait, pull and reply each launched dispatch in launch order,
+        then free its slot."""
         while True:
-            with self._cond:
-                while not self._queue and not self._closed:
-                    self._cond.wait()
-                if not self._queue:
-                    return                      # closed and drained
-                batch = self._collect_locked()
-            t_collect = self._clock()
-            self._run_batch(batch, t_collect)
+            d = self._handoff.get()
+            if d is None:
+                return
+            try:
+                self._complete(d)
+            finally:
+                self._release(d.tid)
+
+    def _release(self, lane: int):
+        with self._cond:
+            self._unreplied -= 1
+        self._lanes.put(lane)
 
     def _stage_children(self, label: str) -> dict:
         """Per-(tier, stage) bound histogram children, resolved once per
@@ -629,25 +730,28 @@ class AsyncFGFTService:
                        block_elements=sum(r_pads) * n,
                        lowering=eng.lowering)
 
-    def _run_batch(self, batch: List[_Request],
-                   t_collect: Optional[float] = None):
-        """One coalesced dispatch inside a ``serve.dispatch`` span whose
-        children time the dispatcher's stages (DESIGN.md §15): build,
-        put, launch, device, pull (``_fused_dispatch``) and reply."""
+    def _launch(self, batch: List[_Request], t_collect: float, tid: int,
+                in_flight: Optional[int]) -> Optional[_Dispatch]:
+        """The launch stage of one coalesced dispatch: lay it out, build
+        and put every graph block, launch them back to back and queue
+        every answer's copy to the host behind them.  Returns the
+        dispatch pending, or None when it failed (its futures then hold
+        the exception).  Its spans (DESIGN.md §15) record under lane
+        ``tid``; ``in_flight`` is None on the inline path."""
+        import jax
         t0 = self._clock()
-        if t_collect is None:
-            t_collect = t0
         tracer = obs.default_tracer()
         try:
             lay = self._layout(batch)
         except Exception as exc:  # noqa: BLE001 — fail the batch, not the service
             self._fail(batch, exc)
-            return
-        label = "bank" if batch[0].tier == BANK else batch[0].tier
+            return None
+        eng = self._routes[batch[0].graph_id].engine
+        tier = batch[0].tier
+        label = "bank" if tier == BANK else tier
         args = None
         if tracer.enabled:
-            stages = self._routes[batch[0].graph_id].engine.walk_stages(
-                None if batch[0].tier == BANK else batch[0].tier)
+            stages = eng.walk_stages(None if tier == BANK else tier)
             args = {"tier": label, "w": lay.n, "b": lay.b,
                     "r_pad": lay.r_pad, "requests": len(batch),
                     "rows": sum(req.signal.shape[0] for req in batch),
@@ -655,15 +759,85 @@ class AsyncFGFTService:
                     "block_elements": lay.block_elements,
                     "walk_stages": lay.b * stages,
                     "lowering": lay.lowering}
-        with tracer.span("serve.dispatch", cat="serve", args=args):
+            if in_flight is not None:
+                args["in_flight"] = in_flight
+        d = _Dispatch(batch=batch, lay=lay, label=label,
+                      t_collect=t_collect, t0=t0, tid=tid,
+                      in_flight=in_flight, args=args)
+        d.open_mirror(tracer)
+        if tracer.enabled:
+            d.ts = tracer.now()
+
+        def launch(x, row):
+            # row None: the engine's own step over the block it is handed
+            kw = {} if row is None else {"row": row}
+            if tier == BANK:
+                return eng.step_bank_versioned(x, **kw)
+            return eng.step_versioned(x, self._h, tier=tier, **kw)
+
+        try:
+            with tracer.span("serve.build", cat="serve", tid=tid):
+                if lay.whole:
+                    host = [np.zeros((lay.b, lay.r_pad, lay.n), np.float32)]
+                    views = list(host[0])
+                    rows = [None]
+                else:
+                    host = views = [np.zeros((r, lay.n), np.float32)
+                                    for r in lay.r_pads]
+                    rows = lay.rows
+                for req, (k, off) in zip(batch, lay.offsets):
+                    r, size = req.signal.shape
+                    views[k][off:off + r, :size] = req.signal
+            with tracer.span("serve.put", cat="serve", tid=tid):
+                xs = jax.device_put(host)
+            with tracer.span("serve.launch", cat="serve", tid=tid):
+                d.out = [launch(x, row) for x, row in zip(xs, rows)]
+                # queue the answers' copies to the host behind the steps,
+                # as a bare pull would, so that waiting for the device
+                # adds no round trip
+                for y, _ in d.out:
+                    y.copy_to_host_async()
+        except Exception as exc:  # noqa: BLE001 — fail the batch, not the service
+            self._fail(batch, exc)
+            self._finish(d)
+            return None
+        return d
+
+    def _complete(self, d: _Dispatch):
+        """The completion stage: wait for the device, pull every answer
+        and reply.  Rows are independent under every kernel in the
+        stack (they broadcast over the leading axes), so the coalesced
+        answer matches the per-request loop — bitwise for the G family
+        (tests/test_service.py)."""
+        import jax
+        tracer = obs.default_tracer()
+        d.open_mirror(tracer)
+        try:
+            ys = [y for y, _ in d.out]
             try:
-                ys, versions = self._fused_dispatch(batch, lay)
+                with tracer.span("serve.device", cat="serve", tid=d.tid):
+                    jax.block_until_ready(ys)
+                with tracer.span("serve.pull", cat="serve", tid=d.tid):
+                    ys = [np.asarray(y) for y in ys]
             except Exception as exc:  # noqa: BLE001 — fail the batch, not the service
-                self._fail(batch, exc)
+                self._fail(d.batch, exc)
                 return
-            with tracer.span("serve.reply", cat="serve"):
-                self._reply(batch, lay, ys, versions, label, t_collect,
-                            t0)
+            versions = [v for _, v in d.out]
+            if d.lay.whole:
+                ys, versions = list(ys[0]), versions * d.lay.b
+            with tracer.span("serve.reply", cat="serve", tid=d.tid):
+                self._reply(d, ys, versions)
+        finally:
+            self._finish(d)
+
+    def _finish(self, d: _Dispatch):
+        """Record the dispatch's ``serve.dispatch`` span around its
+        stages and close its profiler annotation."""
+        tracer = obs.default_tracer()
+        if d.args is not None:
+            tracer.add_span("serve.dispatch", d.ts, tracer.now(),
+                            cat="serve", tid=d.tid, args=d.args)
+        d.close_mirror()
 
     def _fail(self, batch: List[_Request], exc: Exception):
         with self._cond:
@@ -671,11 +845,13 @@ class AsyncFGFTService:
         for req in batch:
             req.future.set_exception(exc)
 
-    def _reply(self, batch: List[_Request], lay: "_Layout", ys, versions,
-               label: str, t_collect: float, t0: float):
+    def _reply(self, d: _Dispatch, ys, versions):
         """Crop each request's answer out of its graph block's host
-        answer in ``ys``, do the batch's bookkeeping and resolve its
+        answer in ``ys``, do the dispatch's bookkeeping and resolve its
         futures with the version that block was served by."""
+        batch, lay, label = d.batch, d.lay, d.label
+        t_collect, t0 = d.t_collect, d.t0
+        overlapped = bool(d.in_flight)
         results = []
         for req, (k, off) in zip(batch, lay.offsets):
             r, size = req.signal.shape
@@ -691,6 +867,7 @@ class AsyncFGFTService:
             self._graph_blocks += lay.b
             dense = lay.b if lay.lowering == "dense" else 0
             self._dense_blocks += dense
+            self._overlapped += overlapped
             depth_now = len(self._queue)
         tracer = obs.default_tracer()
         if obs.recording_enabled():
@@ -711,14 +888,18 @@ class AsyncFGFTService:
                     _OBS_GRAPH_BLOCKS.labels(service=self.name,
                                              tier=label),
                     _OBS_DENSE_BLOCKS.labels(service=self.name,
-                                             tier=label))
-            dispatches, signal, block, blocks, dense_blocks = dchild
+                                             tier=label),
+                    _OBS_OVERLAPPED.labels(service=self.name, tier=label))
+            (dispatches, signal, block, blocks, dense_blocks,
+             overlaps) = dchild
             dispatches.inc()
             signal.inc(lay.signal_elements)
             block.inc(lay.block_elements)
             blocks.inc(lay.b)
             if dense:
                 dense_blocks.inc(dense)
+            if overlapped:
+                overlaps.inc()
             self._obs_depth.set(depth_now)
             stage_obs = self._stage_children(label)
             stage_obs["batch"].observe_many(t0 - t_collect, len(batch))
@@ -758,59 +939,6 @@ class AsyncFGFTService:
                 queue_s=queue_s, service_s=t1 - t0,
                 total_s=t1 - req.t_submit, batch_size=len(batch),
                 trace_id=req.trace_id))
-
-    def _fused_dispatch(self, batch: List[_Request], lay: "_Layout"):
-        """The engine launches answering every request in ``batch`` (all
-        share a dispatch group), laid out by ``lay``: every graph block
-        is built, put and launched back to back, every answer's copy to
-        the host is queued, the device is waited for once, and every
-        answer is pulled.  Returns the host answer and serving version
-        of each graph block.  Rows are independent under every kernel
-        in the stack (they broadcast over the leading axes), so the
-        coalesced answer matches the per-request loop — bitwise for the
-        G family (tests/test_service.py)."""
-        import jax
-        eng = self._routes[batch[0].graph_id].engine
-        tier = batch[0].tier
-        tracer = obs.default_tracer()
-        with tracer.span("serve.build", cat="serve"):
-            if lay.whole:
-                host = [np.zeros((lay.b, lay.r_pad, lay.n), np.float32)]
-                views = list(host[0])
-                rows = [None]
-            else:
-                host = views = [np.zeros((r, lay.n), np.float32)
-                                for r in lay.r_pads]
-                rows = lay.rows
-            for req, (k, off) in zip(batch, lay.offsets):
-                r, size = req.signal.shape
-                views[k][off:off + r, :size] = req.signal
-        with tracer.span("serve.put", cat="serve"):
-            xs = jax.device_put(host)
-
-        def launch(x, row):
-            # row None: the engine's own step over the block it is handed
-            kw = {} if row is None else {"row": row}
-            if tier == BANK:
-                return eng.step_bank_versioned(x, **kw)
-            return eng.step_versioned(x, self._h, tier=tier, **kw)
-
-        with tracer.span("serve.launch", cat="serve"):
-            out = [launch(x, row) for x, row in zip(xs, rows)]
-        ys = [y for y, _ in out]
-        with tracer.span("serve.device", cat="serve"):
-            # queue the answers' copies to the host behind the steps
-            # first, as a bare pull would, so that waiting for the device
-            # on its own adds no round trip
-            for y in ys:
-                y.copy_to_host_async()
-            jax.block_until_ready(ys)
-        with tracer.span("serve.pull", cat="serve"):
-            ys = [np.asarray(y) for y in ys]
-        versions = [v for _, v in out]
-        if lay.whole:
-            ys, versions = list(ys[0]), versions * lay.b
-        return ys, versions
 
     # -- background maintenance (dynamic engines; DESIGN.md §11) -----------
 
@@ -899,6 +1027,7 @@ class AsyncFGFTService:
             self._dispatches = self._coalesced = self._occ_max = 0
             self._signal_elements = self._block_elements = 0
             self._graph_blocks = self._dense_blocks = 0
+            self._overlapped = 0
             self._maintain_ticks = self._maintain_errors = 0
             self._swaps = 0
         self.latency = LatencyRecorder(max_samples=self.latency.max_samples)
@@ -925,6 +1054,9 @@ class AsyncFGFTService:
                 "block_elements": self._block_elements,
                 "graph_blocks": self._graph_blocks,
                 "dense_blocks": self._dense_blocks,
+                # dispatches answered that were launched while an
+                # earlier one was still unreplied: the pipeline engaged
+                "overlapped_dispatches": self._overlapped,
                 "batch": {
                     "cap": self.max_batch,
                     "occupancy_mean": (self._coalesced / self._dispatches

@@ -11,6 +11,7 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 _REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
@@ -121,3 +122,82 @@ def ragged_sym_fit():
 
     fleet = [s(10, 0), s(16, 1), s(9, 2), s(16, 3)]
     return fleet, ApproxEigenbasis.fit(fleet, 16, n_iter=1)
+
+
+# ---------------------------------------------------------------------------
+# Gated answers: hold the service's dispatch pipeline at a known state
+# ---------------------------------------------------------------------------
+
+
+class GatedAnswers:
+    """Wraps engines' steps so that each launch's answer is held in the
+    completion stage's wait for the device until the test opens that
+    launch (numbered from 0 in launch order); the pull of a launch in
+    ``fail`` raises.  So a test decides which dispatches are unreplied
+    when the next one launches, with no timing in its assertions."""
+
+    def __init__(self, monkeypatch, engines, fail=()):
+        self.cond = threading.Condition()
+        self.launched = 0
+        self.opened = set()
+        self.all_open = False
+        self.fail = set(fail)
+        for eng in engines:
+            for name in ("step_versioned", "step_bank_versioned"):
+                monkeypatch.setattr(eng, name,
+                                    self._wrap(getattr(eng, name)))
+
+    def _wrap(self, step):
+        def gated(*args, **kwargs):
+            y, version = step(*args, **kwargs)
+            with self.cond:
+                i = self.launched
+                self.launched += 1
+                self.cond.notify_all()
+            return _GatedAnswer(self, i, y), version
+        return gated
+
+    def wait_launched(self, n, timeout=30.0):
+        with self.cond:
+            assert self.cond.wait_for(lambda: self.launched >= n, timeout), \
+                f"{self.launched} of {n} launches after {timeout}s"
+
+    def open(self, *launches):
+        with self.cond:
+            self.opened.update(launches)
+            self.cond.notify_all()
+
+    def open_all(self):
+        with self.cond:
+            self.all_open = True
+            self.cond.notify_all()
+
+
+class _GatedAnswer:
+    """A device answer that the completion stage waits for only once
+    its launch is opened."""
+
+    def __init__(self, gate, i, y):
+        self.gate, self.i, self.y = gate, i, y
+
+    def copy_to_host_async(self):
+        self.y.copy_to_host_async()
+
+    def block_until_ready(self):
+        gate = self.gate
+        with gate.cond:
+            assert gate.cond.wait_for(
+                lambda: gate.all_open or self.i in gate.opened, 30.0)
+        self.y.block_until_ready()
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        if self.i in self.gate.fail:
+            raise RuntimeError(f"pull of launch {self.i} failed")
+        return np.asarray(self.y, dtype=dtype)
+
+
+@pytest.fixture
+def gate(monkeypatch):
+    """``gate(engines, fail=())`` -> a ``GatedAnswers`` over them."""
+    return lambda engines, fail=(): GatedAnswers(monkeypatch, engines, fail)

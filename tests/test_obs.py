@@ -598,6 +598,41 @@ def test_service_dispatch_emits_stage_spans_in_order(sym_engine):
     assert fake.log[0][2] == disp["args"]
 
 
+def test_threaded_dispatch_spans_pair_by_lane(sym_engine, gate):
+    """The started service's pipeline overlaps dispatches, yet each
+    ``serve.dispatch`` span pairs with exactly one of each stage span
+    by tid and time containment (as the chip benchmark's reader pairs
+    them), and its stages' durations sum to at most its own."""
+    from repro.launch.service import AsyncFGFTService
+    tracer = obs.default_tracer()
+    g = gate([sym_engine])
+    tracer.clear()
+    rng = np.random.default_rng(4)
+    with AsyncFGFTService(sym_engine, max_batch=1,
+                          name="obs-pipeline") as svc:
+        futs = [svc.submit(i % 3, rng.standard_normal((2, 16)).astype(
+            np.float32)) for i in range(6)]
+        g.wait_launched(2)              # dispatch 1 launched, 0 unreplied
+        g.open_all()
+        for f in futs:
+            f.result(timeout=30)
+    serve = [s for s in tracer.spans() if s["name"].startswith("serve.")]
+    disp = sorted((s for s in serve if s["name"] == "serve.dispatch"),
+                  key=lambda s: s["ts"])
+    stages = [s for s in serve if s["name"] != "serve.dispatch"]
+    assert len(disp) == 6 and len(stages) == 6 * len(SERVE_STAGES)
+    assert disp[1]["args"]["in_flight"] == 1
+    assert disp[1]["ts"] < disp[0]["ts"] + disp[0]["dur"]  # they overlap
+    for d in disp:
+        end = d["ts"] + d["dur"]
+        kids = sorted((s for s in stages if s["tid"] == d["tid"]
+                       and d["ts"] <= s["ts"]
+                       and s["ts"] + s["dur"] <= end),
+                      key=lambda s: s["ts"])
+        assert [k["name"] for k in kids] == SERVE_STAGES
+        assert sum(k["dur"] for k in kids) <= d["dur"]
+
+
 def test_service_stats_embed_obs_snapshot(sym_engine):
     from repro.launch.service import AsyncFGFTService
     svc = AsyncFGFTService(sym_engine, clock=FakeClock(),

@@ -1,8 +1,13 @@
 """Async serving front-end (launch/service.py, DESIGN.md §12): exact
 SLO-stats math under a fake injectable clock (no sleeps, no wall-clock
 sensitivity), admission control, coalescing-equivalence properties
-(fused micro-batch == per-request loop, bitwise for the G family), and
-SLO persistence next to the engine checkpoint."""
+(fused micro-batch == per-request loop, bitwise for the G family),
+SLO persistence next to the engine checkpoint, and the threaded
+dispatch pipeline against the inline path."""
+import sys
+import threading
+import time
+
 import numpy as np
 import pytest
 
@@ -396,8 +401,8 @@ def test_dispatch_error_fails_batch_not_service(sym_engine, monkeypatch):
     x = signals_for(sym_engine, 0, 1, 11)
     boom = svc.submit(0, x)
     monkeypatch.setattr(
-        svc, "_fused_dispatch",
-        lambda batch, lay: (_ for _ in ()).throw(
+        sym_engine, "step_versioned",
+        lambda *a, **kw: (_ for _ in ()).throw(
             RuntimeError("device lost")))
     svc.drain_once()
     with pytest.raises(RuntimeError, match="device lost"):
@@ -1125,3 +1130,169 @@ def test_save_slo_ragged_sidecar(ragged_engine, tmp_path):
     slo = load_slo_stats(out)
     assert slo["served"] == 1
     assert slo["queue"]["max"] == svc.max_queue
+
+
+# ---------------------------------------------------------------------------
+# The threaded dispatch pipeline: launch k+1 while k is pulled and replied
+# ---------------------------------------------------------------------------
+
+
+def routed_engines(engine):
+    return (list(engine.engines.values())
+            if isinstance(engine, RaggedFGFTServeEngine) else [engine])
+
+
+@pytest.mark.parametrize("family", ["sym", "general"])
+def test_threaded_service_answers_as_drain_once(request, family):
+    """8 closed-loop clients through the started service (dispatcher and
+    completion threads) over a deck of graphs, tiers and bank requests:
+    every answer is bitwise the inline ``drain_once`` answer (G family)
+    and within f32 of it (T family)."""
+    router = request.getfixturevalue(
+        "ragged3_engine" if family == "sym" else "ragged3_gen_engine")
+    reqs = [r for order in ([0, 1, 2], [2, 0, 1], [1, 2, 0])
+            for r in mixed_queue(router, order)]
+    ref = reference_loop(router, reqs, h=lowpass)
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)         # interleave the threads finely
+    try:
+        with AsyncFGFTService(router, h=lowpass, max_batch=4) as svc:
+            got = service_mod.closed_loop_load(svc, reqs, workers=8)
+            st = svc.stats()
+    finally:
+        sys.setswitchinterval(switch)
+    assert st["served"] == len(reqs) and st["errors"] == 0
+    assert st["overlapped_dispatches"] <= st["dispatches"]
+    for (gid, x, tier, bank), a, b in zip(reqs, got, ref):
+        assert a.graph_id == gid and a.tier == b.tier
+        assert a.y.shape == b.y.shape
+        if family == "sym":
+            assert np.array_equal(a.y, b.y)
+        else:
+            np.testing.assert_allclose(a.y, b.y, rtol=1e-5,
+                                       atol=1e-5 * np.abs(b.y).max())
+
+
+def test_pipeline_counts_overlap_and_bounds_depth(sym_engine, gate):
+    """Dispatch 1 launches while dispatch 0 is unreplied, dispatch 2 only
+    once a slot frees (never while both are unreplied), and each
+    records how many were unreplied at its launch."""
+    from repro import obs
+    g = gate([sym_engine])
+    tracer = obs.default_tracer()
+    tracer.clear()
+    name = "pipeline-overlap"
+    x = signals_for(sym_engine, 0, 2, 12)
+    with AsyncFGFTService(sym_engine, max_batch=1, name=name) as svc:
+        futs = [svc.submit(i % 3, x, tier="full") for i in range(3)]
+        g.wait_launched(2)
+        time.sleep(0.2)                 # room for a third launch, if any
+        assert g.launched == 2
+        assert not any(f.done() for f in futs)
+        g.open(0)
+        g.wait_launched(3)              # launched while 1 is unreplied
+        g.open_all()
+        results = [f.result(timeout=30) for f in futs]
+        st = svc.stats()
+    assert [r.batch_size for r in results] == [1, 1, 1]
+    assert st["dispatches"] == 3 and st["overlapped_dispatches"] == 2
+    assert service_mod._OBS_OVERLAPPED.value(service=name,
+                                             tier="full") == 2
+    disp = sorted((s for s in tracer.spans(name="serve.dispatch")),
+                  key=lambda s: s["ts"])
+    assert [s["args"]["in_flight"] for s in disp] == [0, 1, 1]
+    # overlapping dispatches record under different span lanes; the
+    # third takes the lane the first freed
+    assert disp[0]["tid"] != disp[1]["tid"]
+    assert disp[2]["tid"] == disp[0]["tid"]
+
+
+def test_drain_once_records_no_overlap(sym_engine):
+    svc = AsyncFGFTService(sym_engine, auto_start=False, max_batch=1)
+    for i in range(3):
+        svc.submit(i, signals_for(sym_engine, i, 1, i))
+    assert drain_all(svc) == [1, 1, 1]
+    st = svc.stats()
+    assert st["dispatches"] == 3 and st["overlapped_dispatches"] == 0
+    svc.reset_stats()
+    assert svc.stats()["overlapped_dispatches"] == 0
+
+
+def test_swap_between_in_flight_dispatches_keeps_versions(dyn_engine,
+                                                          gate):
+    """A hot swap lands after dispatch 0 launched and before dispatch 1
+    launched, both unreplied: each answer is its own version's,
+    bitwise."""
+    from repro.graphs import weight_jitter
+    x = signals_for(dyn_engine, 1, 3, 13)
+
+    def inline_answer():
+        svc = AsyncFGFTService(dyn_engine, auto_start=False)
+        fut = svc.submit(1, x)
+        svc.drain_once()
+        return fut.result(timeout=0)
+
+    before = inline_answer()
+    g = gate([dyn_engine])
+    with AsyncFGFTService(dyn_engine, max_batch=1) as svc:
+        first = svc.submit(1, x)
+        g.wait_launched(1)
+        adj = (np.abs(np.asarray(dyn_engine._laps_host[1])) *
+               (1 - np.eye(12))).astype(np.float32)
+        dyn_engine.apply_updates(1, weight_jitter(adj, 6, scale=0.2,
+                                                  seed=3))
+        assert svc.maintain_now()["action"] != "reuse"
+        second = svc.submit(1, x)
+        g.wait_launched(2)
+        assert not first.done()
+        g.open_all()
+        r0, r1 = first.result(timeout=30), second.result(timeout=30)
+    after = inline_answer()
+    assert r0.version == before.version
+    assert r1.version == before.version + 1 == after.version
+    assert np.array_equal(r0.y, before.y)
+    assert np.array_equal(r1.y, after.y)
+    assert not np.array_equal(r0.y, r1.y)
+
+
+@pytest.mark.parametrize("threaded", [False, True])
+def test_pull_error_fails_batch_not_service(sym_engine, gate, threaded):
+    """An answer that raises at its pull fails that batch only; the next
+    batch is served, inline and on the completion thread alike."""
+    g = gate([sym_engine], fail={0})
+    g.open_all()
+    x = signals_for(sym_engine, 0, 1, 14)
+    svc = AsyncFGFTService(sym_engine, auto_start=threaded)
+    bad = svc.submit(0, x)
+    if not threaded:
+        svc.drain_once()
+    with pytest.raises(RuntimeError, match="pull of launch 0 failed"):
+        bad.result(timeout=30)
+    ok = svc.submit(0, x)
+    if not threaded:
+        svc.drain_once()
+    assert ok.result(timeout=30).y.shape == (1, sym_engine.basis.n)
+    svc.close()
+    st = svc.stats()
+    assert st["errors"] == 1 and st["served"] == 1
+
+
+def test_close_with_dispatches_in_flight_answers_all(sym_engine, gate):
+    """close() while two dispatches are unreplied and more requests wait
+    in the queue: every future resolves and both threads are joined."""
+    g = gate([sym_engine])
+    svc = AsyncFGFTService(sym_engine, max_batch=1, name="close-in-flight")
+    x = signals_for(sym_engine, 2, 2, 15)
+    futs = [svc.submit(i % 3, x) for i in range(6)]
+    g.wait_launched(2)
+    threads = list(svc._threads)
+    assert sorted(t.name for t in threads) == ["close-in-flight-complete",
+                                               "close-in-flight-dispatch"]
+    opener = threading.Timer(0.1, g.open_all)
+    opener.start()
+    svc.close()
+    opener.join()
+    assert all(f.done() for f in futs)
+    assert [f.result(timeout=0).batch_size for f in futs] == [1] * 6
+    assert not any(t.is_alive() for t in threads)
+    assert svc.stats()["served"] == 6
